@@ -9,10 +9,9 @@ reports the coords a node message actually moves.  The headline numbers (DESIGN.
 * sparse RandK moves <= 2K coords per message (K values + K indices; K only
   when the support is derivable from the shared seed) vs d for dense — the
   `bits sent` plots stop being fictional;
-* the fused Pallas path runs every compressor in one HBM pass (on this CPU
-  container it executes in interpret mode, so fused wall-times are NOT
-  meaningful — structural numbers only; set REPRO_PALLAS_INTERPRET=0 on a
-  real TPU).
+* the fused Pallas path runs every compressor in one HBM pass (off a TPU
+  it executes in interpret mode, so fused wall-times are NOT meaningful
+  there — structural numbers only).
 
 Env: REPRO_BENCH_QUICK=1 shrinks to d=1e4 for CI smoke runs.
 """

@@ -14,7 +14,6 @@ point takes --full to select the assigned full-size config under the
 """
 import argparse
 import os
-import sys
 
 from repro.launch.train import main as train_main
 
@@ -24,8 +23,8 @@ if __name__ == "__main__":
                     default=int(os.environ.get("REPRO_EXAMPLE_ROUNDS", 300)))
     ap.add_argument("--arch", default="starcoder2-3b")
     args, rest = ap.parse_known_args()
-    sys.exit(train_main([
+    train_main([
         "--arch", args.arch, "--steps", str(args.steps),
         "--nodes", "4", "--batch", "2", "--seq", "128",
         "--gamma", "0.003", "--compression", "0.0625",
-        "--server-opt", "adam", "--log-every", "25", *rest]))
+        "--server-opt", "adam", "--log-every", "25", *rest])

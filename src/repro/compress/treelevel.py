@@ -165,25 +165,39 @@ def fused_tree_update(key: jax.Array, grads_new: PyTree, h: PyTree,
 
     ``variant="dasha"``: h_new = grads_new.  ``variant="mvr"``: the kernel
     fuses the momentum h-update h_new = gn + (1-b)(h - go) as well
-    (``grads_old`` required).  Returns (m, h_new, g_local_new) trees."""
+    (``grads_old`` required).  Returns (m, h_new, g_local_new) trees.
+
+    Under a mesh (``jax.set_mesh``) with ``specs`` given, each leaf's
+    kernel runs on every device's own shard (``shard_map``): the update
+    is elementwise, and XLA cannot partition a Mosaic kernel."""
     from repro.kernels import ops as kops
 
     masks, scale = tree_masks(key, grads_new, mode=mode, p=p, n=n,
                               specs=specs)
+    if specs is None:
+        specs = _none_specs(grads_new)
+    mesh = jax.sharding.get_abstract_mesh()
 
     if variant == "mvr":
         assert grads_old is not None, "mvr fused path needs grads_old"
 
-        def leaf(mask, gn, go, hh, gl):
+        def update(mask, gn, go, hh, gl):
             return kops.dasha_mvr_update(gn, go, hh, gl, mask, a, b, scale)
 
-        trips = jax.tree_util.tree_map(leaf, masks, grads_new, grads_old,
-                                       h, g_local)
+        operands = (masks, grads_new, grads_old, h, g_local)
     else:
-        def leaf(mask, gn, hh, gl):
+        def update(mask, gn, hh, gl):
             return kops.dasha_update(gn, hh, gl, mask, a, scale)
 
-        trips = jax.tree_util.tree_map(leaf, masks, grads_new, h, g_local)
+        operands = (masks, grads_new, h, g_local)
+
+    def leaf(spec, *xs):
+        if spec is None or mesh.empty:
+            return update(*xs)
+        return jax.shard_map(update, in_specs=(spec,) * len(xs),
+                             out_specs=(spec,) * 3, check_vma=False)(*xs)
+
+    trips = jax.tree_util.tree_map(leaf, specs, *operands, is_leaf=_spec_leaf)
 
     def pick(i):
         return jax.tree_util.tree_map(lambda t: t[i], trips,

@@ -13,10 +13,12 @@ the state tensors stream through, so the compressed message m is produced
 for free on top of the mandatory estimator update traffic.
 
 Tiling: inputs are reshaped to (R, 128) by the ops layer; the grid walks R in
-blocks of ``block_rows`` rows so each program holds
-``7 tensors x block_rows x 128 x 4B`` in VMEM (block_rows=2048 -> ~7 MB,
-comfortably under the ~16 MB v5e VMEM budget while keeping the last dim at
-the 128-lane width).
+blocks of ``block_rows`` rows.  The pipeline double-buffers every operand, so
+a program holds ``2 x tensors x block_rows x 128 x 4 B`` of VMEM: DASHA
+streams 7 tensors (4 in, 3 out) and MVR 8, so block_rows=1024 takes 7 MiB
+and 8 MiB, inside the 16 MiB of scoped VMEM that Mosaic grants a kernel on
+TPU v5e by default.  At 2048 (14 and 16 MiB plus the compiler's own scratch)
+the v5e compiler refuses both kernels for running out of VMEM.
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANE = 128          # TPU vector lane width: last dim of every block
-DEFAULT_BLOCK_ROWS = 2048
+DEFAULT_BLOCK_ROWS = 1024
 
 
 def _dasha_update_kernel(a_ref, scale_ref, grad_ref, h_ref, gl_ref, mask_ref,

@@ -1,14 +1,14 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles the (d,) <-> (R, 128) padding/reshape plumbing so callers pass flat
-vectors (or any shape); kernels see lane-aligned 2-D blocks.  On this CPU
-container every call runs with ``interpret=True`` (the kernel body executes
-in Python), on a real TPU the same code path compiles to Mosaic.
+vectors (or any shape); kernels see lane-aligned 2-D blocks.  Where a call
+runs is decided when it is traced, from the default backend: on a TPU every
+kernel compiles to Mosaic (and a kernel the chip refuses raises); on any
+other backend the kernel body runs in the Pallas interpreter.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -17,11 +17,10 @@ import jax.numpy as jnp
 from repro.kernels.dasha_update import (LANE, dasha_mvr_update_pallas,
                                         dasha_update_pallas, quantize_pallas)
 
-#: interpret-mode switch: REPRO_PALLAS_INTERPRET=0 on real TPUs compiles the
-#: kernels to Mosaic; any other value (or unset) runs the Python interpreter
-#: path, which is what this CPU container supports.
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1").lower() \
-    not in ("0", "false", "no")
+
+def _interpret() -> bool:
+    """Interpret the kernels unless they are traced for a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _to_lanes(x: jax.Array) -> Tuple[jax.Array, int]:
@@ -50,7 +49,7 @@ def dasha_update(grad: jax.Array, h: jax.Array, g_local: jax.Array,
     gl2, _ = _to_lanes(g_local)
     mk2, _ = _to_lanes(mask)
     m, hn, gln = dasha_update_pallas(g2, h2, gl2, mk2, a, scale,
-                                     interpret=INTERPRET)
+                                     interpret=_interpret())
     def back(t):
         return _from_lanes(t, d, shape, dtype)
 
@@ -68,7 +67,7 @@ def dasha_mvr_update(grad_new: jax.Array, grad_old: jax.Array, h: jax.Array,
     gl2, _ = _to_lanes(g_local)
     mk2, _ = _to_lanes(mask)
     m, hn, gln = dasha_mvr_update_pallas(gn2, go2, h2, gl2, mk2, a, b, scale,
-                                         interpret=INTERPRET)
+                                         interpret=_interpret())
     def back(t):
         return _from_lanes(t, d, shape, dtype)
 
@@ -82,38 +81,30 @@ def slab_writeback(full: jax.Array, idx: jax.Array, rows: jax.Array, *,
     """Write a chunk slab back into the persistent (n, d) store.
 
     ``idx`` (U,) int32 — sorted-unique global row ids padded with the
-    sentinel ``n`` (dropped); ``rows`` (U, d) — the slab.  On compiled
-    (non-interpret) backends this is the aliased Pallas kernel
-    (:mod:`repro.kernels.slab_writeback`): the store is donated and
-    mutated in place.  Under ``REPRO_PALLAS_INTERPRET`` (this CPU
-    container) the default is XLA's drop-mode scatter — running the
-    interpreter per chunk would serialize U python iterations — and the
-    kernel stays covered by passing ``use_kernel=True`` in the unit
-    tests.  Both paths produce identical bytes (same update, same drop
-    semantics), so store contents never depend on the dispatch."""
-    from repro.kernels.slab_writeback import (DEFAULT_BLOCK_ROWS,
-                                              slab_writeback_pallas)
+    sentinel ``n`` (dropped); ``rows`` (U, d) — the slab.  On a TPU this is
+    the aliased Pallas kernel (:mod:`repro.kernels.slab_writeback`), which
+    touches only the addressed rows of the store.  Elsewhere the default is
+    XLA's drop-mode scatter — the interpreter would serialize U Python
+    iterations per chunk — and the tests force the kernel with
+    ``use_kernel=True``.  Both paths produce identical bytes (same update,
+    same drop semantics), so store contents never depend on the
+    dispatch."""
+    from repro.kernels.slab_writeback import slab_writeback_pallas
     if use_kernel is None:
-        use_kernel = not INTERPRET
+        use_kernel = not _interpret()
     if not use_kernel:
         if accumulate:
             return full.at[idx].add(rows, mode="drop")
         return full.at[idx].set(rows, mode="drop")
-    n = full.shape[0]
-    u = idx.shape[0]
-    block = min(DEFAULT_BLOCK_ROWS, u)
-    pad = (-u) % block
-    idx = jnp.pad(idx, (0, pad), constant_values=n)
-    rows = jnp.pad(rows, ((0, pad), (0, 0)))
     return slab_writeback_pallas(full, idx, rows, accumulate=accumulate,
-                                 block_rows=block, interpret=INTERPRET)
+                                 interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("levels",))
 def quantize(x: jax.Array, key: jax.Array, levels: int = 15) -> jax.Array:
     """Unbiased row-wise stochastic quantization of x: (R, C)."""
     u = jax.random.uniform(key, x.shape, jnp.float32)
-    return quantize_pallas(x, u, levels, interpret=INTERPRET)
+    return quantize_pallas(x, u, levels, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("levels",))
@@ -121,7 +112,7 @@ def quantize_with_u(x: jax.Array, u: jax.Array, levels: int = 15
                     ) -> jax.Array:
     """Row-wise quantization with EXTERNAL uniforms (the compress plan layer
     draws them once so dense and fused backends dither identically)."""
-    return quantize_pallas(x, u, levels, interpret=INTERPRET)
+    return quantize_pallas(x, u, levels, interpret=_interpret())
 
 
 def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, b: jax.Array,
@@ -148,7 +139,7 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, b: jax.Array,
     cg = jnp.broadcast_to(c[:, None], (B, H, S, N)).reshape(G, nc, chunk, N)
 
     y_diag, states, decays, acs = ssd_chunk_pallas(
-        xg, dtg, Ag, bg, cg, interpret=INTERPRET)
+        xg, dtg, Ag, bg, cg, interpret=_interpret())
 
     def scan_fn(s, inp):
         st, dk = inp                               # (G,N,P), (G,)

@@ -23,7 +23,7 @@ from typing import Dict, Optional
 import jax
 
 from repro.configs import all_arch_ids, get_config
-from repro.launch.mesh import enter_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import memory_per_device
 from repro.launch.specs import SHAPES, input_specs, shape_supported
 from repro.optim.distributed import DashaTrainConfig
@@ -53,7 +53,7 @@ def dryrun_one(arch: str, shape: str, *, multi_pod: bool = False,
         # aliases it in-place instead of double-buffering ~2x the state.
         donate = (0,) if spec.static.get("kind") == "train" else \
             ((1,) if spec.static.get("kind") == "decode" else ())
-        with enter_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(spec.fn,
                              in_shardings=to_shardings(spec.in_shardings,
                                                        mesh),

@@ -13,36 +13,3 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """1-device mesh for CPU smoke runs (same axis names as single pod)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def enter_mesh(mesh):
-    """Version-portable ``with jax.set_mesh(mesh):`` context.
-
-    jax >= 0.6 has jax.set_mesh; 0.5.x has jax.sharding.use_mesh; on 0.4.x
-    the Mesh object itself is the context manager (the classic pjit idiom).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use = getattr(jax.sharding, "use_mesh", None)
-    if use is not None:
-        return use(mesh)
-    return mesh
-
-
-def abstract_mesh(shape, axes):
-    """Version-portable AbstractMesh((16, 16), ("data", "model")).
-
-    jax >= 0.5 takes positional (axis_sizes, axis_names); 0.4.36-0.4.38
-    take a single tuple of (name, size) pairs.  Spec-validation tests build
-    these (no devices needed), so they must work on every pinned jax.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))

@@ -80,6 +80,30 @@ def _batch_sharding(cfg: ArchConfig, mesh: Mesh, batch: int,
     return out
 
 
+def node_state_specs(cfg: ArchConfig, params_s: Any, mesh: Mesh,
+                     dasha: DashaTrainConfig, opt_state_s: Any):
+    """PartitionSpecs of the DASHA trainer state on ``mesh``.
+
+    Returns ``(param_specs, state_param_specs, per_node_specs, opt_specs)``:
+    the per-param specs pinned onto each node's gradient; the specs of
+    params and g (FSDP over the data axes when ``dasha.fsdp``); each
+    node's h_i / g_i with the node axis on the data axes (plain specs after
+    it — the node axis already occupies the data axes); and the server
+    optimizer's state, shaped like ``opt_state_s``."""
+    dp = dp_axes(mesh)
+    p_specs = param_specs(cfg, params_s, mesh)
+    p_specs_f = param_specs(cfg, params_s, mesh, fsdp=dasha.fsdp)
+    per_node = jax.tree_util.tree_map(
+        lambda s: P(dp, *tuple(s)), p_specs,
+        is_leaf=lambda x: isinstance(x, P))
+    if dasha.server_opt == "adam":
+        from repro.optim.base import AdamState
+        opt_specs: Any = AdamState(mu=p_specs_f, nu=p_specs_f, count=P())
+    else:
+        opt_specs = jax.tree_util.tree_map(lambda x: P(), opt_state_s)
+    return p_specs, p_specs_f, per_node, opt_specs
+
+
 # ---------------------------------------------------------------------------
 # train (DASHA data-parallel nodes x tensor parallel)
 # ---------------------------------------------------------------------------
@@ -110,30 +134,16 @@ def train_spec(cfg: ArchConfig, mesh: Mesh, *, seq: int, global_batch: int,
         with expert_sharding(exp_axis):
             return lm.loss_fn(cfg, p, b, seq_shard=seq_axis)[0]
 
-    # shardings: FSDP specs for params/g/opt; plain specs for per-node state
-    # (the node axis already occupies the data axes there).
-    p_specs = param_specs(cfg, params_s, mesh)
-    p_specs_f = param_specs(cfg, params_s, mesh, fsdp=dasha.fsdp)
-
+    p_specs, p_specs_f, per_node, opt_specs = node_state_specs(
+        cfg, params_s, mesh, dasha, state_s.opt_state)
     step = make_train_step(dasha, node_loss, grad_specs=p_specs)
-
-    def node_specs(specs):
-        return jax.tree_util.tree_map(
-            lambda s: P(dp, *tuple(s)), specs,
-            is_leaf=lambda x: isinstance(x, P))
-
-    if dasha.server_opt == "adam":
-        from repro.optim.base import AdamState
-        opt_specs: Any = AdamState(mu=p_specs_f, nu=p_specs_f, count=P())
-    else:
-        opt_specs = jax.tree_util.tree_map(lambda x: P(), state_s.opt_state)
 
     from repro.optim.distributed import DashaTrainState
     state_specs = DashaTrainState(
         params=p_specs_f,
         g=p_specs_f,
-        h_local=node_specs(p_specs),
-        g_local=node_specs(p_specs),
+        h_local=per_node,
+        g_local=per_node,
         opt_state=opt_specs,
         key=P(), step=P())
     batch_specs_ = _batch_sharding(cfg, mesh, global_batch, node_axis=True)

@@ -1,53 +1,91 @@
 """End-to-end DASHA training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch starcoder2-3b \
-        --steps 200 --nodes 4 --batch 2 --seq 128 [--smoke/--full] \
+        --steps 200 --nodes 4 --batch 2 --seq 128 [--full [--layers N]] \
         --compression 0.03125 --variant dasha \
         [--ckpt out/ckpt --ckpt-every 1 --resume]
 
-The whole experiment now runs through the compiled driver (DESIGN.md §10):
-batches are drawn INSIDE the jitted scan (``data_fn``), so the per-step
-host round-trip of the old Python loop (eager batch generation +
-``eval_loss`` + metric ``float()`` casts serializing against the device)
-is gone — the host only wakes up once per ``--chunk`` rounds to log and
-checkpoint.  Checkpoints hold the FULL ``MethodState`` (params, h_i, g_i,
-optimizer state, RNG key, round counter), so ``--resume`` continues
-bit-identically with the same data stream (per-round data keys are
-``fold_in(data_seed, t)``).
+The whole experiment runs through the compiled driver (DESIGN.md §10):
+batches are drawn INSIDE the jitted scan (``data_fn``), so the host only
+wakes up once per ``--chunk`` rounds to log and checkpoint.  Checkpoints
+hold the FULL ``MethodState`` (params, h_i, g_i, optimizer state, RNG key,
+round counter), so ``--resume`` continues bit-identically with the same
+data stream (per-round data keys are ``fold_in(data_seed, t)``).
 
-On this CPU container the driver runs the REDUCED (smoke) config of the
-selected architecture family on a 1-device mesh — the same code path that
-the dry-run lowers for the 256/512-chip production meshes.  ``--full``
-selects the assigned full config (only sensible on a real cluster).
-``REPRO_EXAMPLE_ROUNDS`` overrides ``--steps`` for CI smoke jobs.
+By default the driver runs the reduced (smoke) config of the selected
+architecture family.  ``--full`` selects the published config, and
+``--layers N`` cuts its depth to N layers — the only field it changes — so
+that one chip's share of a deployment fits one chip.
+
+Placement: with one visible device the n nodes are vmapped on it.  With
+several (``--devices`` caps how many are used), the nodes go onto a
+``("data", "model")`` mesh of those devices with ``data`` = device count:
+each device holds its nodes' h_i, g_i and batch, and params, g and the
+server optimizer are replicated.  ``REPRO_EXAMPLE_ROUNDS`` overrides
+``--steps`` for CI smoke jobs.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
 import os
 import time
+from pathlib import Path
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.io import (checkpoint_step, load_method_state,
                                  save_method_state)
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTextConfig, make_node_batches
+from repro.methods import MethodState
 from repro.methods.driver import Driver
 from repro.models import init_params, lm
 from repro.optim.distributed import (DashaTrainConfig, make_method,
                                      payload_frac)
 
+#: the compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: one
+#: fixed directory in the checkout (the path is part of the cache key)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def main(argv=None) -> int:
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed; otherwise the cache goes to :data:`CACHE_DIR`."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+
+
+class TrainRun(NamedTuple):
+    """What :func:`main` leaves behind: the final state, the mesh it lived
+    on (``None`` on one device) and one ``{"step", "loss", "g_norm_sq"}``
+    record per logged step."""
+
+    state: MethodState
+    mesh: Optional[jax.sharding.Mesh]
+    log: List[Dict[str, float]]
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b")
     ap.add_argument("--full", action="store_true",
-                    help="use the full assigned config (cluster only)")
+                    help="use the published config instead of the smoke one")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the config's depth to this many layers")
+    ap.add_argument("--dtype", default=None, choices=["bfloat16", "float32"],
+                    help="parameter dtype (default: the config's)")
     ap.add_argument("--steps", type=int,
                     default=int(os.environ.get("REPRO_EXAMPLE_ROUNDS", 100)))
     ap.add_argument("--nodes", type=int, default=4)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices for the node mesh (default: all visible)")
     ap.add_argument("--batch", type=int, default=2, help="per-node batch")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--gamma", type=float, default=0.003)
@@ -73,28 +111,89 @@ def main(argv=None) -> int:
                     help="scan-segment length (default: --log-every)")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
+
+def arch_config(arch: str, full: bool, layers: Optional[int] = None,
+                dtype: Optional[str] = None):
+    """The model config of ``--arch``/``--full``, its depth cut to
+    ``layers`` and its parameter dtype set to ``dtype`` when given (no
+    other field changes)."""
+    cfg = get_config(arch) if full else get_smoke_config(arch)
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    if dtype is not None:
+        cfg = dataclasses.replace(cfg, dtype=dtype)
+    return cfg
+
+
+def node_mesh(n_nodes: int, n_devices: Optional[int] = None):
+    """The ``("data", "model")`` mesh the nodes are spread over, or
+    ``None`` when one device holds them all."""
+    devices = jax.devices()[:n_devices]
+    if len(devices) == 1:
+        return None
+    if n_nodes % len(devices):
+        raise SystemExit(f"--nodes {n_nodes} is not a multiple of the "
+                         f"{len(devices)} devices")
+    return jax.make_mesh((len(devices), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto),
+                         devices=devices)
+
+
+def _state_shardings(cfg, params_s, mesh, dasha, state_s):
+    from repro.launch.specs import node_state_specs
+    from repro.models.sharding import to_shardings
+    p_specs, p_specs_f, per_node, opt_specs = node_state_specs(
+        cfg, params_s, mesh, dasha, state_s.opt_state)
+    specs = MethodState(x=p_specs_f, g=p_specs_f, g_local=per_node,
+                        h_local=per_node, opt_state=opt_specs, key=P(),
+                        t=P(), bits_sent=P())
+    return p_specs, to_shardings(specs, mesh)
+
+
+def main(argv=None) -> TrainRun:
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = arch_config(args.arch, args.full, args.layers, args.dtype)
+    mesh = node_mesh(args.nodes, args.devices)
     key = jax.random.PRNGKey(args.seed)
     k_init, k_state, k_data = jax.random.split(key, 3)
 
-    params = init_params(cfg, k_init)
-    n_params = sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
-    print(f"[train] arch={cfg.name} params={n_params/1e6:.2f}M "
-          f"nodes={args.nodes} tokens/step={args.nodes*args.batch*args.seq}")
+    params_s = jax.eval_shape(lambda k: init_params(cfg, k), k_init)
+    n_params = sum(int(x.size) for x in jax.tree_util.tree_leaves(params_s))
+    cut = "" if args.layers is None else \
+        f" (depth cut from {arch_config(args.arch, args.full).num_layers})"
+    print(f"[train] arch={cfg.name} layers={cfg.num_layers}{cut} "
+          f"d_model={cfg.d_model} vocab={cfg.vocab_size} dtype={cfg.dtype} "
+          f"params={n_params/1e6:.2f}M nodes={args.nodes} "
+          f"devices={1 if mesh is None else mesh.devices.size} "
+          f"tokens/step={args.nodes*args.batch*args.seq}")
 
     dasha = DashaTrainConfig(
         gamma=args.gamma, compression=args.compression, mode=args.mode,
         variant=args.variant, b=args.mvr_b, p=args.coin_p,
         n_nodes=args.nodes,
-        server_opt=args.server_opt, use_kernel=args.use_kernel)
+        server_opt=args.server_opt, use_kernel=args.use_kernel,
+        spmd_axes=None if mesh is None else ("data",))
 
     def node_loss(p, b):
         return lm.loss_fn(cfg, p, b)[0]
 
     method = make_method(dasha, node_loss)
-    state = method.init(params, k_state, init_mode="zeros")
+
+    def init_state(k_params, k_method):
+        return method.init(init_params(cfg, k_params), k_method,
+                           init_mode="zeros")
+
+    if mesh is None:
+        state = jax.jit(init_state)(k_init, k_state)
+    else:
+        state_s = jax.eval_shape(init_state, k_init, k_state)
+        grad_specs, shardings = _state_shardings(cfg, params_s, mesh, dasha,
+                                                 state_s)
+        state = jax.jit(init_state, out_shardings=shardings)(k_init, k_state)
+        method = make_method(dasha, node_loss, grad_specs=grad_specs)
     done = 0
     if args.resume:
         if not args.ckpt:
@@ -104,16 +203,20 @@ def main(argv=None) -> int:
         print(f"[train] resumed from {args.ckpt} at step {done}")
 
     tcfg = SyntheticTextConfig(vocab_size=cfg.vocab_size, seq_len=args.seq)
-    data_kw = {}
+    data_kw: Dict[str, Any] = {}
     if cfg.arch_type == "vlm":
         data_kw = dict(with_images=cfg.num_image_tokens,
                        d_model=cfg.d_model, dtype=cfg.jax_dtype)
     if cfg.arch_type == "audio":
         data_kw = dict(with_frames=cfg.num_audio_frames,
                        d_model=cfg.d_model, dtype=cfg.jax_dtype)
+    node_axis = None if mesh is None else NamedSharding(mesh, P("data"))
 
     def data_fn(k, t):
-        return make_node_batches(k, tcfg, args.nodes, args.batch, **data_kw)
+        batch = make_node_batches(k, tcfg, args.nodes, args.batch, **data_kw)
+        if node_axis is not None:
+            batch = jax.lax.with_sharding_constraint(batch, node_axis)
+        return batch
 
     def g_norm_sq(s, b):
         return sum(jnp.sum(jnp.square(x))
@@ -131,12 +234,16 @@ def main(argv=None) -> int:
     chunk = args.chunk or args.log_every
     drv = Driver(method, data_fn=data_fn,
                  metrics={"g_norm_sq": g_norm_sq}, chunk=chunk)
+    log: List[Dict[str, float]] = []
     t0 = time.time()
 
     def hook(ms, t, tr):
-        print(f"[train] step {done + t:5d} "
-              f"loss={float(eval_loss(ms.x)):.4f} "
-              f"|g|^2={float(tr['g_norm_sq'][-1]):.3e} "
+        rec = {"step": done + t, "loss": float(eval_loss(ms.x)),
+               "g_norm_sq": float(tr["g_norm_sq"][-1])}
+        log.append(rec)
+        print(f"[train] step {rec['step']:5d} "
+              f"loss={rec['loss']:.4f} "
+              f"|g|^2={rec['g_norm_sq']:.3e} "
               f"payload={frac:.4f} "
               f"coords/node={float(ms.bits_sent):.3e} "
               f"({time.time()-t0:.1f}s)")
@@ -146,15 +253,20 @@ def main(argv=None) -> int:
     remaining = args.steps - done
     if remaining <= 0:
         print(f"[train] checkpoint already at step {done} >= {args.steps}")
-        return 0
-    state, _ = drv.run(state, remaining, data_key=k_data,
-                       checkpoint=hook, checkpoint_every=args.ckpt_every)
+        return TrainRun(state, mesh, log)
+    # the sharding specs inside the step name mesh axes: trace under it
+    with contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh):
+        state, _ = drv.run(state, remaining, data_key=k_data,
+                           checkpoint=hook, checkpoint_every=args.ckpt_every,
+                           donate_input=True)
+    jax.block_until_ready(state)
     if args.ckpt:
         print(f"[train] saved full method state to {args.ckpt}")
     sps = remaining / max(time.time() - t0, 1e-9)
-    print(f"[train] done: {remaining} rounds at {sps:.2f} steps/s")
-    return 0
+    print(f"[train] done: {remaining} rounds at {sps:.2f} steps/s "
+          f"(compilation included)")
+    return TrainRun(state, mesh, log)
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

@@ -19,6 +19,8 @@ Key contracts:
   uninterrupted one (resume bit-identity, tested in tests/test_driver.py).
 * **Donation is safe**: the caller's input state is defensively copied
   before the first donating call; only driver-internal carries are donated.
+  A caller that drops its state (``run(..., donate_input=True)``) skips the
+  copy, which would otherwise hold the state twice in device memory.
   On backends without donation support (CPU) donation is auto-disabled.
 * ``sweep(method_fn, values, state, rounds, ...)`` vmaps the chunk runner
   over a hyperparameter axis (the Appendix-A powers-of-two stepsize tunes):
@@ -196,7 +198,8 @@ class Driver:
 
     def run(self, state, rounds: int, *, data_key: Optional[jax.Array] = None,
             checkpoint: Optional[Callable] = None,
-            checkpoint_every: int = 1, obs=None):
+            checkpoint_every: int = 1, obs=None,
+            donate_input: bool = False):
         """Drive ``rounds`` rounds; returns ``(final_state, traces)`` with
         ``traces`` a dict of length-``rounds`` arrays (named metrics plus
         ``bits_sent`` when the state carries it).
@@ -205,7 +208,9 @@ class Driver:
         ``checkpoint_every``-th chunk and after the final one.  ``obs`` is
         an optional :class:`repro.obs.Obs` handle: per-chunk HOST-track
         wall spans, compile spans and ``driver.*`` metrics — recorded
-        between chunks, never inside traced code.
+        between chunks, never inside traced code.  ``donate_input=True``
+        hands the caller's ``state`` buffers to the first chunk: the caller
+        must not read them afterwards.
         """
         if self.data_fn is not None and data_key is None:
             raise ValueError("data_fn requires an explicit data_key")
@@ -216,7 +221,7 @@ class Driver:
             return state, _empty_traces(
                 self.metrics, state, template,
                 bits=hasattr(state, "bits_sent"))
-        if self.donate:
+        if self.donate and not donate_input:
             # the first donating call would invalidate the caller's buffers
             state = jax.tree_util.tree_map(jnp.copy, state)
         chunk = self.chunk or min(rounds, DEFAULT_CHUNK)

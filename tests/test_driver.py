@@ -117,6 +117,21 @@ def test_method_run_is_a_driver_shim():
     np.testing.assert_array_equal(np.asarray(trace), np.asarray(t3))
 
 
+def test_donated_input_state_runs_the_same_rounds():
+    """With donation on, the driver copies the caller's state unless it is
+    handed over (``donate_input=True``); the rounds are the same, and a
+    copied input stays readable."""
+    m, st0 = _dasha()
+    ref, _ = drv.Driver(m, chunk=3, donate=False).run(st0, 7)
+    driver = drv.Driver(m, chunk=3, donate=True)
+    kept, _ = driver.run(st0, 7)
+    _assert_states_equal(kept, ref)
+    np.asarray(st0.x)                     # the caller's input is intact
+    own = jax.tree_util.tree_map(jnp.copy, st0)
+    handed, _ = driver.run(own, 7, donate_input=True)
+    _assert_states_equal(handed, ref)
+
+
 def test_zero_rounds_returns_empty_traces():
     m, st0 = _dasha()
     f, t = drv.run(m, st0, 0,

@@ -3,16 +3,15 @@ both production meshes (validated with AbstractMesh — no devices needed)."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import all_arch_ids, get_config
-from repro.launch.mesh import abstract_mesh
 from repro.models import init_params, lm
 from repro.models.sharding import cache_specs, dp_axes, dp_size, param_specs
 
 MESHES = {
-    "single_pod": abstract_mesh((16, 16), ("data", "model")),
-    "multi_pod": abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    "single_pod": AbstractMesh((16, 16), ("data", "model")),
+    "multi_pod": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 

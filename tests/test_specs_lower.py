@@ -7,14 +7,13 @@ import sys
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import abstract_mesh
 from repro.launch.specs import input_specs, shape_supported
 from repro.optim.distributed import DashaTrainConfig
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
