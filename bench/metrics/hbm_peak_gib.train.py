@@ -1,0 +1,7 @@
+"""Peak device memory of the run, ``peak_bytes_in_use`` after the window,
+on the fullest chip, in GiB."""
+
+
+def read(ctx):
+    peak = ctx["memory_peak_bytes"]
+    return peak / 2 ** 30 if peak else None

@@ -1,0 +1,7 @@
+"""Share of the traced window in which no op ran on the device (mean over
+chips): 1 - union of device op intervals / window."""
+
+
+def read(ctx):
+    share = ctx["trace"].idle_share()
+    return None if share is None else 100.0 * share
