@@ -13,7 +13,9 @@ axis and GSPMD shardings.  This module is the ONE place that bridges them:
   not mvr" restriction.
 
 All masks come from :mod:`repro.compress.plan`, so the dense and fused paths
-are parity-testable under the same key.
+are parity-testable under the same key.  Every mask draw runs under the
+``dasha.compress`` named scope (the mean over nodes under
+``dasha.aggregate``), which names its ops in a device trace.
 """
 from __future__ import annotations
 
@@ -78,8 +80,9 @@ def bernoulli_compress(key: jax.Array, delta: PyTree, p: float,
 
     if specs is None:
         specs = _none_specs(delta)
-    return jax.tree_util.tree_map(leaf, leaf_keys(key, delta), delta, specs,
-                                  is_leaf=_spec_leaf)
+    with jax.named_scope("dasha.compress"):
+        return jax.tree_util.tree_map(leaf, leaf_keys(key, delta), delta,
+                                      specs, is_leaf=_spec_leaf)
 
 
 def permk_compress(key: jax.Array, delta: PyTree, n: int,
@@ -106,12 +109,14 @@ def permk_compress(key: jax.Array, delta: PyTree, n: int,
         # disjoint supports => the mean recovers exactly node owner(c)'s
         # value at c; computed as a plain mean so GSPMD emits ONE reduce
         # over the node axis.
-        return m, jnp.mean(m.astype(jnp.float32), 0)
+        with jax.named_scope("dasha.aggregate"):
+            return m, jnp.mean(m.astype(jnp.float32), 0)
 
     if specs is None:
         specs = _none_specs(delta)
-    pairs = jax.tree_util.tree_map(leaf, leaf_keys(key, delta), delta, specs,
-                                   is_leaf=_spec_leaf)
+    with jax.named_scope("dasha.compress"):
+        pairs = jax.tree_util.tree_map(leaf, leaf_keys(key, delta), delta,
+                                       specs, is_leaf=_spec_leaf)
     m = jax.tree_util.tree_map(lambda p_: p_[0], pairs, is_leaf=_is_pair)
     agg = jax.tree_util.tree_map(lambda p_: p_[1], pairs, is_leaf=_is_pair)
     return m, agg
@@ -149,8 +154,9 @@ def tree_masks(key: jax.Array, tree: PyTree, *, mode: str, p: float, n: int,
 
     if specs is None:
         specs = _none_specs(tree)
-    masks = jax.tree_util.tree_map(leaf, leaf_keys(key, tree), tree, specs,
-                                   is_leaf=_spec_leaf)
+    with jax.named_scope("dasha.compress"):
+        masks = jax.tree_util.tree_map(leaf, leaf_keys(key, tree), tree,
+                                       specs, is_leaf=_spec_leaf)
     scale = float(n) if mode == "permk" else 1.0 / p
     return masks, scale
 

@@ -67,8 +67,8 @@ from repro.methods.accounting import downlink_receivers
 from repro.methods.engine import FaultStep, Hyper, Method
 from repro.methods.rules import get_rule
 from repro.methods.substrates import gather_slab_rows, slab_layout
-from repro.obs import timeline as obs_timeline
 from repro.obs.handle import maybe as _obs_scope
+from repro.obs.handle import span
 from repro.obs.timeline import SERVER, client_track, record_fed_round
 
 X_BYTES_PER_COORD = 4                  # the server broadcast is dense fp32
@@ -418,36 +418,31 @@ class FedSim:
         self._compiled[("slab", length, metric_fn)] = fn
         return fn
 
-    def _slab_enter(self, state, uniq_pad: np.ndarray, tl=None):
+    def _slab_enter(self, state, uniq_pad: np.ndarray, h=None):
         """Swap the (n, d) store out of the carry: gather the chunk's
         touched rows into the slab; the full arrays wait on the side for
-        :meth:`_slab_exit`'s once-per-chunk writeback.  A live timeline
-        (``tl``) gets the gather as a HOST-track wall span."""
+        :meth:`_slab_exit`'s once-per-chunk writeback.  The gather is the
+        ``fed.slab_gather`` span (:func:`repro.obs.span`)."""
         idx = jnp.asarray(uniq_pad)
-        t0 = None if tl is None else tl.now()
-        st = state._replace(h_local=gather_slab_rows(state.h_local, idx),
-                            g_local=gather_slab_rows(state.g_local, idx))
-        if tl is not None:
-            tl.span(obs_timeline.HOST, "slab_gather", t0, tl.now(),
-                    rows=int(uniq_pad.size))
+        with span(h, "fed.slab_gather", rows=int(uniq_pad.size)):
+            st = state._replace(
+                h_local=gather_slab_rows(state.h_local, idx),
+                g_local=gather_slab_rows(state.g_local, idx))
         return st, state.h_local, state.g_local
 
     def _slab_exit(self, state, uniq_pad: np.ndarray, full_h, full_g,
-                   tl=None):
+                   h=None):
         """Per-chunk writeback: one O(U·d) scatter into the store (the
         aliased Pallas kernel on compiled backends, XLA drop-scatter
-        under interpret — :func:`repro.kernels.ops.slab_writeback`)."""
+        under interpret — :func:`repro.kernels.ops.slab_writeback`), as
+        the ``fed.slab_writeback`` span."""
         idx = jnp.asarray(uniq_pad)
-        t0 = None if tl is None else tl.now()
-        out = state._replace(
-            h_local=ops.slab_writeback(full_h, idx, state.h_local),
-            g_local=ops.slab_writeback(full_g, idx, state.g_local))
-        if tl is not None:
-            tl.span(obs_timeline.HOST, "slab_writeback", t0, tl.now(),
-                    rows=int(uniq_pad.size))
-        return out
+        with span(h, "fed.slab_writeback", rows=int(uniq_pad.size)):
+            return state._replace(
+                h_local=ops.slab_writeback(full_h, idx, state.h_local),
+                g_local=ops.slab_writeback(full_g, idx, state.g_local))
 
-    def _run_chunk(self, state, length: int, metric_fn, tl=None):
+    def _run_chunk(self, state, length: int, metric_fn, h=None):
         """One engine chunk on the active store: the slab path precomputes
         the cohort schedule from ``state.key`` (the same stateless key
         chain the engine folds in-jit), gathers the touched rows, scans
@@ -456,10 +451,10 @@ class FedSim:
         if self.slab:
             sels = self.substrate.cohort_schedule(state.key, length)
             uniq, loc = slab_layout(sels, self.n)
-            st, full_h, full_g = self._slab_enter(state, uniq, tl)
+            st, full_h, full_g = self._slab_enter(state, uniq, h)
             st, ys = self._chunk_fn_slab(length, metric_fn)(
                 st, jnp.asarray(sels), jnp.asarray(loc))
-            state = self._slab_exit(st, uniq, full_h, full_g, tl)
+            state = self._slab_exit(st, uniq, full_h, full_g, h)
         else:
             state, ys = self._chunk_fn(length, metric_fn)(state)
         return state, ys
@@ -615,8 +610,7 @@ class FedSim:
         done = start_round
         while done < rounds:
             length = min(self.chunk, rounds - done)
-            state, ys = self._run_chunk(state, length, metric_fn,
-                                        h.timeline)
+            state, ys = self._run_chunk(state, length, metric_fn, h)
             ys = jax.device_get(ys)                # ONE transfer per chunk
             for j in range(length):
                 t = done + j
@@ -788,8 +782,7 @@ class FedSim:
             if sync:
                 # retries recover every message: the engine runs the
                 # fault-free scan, states bit-identical to no faults
-                state, ys = self._run_chunk(state, length, metric_fn,
-                                            h.timeline)
+                state, ys = self._run_chunk(state, length, metric_fn, h)
             else:
                 keys = self._key_chain(state.key, length)
                 present = np.stack([np.asarray(self._present_fn(k), bool)
@@ -1071,8 +1064,8 @@ class FedSim:
                 # chunked scan — bit-identical jaxpr, bit-identical states
                 if buf_off == buf_len:
                     buf_len = min(self.chunk, rounds - t)
-                    state, buf = self._run_chunk(state, buf_len, metric_fn,
-                                                 h.timeline)
+                    state, buf = self._run_chunk(state, buf_len,
+                                                 metric_fn, h)
                     buf = jax.device_get(buf)
                     buf_off = 0
                 ys, j = buf, buf_off

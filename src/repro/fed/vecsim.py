@@ -37,7 +37,6 @@ Throughput: >= 10x the heap reference at n >= 1024
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -56,7 +55,7 @@ from repro.methods.rules import get_rule
 from repro.methods.substrates import gather_slab_rows as _gather_rows
 from repro.methods.substrates import slab_layout
 from repro.obs.handle import maybe as _obs_scope
-from repro.obs.timeline import HOST
+from repro.obs.handle import span
 
 
 @dataclasses.dataclass
@@ -284,49 +283,29 @@ class VecFedSim:
         mu_c = np.take_along_axis(mu, sels, axis=1)
         return sels, uniq_pad, loc, md_c, mu_c
 
-    def _slab_enter(self, state, uniq_pad: np.ndarray, tl=None):
+    def _slab_enter(self, state, uniq_pad: np.ndarray, h=None):
         """Swap the (n, d) store out of the carry: gather the chunk's
         touched rows into the slab.  Returns (slab_state, full_h, full_g)
         — the full arrays stay on host/device UNTOUCHED until
-        :meth:`_slab_exit` scatters the slab back once per chunk.  A live
-        timeline (``tl``) gets the gather as a HOST-track wall span."""
+        :meth:`_slab_exit` scatters the slab back once per chunk.  The
+        gather is the ``vec.slab_gather`` span (:func:`repro.obs.span`)."""
         idx = jnp.asarray(uniq_pad)
-        t0 = None if tl is None else tl.now()
-        st = state._replace(h_local=_gather_rows(state.h_local, idx),
-                            g_local=_gather_rows(state.g_local, idx))
-        if tl is not None:
-            tl.span(HOST, "slab_gather", t0, tl.now(),
-                    rows=int(uniq_pad.size))
+        with span(h, "vec.slab_gather", rows=int(uniq_pad.size)):
+            st = state._replace(h_local=_gather_rows(state.h_local, idx),
+                                g_local=_gather_rows(state.g_local, idx))
         return st, state.h_local, state.g_local
 
     def _slab_exit(self, state, uniq_pad: np.ndarray, full_h, full_g,
-                   tl=None):
+                   h=None):
         """Per-chunk writeback: one O(U·d) scatter into the store (the
         aliased Pallas kernel on compiled backends, XLA drop-scatter under
-        interpret — :func:`repro.kernels.ops.slab_writeback`)."""
+        interpret — :func:`repro.kernels.ops.slab_writeback`), as the
+        ``vec.slab_writeback`` span."""
         idx = jnp.asarray(uniq_pad)
-        t0 = None if tl is None else tl.now()
-        out = state._replace(
-            h_local=ops.slab_writeback(full_h, idx, state.h_local),
-            g_local=ops.slab_writeback(full_g, idx, state.g_local))
-        if tl is not None:
-            tl.span(HOST, "slab_writeback", t0, tl.now(),
-                    rows=int(uniq_pad.size))
-        return out
-
-    def _obs_chunk(self, h, t0: float, done: int, length: int) -> None:
-        """Per-chunk host record: a HOST-track wall span + a chunk
-        duration histogram (callers guard with ``if h`` — a disabled
-        handle costs one falsy check per chunk)."""
-        dt = time.perf_counter() - t0
-        tl = h.timeline
-        if tl is not None:
-            end = tl.now()
-            tl.span(HOST, "chunk", end - dt, end,
-                    start_round=int(done), rounds=int(length))
-        hist = h.histogram("vec.chunk_s")
-        if hist is not None:
-            hist.observe(dt)
+        with span(h, "vec.slab_writeback", rows=int(uniq_pad.size)):
+            return state._replace(
+                h_local=ops.slab_writeback(full_h, idx, state.h_local),
+                g_local=ops.slab_writeback(full_g, idx, state.g_local))
 
     def run(self, state, rounds: int, *,
             metric_fn: Optional[Callable] = None, obs=None,
@@ -334,7 +313,8 @@ class VecFedSim:
             checkpoint: Optional[Callable] = None) -> SimResult:
         """``obs`` is an optional :class:`repro.obs.Obs` handle.  The
         scan emits per-round scalars only, so a live timeline here gets
-        HOST-track chunk / slab spans (wall time) plus compile spans; the
+        the ``vec.chunk`` / ``vec.slab_gather`` / ``vec.slab_writeback``
+        HOST-track spans (wall time) plus compile spans; the
         per-client simulated-time view is reconstructed post hoc by
         :func:`repro.obs.reconstruct_vec_timeline` from this run's
         result.  A metrics registry gets the same campaign aggregates
@@ -403,24 +383,20 @@ class VecFedSim:
             for j in range(length):
                 md[j], mu[j] = round_multipliers(
                     streams[done + j], self.downlink, self.uplink, n)
-            t0 = time.perf_counter() if h else 0.0
-            if self.slab:
-                sels, uniq, loc, md_c, mu_c = self._slab_chunk_xs(
-                    state, length, md, mu)
-                st, full_h, full_g = self._slab_enter(state, uniq,
-                                                      h.timeline)
-                st, ys = self._chunk_fn_slab(length, metric_fn)(
-                    st, jnp.asarray(md_c), jnp.asarray(mu_c),
-                    jnp.asarray(sels), jnp.asarray(loc))
-                state = self._slab_exit(st, uniq, full_h, full_g,
-                                        h.timeline)
-            else:
-                state, ys = self._chunk_fn(length, metric_fn)(
-                    state, jnp.asarray(md), jnp.asarray(mu))
-            part = jax.device_get(ys)              # ONE transfer per chunk
-            parts.append(part)
-            if h:
-                self._obs_chunk(h, t0, done, length)
+            with span(h, "vec.chunk", start_round=done, rounds=length):
+                if self.slab:
+                    sels, uniq, loc, md_c, mu_c = self._slab_chunk_xs(
+                        state, length, md, mu)
+                    st, full_h, full_g = self._slab_enter(state, uniq, h)
+                    st, ys = self._chunk_fn_slab(length, metric_fn)(
+                        st, jnp.asarray(md_c), jnp.asarray(mu_c),
+                        jnp.asarray(sels), jnp.asarray(loc))
+                    state = self._slab_exit(st, uniq, full_h, full_g, h)
+                else:
+                    state, ys = self._chunk_fn(length, metric_fn)(
+                        state, jnp.asarray(md), jnp.asarray(mu))
+                part = jax.device_get(ys)          # ONE transfer per chunk
+                parts.append(part)
             done += length
             if checkpoint is not None:
                 now = float(self._seq_wall(part["round_t"], now)[-1])
@@ -796,26 +772,24 @@ class VecFedSim:
                     streams[done + j], self.downlink, self.uplink, n)
             crash_off = fc.crashed[sl] | fc.drop_down[sl]
             lostx = fc.drop_up[sl] | fc.corrupt[sl]
-            t0 = time.perf_counter() if h else 0.0
-            if sync:
-                fn = self._chunk_fn_sync_faulted(length, metric_fn)
-                state, ys = fn(state, jnp.asarray(md), jnp.asarray(mu),
-                               jnp.asarray(crash_off), jnp.asarray(lostx),
-                               jnp.asarray(fc.first_success[sl]),
-                               jnp.asarray(fc.up_attempts[sl]),
-                               jnp.asarray(fc.capped[sl]))
-            else:
-                fn = self._chunk_fn_graceful_faulted(length, metric_fn,
-                                                     reset_mode)
-                args = (jnp.asarray(md), jnp.asarray(mu),
-                        jnp.asarray(crash_off), jnp.asarray(lostx))
-                if reset_mode:
-                    args += (jnp.asarray(fc.rejoin[sl]),)
-                state, ys = fn(state, *args)
-            part = jax.device_get(ys)              # ONE transfer per chunk
-            parts.append(part)
-            if h:
-                self._obs_chunk(h, t0, done, length)
+            with span(h, "vec.chunk", start_round=done, rounds=length):
+                if sync:
+                    fn = self._chunk_fn_sync_faulted(length, metric_fn)
+                    state, ys = fn(state, jnp.asarray(md), jnp.asarray(mu),
+                                   jnp.asarray(crash_off), jnp.asarray(lostx),
+                                   jnp.asarray(fc.first_success[sl]),
+                                   jnp.asarray(fc.up_attempts[sl]),
+                                   jnp.asarray(fc.capped[sl]))
+                else:
+                    fn = self._chunk_fn_graceful_faulted(length, metric_fn,
+                                                         reset_mode)
+                    args = (jnp.asarray(md), jnp.asarray(mu),
+                            jnp.asarray(crash_off), jnp.asarray(lostx))
+                    if reset_mode:
+                        args += (jnp.asarray(fc.rejoin[sl]),)
+                    state, ys = fn(state, *args)
+                part = jax.device_get(ys)          # ONE transfer per chunk
+                parts.append(part)
             done += length
             if checkpoint is not None:
                 now = float(self._seq_wall(part["round_t"], now)[-1])
@@ -1111,42 +1085,38 @@ class VecFedSim:
             for j in range(length):
                 md[j], mu[j] = round_multipliers(
                     streams[done + j], self.downlink, self.uplink, n)
-            t0 = time.perf_counter() if h else 0.0
-            if self.slab:
-                sels, uniq, loc, md_c, mu_c = self._slab_chunk_xs(
-                    state, length, md, mu)
-                st, full_h, full_g = self._slab_enter(state, uniq,
-                                                      h.timeline)
-                if tau >= 1:
-                    carry = (st, free, ring_a, ring_floor, ring_m,
-                             ring_sel, flush)
+            with span(h, "vec.chunk", start_round=done, rounds=length):
+                if self.slab:
+                    sels, uniq, loc, md_c, mu_c = self._slab_chunk_xs(
+                        state, length, md, mu)
+                    st, full_h, full_g = self._slab_enter(state, uniq, h)
+                    if tau >= 1:
+                        carry = (st, free, ring_a, ring_floor, ring_m,
+                                 ring_sel, flush)
+                    else:
+                        carry = (st, free, ring_a, ring_floor, flush)
+                    carry, ys = self._chunk_fn_async_slab(length, metric_fn)(
+                        carry, jnp.asarray(md_c), jnp.asarray(mu_c),
+                        jnp.asarray(sels), jnp.asarray(loc))
+                    if tau >= 1:
+                        st, free, ring_a, ring_floor, ring_m, ring_sel, \
+                            flush = carry
+                    else:
+                        st, free, ring_a, ring_floor, flush = carry
+                    state = self._slab_exit(st, uniq, full_h, full_g, h)
                 else:
-                    carry = (st, free, ring_a, ring_floor, flush)
-                carry, ys = self._chunk_fn_async_slab(length, metric_fn)(
-                    carry, jnp.asarray(md_c), jnp.asarray(mu_c),
-                    jnp.asarray(sels), jnp.asarray(loc))
-                if tau >= 1:
-                    st, free, ring_a, ring_floor, ring_m, ring_sel, \
-                        flush = carry
-                else:
-                    st, free, ring_a, ring_floor, flush = carry
-                state = self._slab_exit(st, uniq, full_h, full_g,
-                                        h.timeline)
-            else:
-                if tau >= 1:
-                    carry = (state, free, ring_a, ring_floor, ring_m,
-                             flush)
-                else:
-                    carry = (state, free, ring_a, ring_floor, flush)
-                carry, ys = self._chunk_fn_async(length, metric_fn)(
-                    carry, jnp.asarray(md), jnp.asarray(mu))
-                if tau >= 1:
-                    state, free, ring_a, ring_floor, ring_m, flush = carry
-                else:
-                    state, free, ring_a, ring_floor, flush = carry
-            parts.append(jax.device_get(ys))       # ONE transfer per chunk
-            if h:
-                self._obs_chunk(h, t0, done, length)
+                    if tau >= 1:
+                        carry = (state, free, ring_a, ring_floor, ring_m,
+                                 flush)
+                    else:
+                        carry = (state, free, ring_a, ring_floor, flush)
+                    carry, ys = self._chunk_fn_async(length, metric_fn)(
+                        carry, jnp.asarray(md), jnp.asarray(mu))
+                    if tau >= 1:
+                        state, free, ring_a, ring_floor, ring_m, flush = carry
+                    else:
+                        state, free, ring_a, ring_floor, flush = carry
+                parts.append(jax.device_get(ys))   # ONE transfer per chunk
             done += length
         ys = {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
 

@@ -85,6 +85,7 @@ def dasha_update_pallas(grad: jax.Array, h: jax.Array, g_local: jax.Array,
         out_specs=out_specs,
         out_shape=(shape, shape, shape),
         interpret=interpret,
+        name="dasha_update",
     )(jnp.full((1,), a, grad.dtype), jnp.full((1,), scale, grad.dtype),
       grad, h, g_local, mask)
 
@@ -108,6 +109,7 @@ def dasha_mvr_update_pallas(grad_new: jax.Array, grad_old: jax.Array,
         out_specs=out_specs,
         out_shape=(shape, shape, shape),
         interpret=interpret,
+        name="dasha_mvr_update",
     )(jnp.full((1,), a, dt), jnp.full((1,), b, dt), jnp.full((1,), scale, dt),
       grad_new, grad_old, h, g_local, mask)
 
@@ -148,4 +150,5 @@ def quantize_pallas(x: jax.Array, u: jax.Array, levels: int, *,
         out_specs=tens,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="quantize",
     )(jnp.full((1,), levels, jnp.float32), x, u)

@@ -31,6 +31,13 @@ def _to_lanes(x: jax.Array) -> Tuple[jax.Array, int]:
     return flat.reshape(-1, LANE), d
 
 
+def _mask_to_lanes(mask: jax.Array) -> jax.Array:
+    """The mask cast into the kernel's layout belongs to the mask draw: it
+    runs under the compression plan's named scope."""
+    with jax.named_scope("dasha.compress"):
+        return _to_lanes(mask)[0]
+
+
 def _from_lanes(x2: jax.Array, d: int, shape, dtype) -> jax.Array:
     return x2.reshape(-1)[:d].reshape(shape).astype(dtype)
 
@@ -47,7 +54,7 @@ def dasha_update(grad: jax.Array, h: jax.Array, g_local: jax.Array,
     g2, d = _to_lanes(grad)
     h2, _ = _to_lanes(h)
     gl2, _ = _to_lanes(g_local)
-    mk2, _ = _to_lanes(mask)
+    mk2 = _mask_to_lanes(mask)
     m, hn, gln = dasha_update_pallas(g2, h2, gl2, mk2, a, scale,
                                      interpret=_interpret())
     def back(t):
@@ -65,7 +72,7 @@ def dasha_mvr_update(grad_new: jax.Array, grad_old: jax.Array, h: jax.Array,
     go2, _ = _to_lanes(grad_old)
     h2, _ = _to_lanes(h)
     gl2, _ = _to_lanes(g_local)
-    mk2, _ = _to_lanes(mask)
+    mk2 = _mask_to_lanes(mask)
     m, hn, gln = dasha_mvr_update_pallas(gn2, go2, h2, gl2, mk2, a, b, scale,
                                          interpret=_interpret())
     def back(t):

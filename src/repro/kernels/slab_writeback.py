@@ -88,5 +88,6 @@ def slab_writeback_pallas(full: jax.Array, idx: jax.Array, rows: jax.Array,
         out_shape=jax.ShapeDtypeStruct((d, n), full.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="slab_writeback",
     )(idx, count, full.T, rows.T)
     return out.T

@@ -92,4 +92,5 @@ def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
                    jax.ShapeDtypeStruct((G, nc), f32),
                    jax.ShapeDtypeStruct((G, nc, Q), f32)),
         interpret=interpret,
+        name="ssd_chunk",
     )(x, dt, A, b, c)

@@ -35,14 +35,12 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional, Tuple
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.handle import maybe as _obs_scope
-from repro.obs.timeline import HOST
+from repro.obs.handle import span
 
 PyTree = Any
 MetricFn = Callable[[Any, Any], jax.Array]       # (state, data) -> scalar
@@ -82,17 +80,19 @@ def _scan_chunk(step, data_fn, data, metrics: Dict[str, MetricFn],
         # one (the held value between evaluations restarts at 0 per run()
         # call — metric_every=1, the default, holds nothing)
         t = _round_index(st, i0 + j)
-        d = data if data_fn is None else \
-            data_fn(jax.random.fold_in(data_key, t), t)
+        with jax.named_scope("driver.data"):
+            d = data if data_fn is None else \
+                data_fn(jax.random.fold_in(data_key, t), t)
         new = step(st, d)
         vals = {}
-        for name, fn in metrics.items():
-            if metric_every > 1:
-                vals[name] = jax.lax.cond(t % metric_every == 0,
-                                          lambda _: fn(new, d),
-                                          lambda _: last[name], None)
-            else:
-                vals[name] = fn(new, d)
+        with jax.named_scope("driver.metrics"):
+            for name, fn in metrics.items():
+                if metric_every > 1:
+                    vals[name] = jax.lax.cond(t % metric_every == 0,
+                                              lambda _: fn(new, d),
+                                              lambda _: last[name], None)
+                else:
+                    vals[name] = fn(new, d)
         out = dict(vals)
         bits = getattr(new, "bits_sent", None)
         if bits is not None:
@@ -122,6 +122,12 @@ def _data_template(data_fn, data, data_key):
                           jax.ShapeDtypeStruct((), jnp.int32))
 
 
+def _fetch(h, traces):
+    """One chunk's traces to the host, as the ``driver.fetch`` span."""
+    with span(h, "driver.fetch"):
+        return jax.device_get(traces)
+
+
 def _empty_traces(metrics, state, data_template, bits: bool):
     tr = {name: jnp.zeros((0,) + s.shape, s.dtype)
           for name, s in ((n, jax.eval_shape(f, state, data_template))
@@ -129,28 +135,6 @@ def _empty_traces(metrics, state, data_template, bits: bool):
     if bits:
         tr["bits_sent"] = jnp.zeros((0,), jnp.float32)
     return tr
-
-
-def _obs_driver_chunk(h, t0: float, start_round: int,
-                      length: int) -> None:
-    """Per-chunk host record for the driver loops: a HOST-track wall span
-    plus the ``driver.chunk_s`` histogram (callers guard with ``if h`` —
-    disabled observability is one falsy check per chunk)."""
-    dt = time.perf_counter() - t0
-    tl = h.timeline
-    if tl is not None:
-        end = tl.now()
-        tl.span(HOST, "chunk", end - dt, end,
-                start_round=int(start_round), rounds=int(length))
-    hist = h.histogram("driver.chunk_s")
-    if hist is not None:
-        hist.observe(dt)
-
-
-def _obs_driver_done(h, rounds: int) -> None:
-    c = h.counter("driver.rounds")
-    if c is not None:
-        c.inc(int(rounds))
 
 
 class Driver:
@@ -206,9 +190,11 @@ class Driver:
 
         ``checkpoint(state, rounds_done, chunk_traces)`` fires after every
         ``checkpoint_every``-th chunk and after the final one.  ``obs`` is
-        an optional :class:`repro.obs.Obs` handle: per-chunk HOST-track
-        wall spans, compile spans and ``driver.*`` metrics — recorded
-        between chunks, never inside traced code.  ``donate_input=True``
+        an optional :class:`repro.obs.Obs` handle: HOST-track wall spans
+        of the run's set-up and of each chunk's dispatch, trace fetch and
+        hook call, and compile spans — recorded between chunks, never inside traced code.  The
+        same spans reach a running profiler as ``repro.driver.*``
+        annotations, with or without ``obs``.  ``donate_input=True``
         hands the caller's ``state`` buffers to the first chunk: the caller
         must not read them afterwards.
         """
@@ -216,38 +202,37 @@ class Driver:
             raise ValueError("data_fn requires an explicit data_key")
         if data_key is None:
             data_key = jax.random.PRNGKey(0)        # unused
-        template = _data_template(self.data_fn, self.data, data_key)
-        if rounds <= 0:
-            return state, _empty_traces(
-                self.metrics, state, template,
-                bits=hasattr(state, "bits_sent"))
-        if self.donate and not donate_input:
-            # the first donating call would invalidate the caller's buffers
-            state = jax.tree_util.tree_map(jnp.copy, state)
+        with span(obs, "driver.prepare"):
+            template = _data_template(self.data_fn, self.data, data_key)
+            if rounds <= 0:
+                return state, _empty_traces(
+                    self.metrics, state, template,
+                    bits=hasattr(state, "bits_sent"))
+            if self.donate and not donate_input:
+                # the first donating call would invalidate the caller's
+                # buffers
+                state = jax.tree_util.tree_map(jnp.copy, state)
+            carry = (state, jnp.zeros((), jnp.int32),
+                     _metric_zeros(self.metrics, state, template))
         chunk = self.chunk or min(rounds, DEFAULT_CHUNK)
-        carry = (state, jnp.zeros((), jnp.int32),
-                 _metric_zeros(self.metrics, state, template))
         done, n_chunk, parts = 0, 0, []
         with _obs_scope(obs) as h:
             while done < rounds:
                 length = min(chunk, rounds - done)
-                t0 = time.perf_counter() if h else 0.0
-                carry, tr = self._chunk_fn(length)(carry, data_key)
+                with span(h, "driver.dispatch", start_round=done,
+                          rounds=length):
+                    carry, tr = self._chunk_fn(length)(carry, data_key)
                 done += length
                 n_chunk += 1
                 # one transfer per chunk (CPU default): the traces leave
                 # the device as they stream, so finishing a run never
                 # dispatches a many-operand XLA concatenate over live
                 # chunk buffers
-                parts.append(jax.device_get(tr) if self.host_traces
-                             else tr)
-                if h:
-                    _obs_driver_chunk(h, t0, done - length, length)
+                parts.append(_fetch(h, tr) if self.host_traces else tr)
                 if checkpoint is not None and \
                         (done >= rounds or n_chunk % checkpoint_every == 0):
-                    checkpoint(carry[0], done, tr)
-            if h:
-                _obs_driver_done(h, rounds)
+                    with span(h, "driver.checkpoint", rounds_done=done):
+                        checkpoint(carry[0], done, tr)
         cat = np.concatenate if self.host_traces else jnp.concatenate
         traces = {k: cat([p[k] for p in parts]) for k in parts[0]}
         return carry[0], traces
@@ -319,8 +304,7 @@ class Sweeper:
             data_key: Optional[jax.Array] = None, obs=None):
         """Run ``rounds`` rounds of every lane; returns ``(final_states,
         traces)`` with a leading (G,) axis on every state leaf and
-        (G, rounds) traces.  ``obs`` as in :meth:`Driver.run` (the
-        ``driver.rounds`` counter bills rounds x lanes)."""
+        (G, rounds) traces.  ``obs`` as in :meth:`Driver.run`."""
         values = jax.tree_util.tree_map(jnp.asarray, values)
         leaves = jax.tree_util.tree_leaves(values)
         if not leaves:
@@ -330,26 +314,24 @@ class Sweeper:
             raise ValueError("data_fn requires an explicit data_key")
         if data_key is None:
             data_key = jax.random.PRNGKey(0)        # unused
-        template = _data_template(self.data_fn, self.data, data_key)
+        with span(obs, "driver.prepare"):
+            template = _data_template(self.data_fn, self.data, data_key)
+            stacked = jax.tree_util.tree_map(
+                lambda l: jnp.tile(l, (G,) + (1,) * jnp.ndim(l)), state)
+            carry = (stacked, jnp.zeros((G,), jnp.int32),
+                     _metric_zeros(self.metrics, state, template,
+                                   batch_shape=(G,)))
         chunk = self.chunk or min(rounds, DEFAULT_CHUNK)
-        stacked = jax.tree_util.tree_map(
-            lambda l: jnp.tile(l, (G,) + (1,) * jnp.ndim(l)), state)
-        carry = (stacked, jnp.zeros((G,), jnp.int32),
-                 _metric_zeros(self.metrics, state, template,
-                               batch_shape=(G,)))
         done, parts = 0, []
         with _obs_scope(obs) as h:
             while done < rounds:
                 length = min(chunk, rounds - done)
-                t0 = time.perf_counter() if h else 0.0
-                carry, tr = self._chunk_fn(length)(values, carry, data_key)
+                with span(h, "driver.dispatch", start_round=done,
+                          rounds=length, lanes=G):
+                    carry, tr = self._chunk_fn(length)(values, carry,
+                                                       data_key)
                 done += length
-                parts.append(jax.device_get(tr) if self.host_traces
-                             else tr)
-                if h:
-                    _obs_driver_chunk(h, t0, done - length, length)
-            if h and rounds > 0:
-                _obs_driver_done(h, rounds * G)
+                parts.append(_fetch(h, tr) if self.host_traces else tr)
         cat = np.concatenate if self.host_traces else jnp.concatenate
         traces = {k: cat([p[k] for p in parts], axis=1)
                   for k in parts[0]} if parts else {}

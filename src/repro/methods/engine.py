@@ -10,7 +10,11 @@ Algorithm 1 (and Algorithm 2's sync round, and MARINA's) written ONCE:
     [coin]   with prob p: dense sync round (where-selected) # Alg. 2 / MARINA
 
 Everything variant-specific lives in :mod:`repro.methods.rules`; everything
-representation-specific lives in :mod:`repro.methods.substrates`.  The RNG
+representation-specific lives in :mod:`repro.methods.substrates`.  Each line
+runs under a ``jax.named_scope`` (``dasha.server`` / ``dasha.oracle`` /
+``dasha.node_update`` / ``dasha.aggregate``; the substrates add
+``dasha.compress`` and ``dasha.aggregate`` inside the node update), which
+names the compiled ops in a device trace and adds no op.  The RNG
 contract reproduces the seed's flat loop exactly
 (``key, k_h, k_c, k_coin = split(key, 4)``), so the legacy
 :mod:`repro.core.dasha` entry points are bit-identical shims over this
@@ -251,8 +255,9 @@ class Method(NamedTuple):
             # line 4 (server) + broadcast
             g_vis = state.g if deficit is None \
                 else sub.sub_deficit(state.g, deficit)
-            x_new, opt_state = sub.server_update(state.x, g_vis,
-                                                 state.opt_state, hp)
+            with jax.named_scope("dasha.server"):
+                x_new, opt_state = sub.server_update(state.x, g_vis,
+                                                     state.opt_state, hp)
             # sampled-client substrates window the round onto a gathered
             # (C, d) cohort slice: the h-update and estimator run at
             # O(C*d), then scatter back; the full path takes the unsliced
@@ -282,22 +287,25 @@ class Method(NamedTuple):
                 h_prev = jnp.where(rmask, jnp.zeros_like(h_prev), h_prev)
                 g_prev = jnp.where(rmask, jnp.zeros_like(g_prev), g_prev)
             # line 8: THE variant-specific line
-            h_new, aux = rule.h_update(rsub, k_h, hp, x_new, state.x,
-                                       h_prev, data)
+            with jax.named_scope("dasha.oracle"):
+                h_new, aux = rule.h_update(rsub, k_h, hp, x_new, state.x,
+                                           h_prev, data)
             # lines 9-10: m_i = C_i(drift); g_i <- g_i + m_i
             msgs = present = None
-            if hasattr(rsub, "estimator_update_full"):
-                agg, h_out, g_local, payload, msgs, present = \
-                    rsub.estimator_update_full(
+            with jax.named_scope("dasha.node_update"):
+                if hasattr(rsub, "estimator_update_full"):
+                    agg, h_out, g_local, payload, msgs, present = \
+                        rsub.estimator_update_full(
+                            k_c, h_new, h_prev, g_prev, a_eff, aux)
+                else:
+                    agg, h_out, g_local, payload = rsub.estimator_update(
                         k_c, h_new, h_prev, g_prev, a_eff, aux)
-            else:
-                agg, h_out, g_local, payload = rsub.estimator_update(
-                    k_c, h_new, h_prev, g_prev, a_eff, aux)
             if rsub is not sub:
                 # unsampled rows FREEZE: offline clients compute nothing
                 h_out = rsub.scatter_nodes(state.h_local, h_out)
                 g_local = rsub.scatter_nodes(state.g_local, g_local)
-            g = sub.add_server(state.g, agg)                   # line 14
+            with jax.named_scope("dasha.aggregate"):
+                g = sub.add_server(state.g, agg)               # line 14
             if faults is not None:
                 if msgs is None:
                     raise ValueError(
@@ -308,8 +316,9 @@ class Method(NamedTuple):
                 # pre-round — post-reset — (h_i, g_i).  bits_sent still
                 # charges the upload: the client DID transmit.
                 dmask = faults.drop[:, None]
-                g = g - sub.mean_nodes(
-                    jnp.where(dmask, msgs.dense(), 0.0))
+                with jax.named_scope("dasha.aggregate"):
+                    g = g - sub.mean_nodes(
+                        jnp.where(dmask, msgs.dense(), 0.0))
                 h_out = jnp.where(dmask, h_prev, h_out)
                 g_local = jnp.where(dmask, g_prev, g_local)
                 if reset_corr is not None:
@@ -319,10 +328,12 @@ class Method(NamedTuple):
                 # Alg. 2 lines 9-11 / MARINA: with prob p ALL nodes upload
                 # a fresh dense megabatch gradient instead
                 coin = jax.random.bernoulli(k_coin, hp.p)
-                h_sync = rule.sync_update(sub, k_h, hp, x_new, data)
+                with jax.named_scope("dasha.oracle"):
+                    h_sync = rule.sync_update(sub, k_h, hp, x_new, data)
                 h_out = sub.where(coin, h_sync, h_out)
                 g_local = sub.where(coin, h_sync, g_local)
-                g = sub.where(coin, sub.mean_nodes(h_sync), g)
+                with jax.named_scope("dasha.aggregate"):
+                    g = sub.where(coin, sub.mean_nodes(h_sync), g)
             round_pay = accounting.round_payload(
                 payload, sub.dense_coords(h_out), coin)
             new = MethodState(x=x_new, g=g, g_local=g_local,

@@ -745,6 +745,14 @@ def _leaf_size(leaf) -> float:
     return sz
 
 
+def _mean_nodes(per_node):
+    """The f32 mean over the leading node axis of every leaf (line 14's
+    aggregate), as the ``dasha.aggregate`` scope."""
+    with jax.named_scope("dasha.aggregate"):
+        return jax.tree_util.tree_map(
+            lambda h: jnp.mean(h.astype(jnp.float32), 0), per_node)
+
+
 @dataclasses.dataclass(frozen=True)
 class TreeCompression:
     """Tree-native compression: the trainer's mode knob over
@@ -766,7 +774,6 @@ class TreeCompression:
                    for l in jax.tree_util.tree_leaves(per_node_tree))
 
     def estimator_update(self, key, h_new, h, g_local, a: float, aux=None):
-        f32 = jnp.float32
         if self.use_kernel:
             if isinstance(aux, MvrFusion):
                 # recompute the momentum h-update INSIDE the kernel pass
@@ -778,9 +785,7 @@ class TreeCompression:
                 m, h_out, gl = fused_tree_update(
                     key, h_new, h, g_local, mode=self.mode, a=a, p=self.p,
                     n=self.n, variant="dasha", specs=self.specs)
-            agg = jax.tree_util.tree_map(
-                lambda mm: jnp.mean(mm.astype(f32), 0), m)
-            return agg, h_out, gl, self.payload_per_node(h_new)
+            return _mean_nodes(m), h_out, gl, self.payload_per_node(h_new)
 
         delta = jax.tree_util.tree_map(
             lambda hn, hh, gl_: hn - hh - a * (gl_ - hh),
@@ -790,8 +795,7 @@ class TreeCompression:
         else:
             m = bernoulli_compress(key, delta, self.p, specs=self.specs,
                                    shared=self.mode == "shared_coords")
-            agg = jax.tree_util.tree_map(
-                lambda mm: jnp.mean(mm.astype(f32), 0), m)
+            agg = _mean_nodes(m)
         gl_new = jax.tree_util.tree_map(jnp.add, g_local, m)
         return agg, h_new, gl_new, self.payload_per_node(h_new)
 
@@ -895,8 +899,7 @@ class TreeSubstrate:
             lambda a_, b_: jnp.where(coin, a_, b_), a, b)
 
     def mean_nodes(self, per_node):
-        return jax.tree_util.tree_map(
-            lambda h: jnp.mean(h.astype(jnp.float32), 0), per_node)
+        return _mean_nodes(per_node)
 
     def add_server(self, g, agg):
         return jax.tree_util.tree_map(jnp.add, g, agg)

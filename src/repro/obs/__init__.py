@@ -1,6 +1,6 @@
 """repro.obs — campaign telemetry (DESIGN.md §17).
 
-Four layers, all host-side (observability never touches traced code —
+Four layers, all host-side (observability adds no op to traced code —
 attaching it adds zero compiles and < 3% wall-clock, both gated):
 
 * :mod:`~repro.obs.timeline` — event timelines: per-client message
@@ -17,9 +17,11 @@ attaching it adds zero compiles and < 3% wall-clock, both gated):
 
 Entry point: build an :class:`Obs` handle and pass it as ``obs=`` to
 ``FedSim.run`` / ``VecFedSim.run`` / ``Driver.run`` / ``Sweeper.run``.
+The run loops mark their host work with :func:`span` (a ``repro.<name>``
+profiler annotation, and a HOST-track span when a timeline is live).
 """
 from .attrib import Attribution, ClientStats, attribute, report
-from .handle import NULL, Obs, maybe
+from .handle import NULL, Obs, maybe, span
 from .metrics import (Counter, Gauge, Histogram, JsonlSink, MemorySink,
                       MetricsRegistry, read_jsonl)
 from .timeline import (COMPILER, HOST, SERVER, Timeline, TimelineEvent,
@@ -28,7 +30,7 @@ from .vecreplay import reconstruct_vec_timeline
 
 __all__ = [
     "Attribution", "ClientStats", "attribute", "report",
-    "NULL", "Obs", "maybe",
+    "NULL", "Obs", "maybe", "span",
     "Counter", "Gauge", "Histogram", "JsonlSink", "MemorySink",
     "MetricsRegistry", "read_jsonl",
     "COMPILER", "HOST", "SERVER", "Timeline", "TimelineEvent",

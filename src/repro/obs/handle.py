@@ -4,11 +4,16 @@ Every run loop in the repo accepts ``obs=None``: an :class:`Obs` bundles
 an optional :class:`~repro.obs.timeline.Timeline` and an optional
 :class:`~repro.obs.metrics.MetricsRegistry`, and the loops guard every
 recording with ``if obs`` — disabled observability is a single falsy
-check per chunk, no traced-code change, zero extra compiles (the
+check per chunk, no op added to traced code, zero extra compiles (the
 ``recompile.watch`` gate in tests/test_obs.py and the
 ``obs_overhead_frac`` gate in benchmarks/fed_scale_bench.py hold the
 enabled path to the same contract: < 3% wall-clock, 0 steady-state
 compiles).
+
+:func:`span` is the loops' one way to mark host work: a profiler
+annotation named ``repro.<name>`` (so a device trace shows what the host
+did between programs) plus, with a live timeline, the same span on its
+HOST track.
 """
 from __future__ import annotations
 
@@ -16,9 +21,11 @@ import contextlib
 import dataclasses
 from typing import Any, Dict, Iterator, Optional
 
+import jax
+
 from repro.obs.metrics import (Counter, Gauge, Histogram, JsonlSink,
                                MetricsRegistry)
-from repro.obs.timeline import COMPILER, Timeline
+from repro.obs.timeline import COMPILER, HOST, Timeline
 
 
 @dataclasses.dataclass
@@ -117,3 +124,23 @@ def maybe(obs: Optional[Obs]) -> Iterator[Obs]:
     h = obs or NULL
     with h.compile_spans():
         yield h
+
+
+#: prefix of the program's host spans in a profiler trace
+SPAN_PREFIX = "repro."
+
+
+@contextlib.contextmanager
+def span(obs: Optional[Obs], name: str, **args) -> Iterator[None]:
+    """Mark the host work inside the block as ``name``.
+
+    Always opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
+    which is one inactive check when no profiler runs; when ``obs`` has a
+    timeline, also records the block as a HOST-track wall span ``name``
+    with ``args``."""
+    tl = None if obs is None else obs.timeline
+    t0 = 0.0 if tl is None else tl.now()
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+        yield
+    if tl is not None:
+        tl.span(HOST, name, t0, tl.now(), **args)

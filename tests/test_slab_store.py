@@ -278,3 +278,24 @@ def test_simulate_threads_the_store_knob():
                  rounds=8, seed=3, engine="vec", store="slab")
     for k in a.traces:
         assert np.array_equal(a.traces[k], b.traces[k]), k
+
+
+@pytest.mark.parametrize("cls,prefix", [(VecFedSim, "vec"), (FedSim, "fed")])
+def test_slab_spans_on_the_host_track(cls, prefix):
+    """Each chunk's gather and writeback are HOST-track spans of a live
+    timeline (through :func:`repro.obs.span`), with the slab's rows."""
+    from repro.obs import Obs
+    from repro.obs.timeline import HOST
+    sim = _sim(cls, "dasha", 37, 9, store="slab")
+    obs = Obs.full()
+    sim.run(sim.init(jnp.zeros(D), jax.random.PRNGKey(42)), 15, obs=obs)
+    host = [e for e in obs.timeline.events if e.track == HOST]
+    chunks = -(-15 // 7)
+    for name in ("slab_gather", "slab_writeback"):
+        spans = [e for e in host if e.name == f"{prefix}.{name}"]
+        assert len(spans) == chunks
+        assert all(e.args["rows"] > 0 and e.t1 >= e.t0 for e in spans)
+    if cls is VecFedSim:
+        assert [e.args for e in host if e.name == "vec.chunk"] == [
+            {"start_round": r, "rounds": min(7, 15 - r)} for r in (0, 7, 14)]
+    assert obs.timeline.validate() == []

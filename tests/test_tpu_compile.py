@@ -89,3 +89,67 @@ def test_slab_writeback_compiles_for_v5e(one_chip, accumulate):
     assert mem.temp_size_in_bytes < store_bytes
     hlo = compiled.as_text()
     assert " copy(" not in hlo and "S(1)" not in hlo
+
+
+@pytest.mark.parametrize("variant,kernel", [("dasha", "dasha_update"),
+                                            ("mvr", "dasha_mvr_update")])
+def test_node_update_kernel_is_named_in_its_scope(one_chip, monkeypatch,
+                                                  variant, kernel):
+    """The compiled DASHA step calls the fused kernel under its pallas_call
+    name, inside the engine's ``dasha.node_update`` scope (the device
+    trace's per-layer split reads both)."""
+    import re
+
+    from repro.kernels import ops
+    from repro.optim.distributed import DashaTrainConfig, make_method
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    f32 = jnp.float32
+
+    def loss(p, b):
+        return jnp.mean((b["x"] @ p["w"] - b["y"]) ** 2)
+
+    cfg = DashaTrainConfig(gamma=0.01, compression=0.25, n_nodes=2,
+                           variant=variant, use_kernel=True)
+    method = make_method(cfg, loss)
+    params = {"w": jax.ShapeDtypeStruct((256, LANE), f32)}
+    state = jax.eval_shape(lambda p, k: method.init(p, k, init_mode="zeros"),
+                           params, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    batch = {"x": jax.ShapeDtypeStruct((2, 8, 256), f32),
+             "y": jax.ShapeDtypeStruct((2, 8, LANE), f32)}
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    hlo = jax.jit(method.step).lower(placed(state), placed(batch)) \
+        .compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", line), line[:80]
+        assert re.search(r'op_name="[^"]*/dasha\.node_update/', line)
+
+
+def test_kernel_head_matches_the_recorded_v5e_trace(one_chip, monkeypatch):
+    """The recorded v5e trace's kernel event and the kernel's instruction
+    in a v5e compile of the same call have one head: the key by which the
+    benchmark's trace reader (``bench/scopes.py``) finds a traced op's
+    scope in the compiled listing."""
+    from jax.profiler import ProfileData
+
+    from bench import scopes, trace
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    args = [jax.ShapeDtypeStruct((2048, LANE), jnp.float32,
+                                 sharding=one_chip)] * 4
+    listing = jax.jit(lambda g, h, gl, m: ops.dasha_update(
+        g, h, gl, m, 0.2, 32.0)).lower(*args).compile().as_text()
+    names = scopes.hlo_op_names([listing])
+    recorded = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                            "tests", "data", "small.xplane.pb")
+    tr = trace.from_profile(ProfileData.from_file(recorded))
+    kernel = {scopes.head(n) for n, _, _ in tr.ops[0]
+              if trace.short_name(n) == "dasha_update"}
+    assert len(kernel) == 1 and kernel <= set(names)
