@@ -17,7 +17,10 @@ Phases (one chip):
   and ``|g|^2`` of every logged step must be finite.
 * ``kernel-vs-jnp``: one DASHA step with the fused kernel and one with the
   jnp path from the same state and key; the messages m_i, h_i and g_i must
-  agree to f32 rounding.
+  agree to f32 rounding.  Then the keyed kernels, which draw the mask
+  themselves, against the explicit-mask kernels fed ``draw_mask``'s mask,
+  at every leaf size of the nodes' state: m_i, h_i and g_i must be
+  bit-equal (max |diff| = 0).
 * ``fed``: the sampled ``VecFedSim`` campaign of
   ``benchmarks/fed_scale_bench.py`` (n=1e5 clients, cohort C=64, d=64) for
   a few 200-round chunks through the compiled slab writeback, then one
@@ -32,6 +35,7 @@ steps, and compares the loss at every logged step and the final params.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import sys
@@ -169,7 +173,77 @@ def phase_kernel_vs_jnp(compiles: list) -> None:
                                  f"{float(ref):.3e})")
         out.append(f"{name} max|diff|={float(diff):.3e} "
                    f"(max|ref|={float(ref):.3e})")
+    del state, lowered, b0, b1
+    out.append(_keyed_vs_mask(cfg, args.nodes, args.compression))
     done("; ".join(out))
+
+
+def _keyed_vs_mask(cfg, nodes: int, p: float) -> str:
+    """The keyed kernels (mask drawn inside) against the explicit-mask
+    kernels fed ``draw_mask``'s mask under the same key, at the lane-layout
+    size of every leaf of the nodes' state: m, h_i and g_i must be
+    bit-equal, for DASHA and MVR."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.compress.plan import draw_mask, u8_threshold
+    from repro.kernels import dasha_update as kern
+    from repro.models import init_params
+
+    thresh = u8_threshold(p)
+    if thresh is None:
+        raise AssertionError(f"compression {p} is no multiple of 1/256")
+    shapes = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    sizes = sorted({-(-nodes * x.size // kern.LANE)
+                    for x in jax.tree_util.tree_leaves(shapes)})
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def inputs(key, rows):
+        mask = draw_mask(key, (rows, kern.LANE), p).astype(jnp.float32)
+        return tuple(jax.random.normal(k, (rows, kern.LANE)) for k in
+                     jax.random.split(jax.random.fold_in(key, 1), 3)) + (mask,)
+
+    # the mask comes in as an argument, as in the step: drawn in the same
+    # program, an interpreted kernel would let XLA fold it into a select
+    @functools.partial(jax.jit, static_argnums=5)
+    def check(g, h, gl, mask, key, variant):
+        words = key.astype(jnp.uint32)
+        if variant == "mvr":   # g_local stands in for the old gradient
+            keyed = kern.dasha_mvr_update_keyed_pallas(
+                g, gl, h, gl, words, 0.2, 0.1, 1 / p, thresh,
+                interpret=False)
+            masked = kern.dasha_mvr_update_pallas(g, gl, h, gl, mask, 0.2,
+                                                  0.1, 1 / p,
+                                                  interpret=False)
+        else:
+            keyed = kern.dasha_update_keyed_pallas(
+                g, h, gl, words, 0.2, 1 / p, thresh, interpret=False)
+            masked = kern.dasha_update_pallas(g, h, gl, mask, 0.2, 1 / p,
+                                              interpret=False)
+        return (jnp.max(jnp.stack([jnp.max(jnp.abs(a - b))
+                                   for a, b in zip(keyed, masked)])),
+                sum(jnp.sum(bits(a) != bits(b))
+                    for a, b in zip(keyed, masked)))
+
+    key = jax.random.PRNGKey(7)
+    worst = 0.0
+    for variant in ("dasha", "mvr"):
+        for i, rows in enumerate(sizes):
+            k = jax.random.fold_in(key, i)
+            diff, unequal = jax.device_get(
+                check(*inputs(k, rows), k, variant))
+            worst = max(worst, float(diff))
+            if int(unequal):
+                raise AssertionError(
+                    f"keyed {variant} kernel at {rows} rows: {int(unequal)} "
+                    f"elements differ from the explicit mask's, max|diff| "
+                    f"{float(diff):.3e}")
+    return (f"keyed vs mask kernels (dasha, mvr) at {len(sizes)} leaf sizes "
+            f"up to {sizes[-1]} rows: max|diff|={worst:.3e}, bit-equal")
 
 
 def phase_fed(compiles: list) -> None:

@@ -3,7 +3,8 @@
 Every compressor draws its randomness HERE, exactly once per round, through
 one of four primitives:
 
-* :func:`draw_mask`       — Bernoulli(p) 0/1 mask (u8-threshold fast path);
+* :func:`draw_mask`       — Bernoulli(p) 0/1 mask (u8-threshold fast path,
+                            :func:`u8_threshold`);
 * :func:`randk_indices`   — uniform K-subset without replacement (RandK);
 * :func:`perm_partition`  — a shared permutation split into n node blocks
                             (PermK, flat path);
@@ -59,14 +60,22 @@ class Plan(NamedTuple):
 # primitives
 # ---------------------------------------------------------------------------
 
+def u8_threshold(p: float) -> Optional[int]:
+    """``round(256 p)`` where the Bernoulli(p) mask is exactly
+    ``uint8 bits < round(256 p)``: p a multiple of 1/256 in (0, 1).  Else
+    ``None`` (p = 1.0 among them: uint8(256) would overflow)."""
+    thresh256 = p * 256.0
+    if abs(thresh256 - round(thresh256)) < 1e-9 and 0 < round(thresh256) < 256:
+        return round(thresh256)
+    return None
+
+
 def draw_mask(k: jax.Array, shape, p: float) -> jax.Array:
     """Bernoulli(p) mask; u8-threshold path (exact when p is a multiple of
     1/256) avoids materialising u32 bits + f32 uniforms over d elements."""
-    thresh256 = p * 256.0
-    # p=1.0 must take the bernoulli path: uint8(256) would overflow
-    if abs(thresh256 - round(thresh256)) < 1e-9 and 0 < round(thresh256) < 256:
-        return jax.random.bits(k, shape, jnp.uint8) \
-            < jnp.uint8(round(thresh256))
+    thresh = u8_threshold(p)
+    if thresh is not None:
+        return jax.random.bits(k, shape, jnp.uint8) < jnp.uint8(thresh)
     return jax.random.bernoulli(k, p, shape)
 
 

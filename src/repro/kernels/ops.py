@@ -14,7 +14,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dasha_update import (LANE, dasha_mvr_update_pallas,
+from repro.kernels.dasha_update import (LANE, dasha_mvr_update_keyed_pallas,
+                                        dasha_mvr_update_pallas,
+                                        dasha_update_keyed_pallas,
                                         dasha_update_pallas, quantize_pallas)
 
 
@@ -42,6 +44,26 @@ def _from_lanes(x2: jax.Array, d: int, shape, dtype) -> jax.Array:
     return x2.reshape(-1)[:d].reshape(shape).astype(dtype)
 
 
+def _key_words(key: jax.Array) -> jax.Array:
+    """The (2,) uint32 words of a threefry key, typed or raw."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return key.astype(jnp.uint32)
+
+
+def _fused(kernel, tensors, *scalars, mask=None, key=None):
+    """Run a node-update kernel on same-shape ``tensors`` in the lane
+    layout, then the ``mask`` in that layout or the ``key``'s words, then
+    the static ``scalars``.  Returns (m, h_new, g_local_new) with the first
+    tensor's shape and dtype."""
+    shape, dtype = tensors[0].shape, tensors[0].dtype
+    lanes = [_to_lanes(t)[0] for t in tensors]
+    extra = _key_words(key) if mask is None else _mask_to_lanes(mask)
+    outs = kernel(*lanes, extra, *scalars, interpret=_interpret())
+    return tuple(_from_lanes(t, tensors[0].size, shape, dtype)
+                 for t in outs)
+
+
 @functools.partial(jax.jit, static_argnames=("a", "scale"))
 def dasha_update(grad: jax.Array, h: jax.Array, g_local: jax.Array,
                  mask: jax.Array, a: float, scale: float
@@ -50,35 +72,39 @@ def dasha_update(grad: jax.Array, h: jax.Array, g_local: jax.Array,
 
     Returns (m, h_new, g_local_new) with the input shape/dtype.
     """
-    shape, dtype = grad.shape, grad.dtype
-    g2, d = _to_lanes(grad)
-    h2, _ = _to_lanes(h)
-    gl2, _ = _to_lanes(g_local)
-    mk2 = _mask_to_lanes(mask)
-    m, hn, gln = dasha_update_pallas(g2, h2, gl2, mk2, a, scale,
-                                     interpret=_interpret())
-    def back(t):
-        return _from_lanes(t, d, shape, dtype)
+    return _fused(dasha_update_pallas, (grad, h, g_local), a, scale,
+                  mask=mask)
 
-    return back(m), back(hn), back(gln)
+
+@functools.partial(jax.jit, static_argnames=("a", "scale", "thresh"))
+def dasha_update_keyed(grad: jax.Array, h: jax.Array, g_local: jax.Array,
+                       key: jax.Array, a: float, scale: float, thresh: int
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`dasha_update` with the mask ``jax.random.bits(key,
+    grad.shape, uint8) < thresh`` drawn inside the kernel (threefry keys,
+    typed or raw; ``grad.size`` padded to 128 at most 2**32)."""
+    return _fused(dasha_update_keyed_pallas, (grad, h, g_local), a, scale,
+                  thresh, key=key)
 
 
 @functools.partial(jax.jit, static_argnames=("a", "b", "scale"))
 def dasha_mvr_update(grad_new: jax.Array, grad_old: jax.Array, h: jax.Array,
                      g_local: jax.Array, mask: jax.Array, a: float, b: float,
                      scale: float) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    shape, dtype = grad_new.shape, grad_new.dtype
-    gn2, d = _to_lanes(grad_new)
-    go2, _ = _to_lanes(grad_old)
-    h2, _ = _to_lanes(h)
-    gl2, _ = _to_lanes(g_local)
-    mk2 = _mask_to_lanes(mask)
-    m, hn, gln = dasha_mvr_update_pallas(gn2, go2, h2, gl2, mk2, a, b, scale,
-                                         interpret=_interpret())
-    def back(t):
-        return _from_lanes(t, d, shape, dtype)
+    return _fused(dasha_mvr_update_pallas, (grad_new, grad_old, h, g_local),
+                  a, b, scale, mask=mask)
 
-    return back(m), back(hn), back(gln)
+
+@functools.partial(jax.jit, static_argnames=("a", "b", "scale", "thresh"))
+def dasha_mvr_update_keyed(grad_new: jax.Array, grad_old: jax.Array,
+                           h: jax.Array, g_local: jax.Array, key: jax.Array,
+                           a: float, b: float, scale: float, thresh: int
+                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`dasha_mvr_update` with the mask drawn inside the kernel (see
+    :func:`dasha_update_keyed`)."""
+    return _fused(dasha_mvr_update_keyed_pallas,
+                  (grad_new, grad_old, h, g_local), a, b, scale, thresh,
+                  key=key)
 
 
 @functools.partial(jax.jit, static_argnames=("accumulate", "use_kernel"))

@@ -40,6 +40,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.io import (checkpoint_step, load_method_state,
                                  save_method_state)
+from repro.compress.treelevel import kernel_draw_count
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTextConfig, make_node_batches
 from repro.methods import MethodState
@@ -176,6 +177,16 @@ def main(argv=None) -> TrainRun:
         n_nodes=args.nodes,
         server_opt=args.server_opt, use_kernel=args.use_kernel,
         spmd_axes=None if mesh is None else ("data",))
+
+    if args.use_kernel:
+        per_node = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((args.nodes,) + x.shape,
+                                           jnp.float32), params_s)
+        specs = None if mesh is None else jax.tree_util.tree_map(
+            lambda _: P("data"), per_node)
+        print("[train] mask draw: " + str(kernel_draw_count(
+            per_node, mode=args.mode, p=args.compression, specs=specs,
+            mesh=mesh)))
 
     def node_loss(p, b):
         return lm.loss_fn(cfg, p, b)[0]

@@ -87,3 +87,115 @@ def test_quantize_zero_rows_passthrough():
     x = jnp.zeros((3, 64))
     q = ops.quantize(x, KEY, 15)
     assert float(jnp.max(jnp.abs(q))) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# keyed kernels: the mask drawn inside from the leaf key
+# ---------------------------------------------------------------------------
+
+#: (4, 5): one row; (4, 37, 300): 347 rows, one block that strips do not
+#: tile, size not a multiple of 128; (4, 300, 130): 1219 rows, two blocks,
+#: the last partial; (4, 1024, 64): exactly two blocks
+KEYED_SHAPES = [(4, 5), (4, 37, 300), (4, 300, 130), (4, 1024, 64)]
+
+
+def _same_bits(got, want):
+    for x, y in zip(got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x).view(np.uint32),
+                                      np.asarray(y).view(np.uint32))
+
+
+def _keyed_inputs(shape, n=4):
+    ks = jax.random.split(jax.random.PRNGKey(sum(shape)), n)
+    return [jax.random.normal(k, shape) for k in ks]
+
+
+@pytest.mark.parametrize("thresh", [1, 8, 255])
+@pytest.mark.parametrize("shape", KEYED_SHAPES)
+def test_keyed_dasha_update_equals_explicit_mask(shape, thresh):
+    """The keyed kernel's m, h_new and g_i are bit-equal to the
+    explicit-mask kernel's fed ``draw_mask(k, shape, thresh / 256)``."""
+    from repro.compress.plan import draw_mask
+    grad, h, gl = _keyed_inputs(shape, 3)
+    key = jax.random.PRNGKey(11)
+    mask = draw_mask(key, shape, thresh / 256).astype(jnp.float32)
+    scale = 256 / thresh
+    _same_bits(ops.dasha_update_keyed(grad, h, gl, key, 0.2, scale, thresh),
+               ops.dasha_update(grad, h, gl, mask, 0.2, scale))
+
+
+@pytest.mark.parametrize("thresh", [1, 8, 255])
+@pytest.mark.parametrize("shape", KEYED_SHAPES)
+def test_keyed_dasha_mvr_update_equals_explicit_mask(shape, thresh):
+    from repro.compress.plan import draw_mask
+    gn, go, h, gl = _keyed_inputs(shape)
+    key = jax.random.PRNGKey(12)
+    mask = draw_mask(key, shape, thresh / 256).astype(jnp.float32)
+    scale = 256 / thresh
+    _same_bits(ops.dasha_mvr_update_keyed(gn, go, h, gl, key, 0.2, 0.3,
+                                          scale, thresh),
+               ops.dasha_mvr_update(gn, go, h, gl, mask, 0.2, 0.3, scale))
+
+
+@pytest.mark.parametrize("variant", ["dasha", "mvr"])
+def test_keyed_kernels_take_typed_keys(variant):
+    """A typed threefry key draws what its raw words draw, and what
+    ``jax.random.bits`` draws from it."""
+    from repro.compress.plan import draw_mask
+    shape = (4, 37, 300)
+    gn, go, h, gl = _keyed_inputs(shape)
+    typed = jax.random.key(13)
+    raw = jax.random.key_data(typed)
+    mask = draw_mask(typed, shape, 8 / 256).astype(jnp.float32)
+    if variant == "mvr":
+        outs = [ops.dasha_mvr_update_keyed(gn, go, h, gl, k, 0.2, 0.3, 32.0,
+                                           8) for k in (typed, raw)]
+        want = ops.dasha_mvr_update(gn, go, h, gl, mask, 0.2, 0.3, 32.0)
+    else:
+        outs = [ops.dasha_update_keyed(gn, h, gl, k, 0.2, 32.0, 8)
+                for k in (typed, raw)]
+        want = ops.dasha_update(gn, h, gl, mask, 0.2, 32.0)
+    for got in outs:
+        _same_bits(got, want)
+
+
+@pytest.mark.parametrize("block_rows,strip_rows", [(64, 16), (64, 8),
+                                                   (48, 32)])
+def test_keyed_kernel_counts_rows_across_blocks_and_strips(block_rows,
+                                                           strip_rows):
+    """Every block and strip hashes its own rows' flat indices: 300 rows in
+    blocks of 64 (the last partial) and strips of 16 or 8, and blocks of
+    48 that strips of 32 do not tile."""
+    from repro.compress.plan import draw_mask
+    from repro.kernels.dasha_update import (dasha_update_keyed_pallas,
+                                            dasha_update_pallas)
+    grad, h, gl = _keyed_inputs((300, 128), 3)
+    key = jax.random.PRNGKey(14)
+    mask = draw_mask(key, (300, 128), 8 / 256).astype(jnp.float32)
+    _same_bits(dasha_update_keyed_pallas(grad, h, gl, key, 0.2, 32.0, 8,
+                                         block_rows=block_rows,
+                                         strip_rows=strip_rows),
+               dasha_update_pallas(grad, h, gl, mask, 0.2, 32.0,
+                                   block_rows=block_rows))
+
+
+def test_strip_mask_counts_past_2_to_the_31():
+    """The strip's u32 counters stay exact up to 2**32 elements: the rows
+    just below it hash the flat indices a u64 count gives."""
+    from jax.extend.random import threefry2x32_p
+
+    from repro.kernels.dasha_update import strip_mask
+    k1, k2 = np.uint32(0x12345678), np.uint32(0x9ABCDEF0)
+    first = 2 ** 25 - 4                     # rows of 128: 2**32 elements
+    idx = ((first + np.arange(4, dtype=np.uint64))[:, None] * 128
+           + np.arange(128, dtype=np.uint64)[None, :])
+    assert idx.max() == 2 ** 32 - 1 and idx.min() > 2 ** 31
+    x0, x1 = threefry2x32_p.bind(
+        jnp.full((4, 128), k1), jnp.full((4, 128), k2),
+        jnp.zeros((4, 128), jnp.uint32), jnp.asarray(idx.astype(np.uint32)))
+    want = (((x0 ^ x1) & 255) < 100).astype(jnp.float32)
+    got = strip_mask(jnp.uint32(k1), jnp.uint32(k2), jnp.uint32(first), 4,
+                     100)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert 0 < float(jnp.sum(got)) < got.size

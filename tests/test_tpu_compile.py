@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.dasha_update import (LANE, dasha_mvr_update_pallas,
+from repro.kernels.dasha_update import (LANE, dasha_mvr_update_keyed_pallas,
+                                        dasha_mvr_update_pallas,
+                                        dasha_update_keyed_pallas,
                                         dasha_update_pallas, quantize_pallas)
 from repro.kernels.slab_writeback import slab_writeback_pallas
 
@@ -65,6 +67,22 @@ def test_dasha_mvr_update_compiles_for_v5e(one_chip):
     _compile(lambda gn, go, h, gl, m: dasha_mvr_update_pallas(
         gn, go, h, gl, m, 0.2, 0.1, 32.0, interpret=False),
         [((ROWS, LANE), f32)] * 5, one_chip)
+
+
+def test_dasha_update_keyed_compiles_for_v5e(one_chip):
+    """The keyed kernel: the key's words in SMEM, the mask hashed strip by
+    strip in the kernel."""
+    f32 = jnp.float32
+    _compile(lambda g, h, gl, k: dasha_update_keyed_pallas(
+        g, h, gl, k, 0.2, 32.0, 8, interpret=False),
+        [((ROWS, LANE), f32)] * 3 + [((2,), jnp.uint32)], one_chip)
+
+
+def test_dasha_mvr_update_keyed_compiles_for_v5e(one_chip):
+    f32 = jnp.float32
+    _compile(lambda gn, go, h, gl, k: dasha_mvr_update_keyed_pallas(
+        gn, go, h, gl, k, 0.2, 0.1, 32.0, 8, interpret=False),
+        [((ROWS, LANE), f32)] * 4 + [((2,), jnp.uint32)], one_chip)
 
 
 def test_quantize_compiles_for_v5e(one_chip):
