@@ -1,6 +1,8 @@
 """Smoke run of both deployments on a TPU chip, through their entry points.
 
-    python chip_smoke.py             # one chip: phases train, kernel-vs-jnp, fed
+    python chip_smoke.py             # one chip: phases train, nemotron,
+                                     # kernel-vs-jnp, fed
+    python chip_smoke.py --phase nemotron   # one phase of them
     python chip_smoke.py --chips 4   # four chips: the node axis on a mesh only
 
 One process owns the chip(s) and starts no other.  Every phase checks its
@@ -15,6 +17,11 @@ Phases (one chip):
   widths, depth cut to :data:`LAYERS`, 4 nodes vmapped on the chip, for
   ``dasha`` and ``mvr`` on the compiled fused node-update kernel; the loss
   and ``|g|^2`` of every logged step must be finite.
+* ``nemotron``: ``repro.launch.train.main`` on one chip's share of
+  NVIDIA-Nemotron-3-Nano-30B-A3B (:data:`NEMOTRON_ARGS`: layers 0-6, 8 of
+  128 experts, 16384 of 131072 vocabulary rows, one node, 8192 tokens) for
+  one 5-step chunk on the fused kernel; the chip-share line is printed,
+  and the logged loss must be finite and no routed token dropped.
 * ``kernel-vs-jnp``: one DASHA step with the fused kernel and one with the
   jnp path from the same state and key; the messages m_i, h_i and g_i must
   agree to f32 rounding.  Then the keyed kernels, which draw the mask
@@ -56,6 +63,12 @@ CMP_LAYERS = 2
 TRAIN_ARGS = ["--arch", "mamba2-780m", "--full", "--layers", str(LAYERS),
               "--nodes", "4", "--batch", "1", "--seq", "2048",
               "--server-opt", "sgd", "--steps", "4", "--log-every", "2"]
+#: the Nemotron-H chip share (bench/configs/nemotron3-nano.l7.e8.n1.chip1)
+NEMOTRON_ARGS = ["--arch", "nemotron-3-nano-30b-a3b", "--full", "--layers",
+                 "7", "--experts", "8", "--vocab", "16384", "--nodes", "1",
+                 "--batch", "1", "--seq", "8192", "--server-opt", "sgd",
+                 "--steps", "5", "--log-every", "5", "--use-kernel",
+                 "--devices", "1"]
 #: relative agreement asked of two f32 computations of the same values
 #: that differ only in fusion or reduction order
 F32_RTOL = 1e-5
@@ -118,6 +131,20 @@ def phase_train(compiles: list) -> None:
         del run
         done(f"layers={LAYERS} loss={last['loss']:.6f} |g|^2={last['g_norm_sq']:.6e} "
              f"at step {last['step']}")
+
+
+def phase_nemotron(compiles: list) -> None:
+    from repro.launch import train
+    done = _phase("nemotron", compiles)
+    run = train.main(NEMOTRON_ARGS)
+    last = run.log[-1]
+    del run
+    if not (_finite(last["loss"]) and _finite(last["g_norm_sq"])):
+        raise AssertionError(f"non-finite train record {last}")
+    if last["dropped"] != 0:
+        raise AssertionError(f"{last['dropped']} routed tokens dropped")
+    done(f"loss={last['loss']:.6f} |g|^2={last['g_norm_sq']:.6e} "
+         f"routed per held expert {last['expert_tokens']} dropped 0")
 
 
 def phase_kernel_vs_jnp(compiles: list) -> None:
@@ -340,6 +367,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=[1, 4],
                     help="4: run only the node axis across four chips")
+    ap.add_argument("--phase", default=None,
+                    choices=["train", "nemotron", "kernel-vs-jnp", "fed"],
+                    help="run this one-chip phase alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -361,8 +391,10 @@ def main(argv=None) -> int:
     compiles: list = []
     recompile.subscribe(lambda event, seconds: compiles.append(seconds))
 
+    one_chip = {"train": phase_train, "nemotron": phase_nemotron,
+                "kernel-vs-jnp": phase_kernel_vs_jnp, "fed": phase_fed}
     phases = [phase_node_mesh] if args.chips == 4 else \
-        [phase_train, phase_kernel_vs_jnp, phase_fed]
+        [one_chip[args.phase]] if args.phase else list(one_chip.values())
     for phase in phases:
         phase(compiles)
     print(json.dumps({"ok": True, "device": {

@@ -36,6 +36,8 @@ ALIASES = {
     "llama-3.2-vision-11b": "llama32_vision_11b",
     "qwen1.5-110b": "qwen15_110b",
     "whisper-tiny": "whisper_tiny",
+    # a benchmark configuration beyond the assigned ten
+    "nemotron-3-nano-30b-a3b": "nemotron3_nano_30b",
 }
 
 
@@ -53,4 +55,5 @@ def get_smoke_config(name: str) -> ArchConfig:
 
 
 def all_arch_ids() -> List[str]:
-    return list(ALIASES.keys())
+    """The CLI ids of the assigned architectures (:data:`ARCHS`)."""
+    return [k for k, v in ALIASES.items() if v in ARCHS]

@@ -13,6 +13,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import sequential_vmap
 
 from repro.kernels.dasha_update import (LANE, dasha_mvr_update_keyed_pallas,
                                         dasha_mvr_update_pallas,
@@ -23,6 +24,43 @@ from repro.kernels.dasha_update import (LANE, dasha_mvr_update_keyed_pallas,
 def _interpret() -> bool:
     """Interpret the kernels unless they are traced for a TPU."""
     return jax.default_backend() != "tpu"
+
+
+#: megablox's (m, k, n) tile: 128 rows, so that a group's partial tile wastes
+#: at most 127; k and n need not divide (its last tiles are masked)
+GMM_TILING = (128, 512, 1024)
+
+
+def _gmm(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    return gmm(lhs, rhs, sizes, lhs.dtype, GMM_TILING, jnp.int32(0), None,
+               False, _interpret())
+
+
+@jax.custom_vjp
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
+                   ) -> jax.Array:
+    """Rows of lhs (M, k) in consecutive groups of ``sizes`` (G + 1,) times
+    rhs (G, k, n): group g's rows times rhs[g]; the last group's rows, and
+    rows past all groups, give 0 and are never computed.  M is a multiple
+    of 128.  megablox's grouped matmul (Pallas), whose backward is its own
+    (``gmm`` for lhs, ``tgmm`` for rhs, the same groups).  It cannot be
+    batched with per-example group sizes, so under ``vmap`` (the nodes'
+    oracle) it and its backward map over the batch one example at a time."""
+    return sequential_vmap(_gmm)(lhs, rhs, sizes)
+
+
+def _grouped_fwd(lhs, rhs, sizes):
+    return grouped_matmul(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _grouped_bwd(res, g):
+    def vjp(lhs, rhs, sizes, g):
+        return jax.vjp(lambda a, b: _gmm(a, b, sizes), lhs, rhs)[1](g)
+    return (*sequential_vmap(vjp)(*res, g), None)
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def _to_lanes(x: jax.Array) -> Tuple[jax.Array, int]:

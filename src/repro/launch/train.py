@@ -13,9 +13,12 @@ round counter), so ``--resume`` continues bit-identically with the same
 data stream (per-round data keys are ``fold_in(data_seed, t)``).
 
 By default the driver runs the reduced (smoke) config of the selected
-architecture family.  ``--full`` selects the published config, and
-``--layers N`` cuts its depth to N layers — the only field it changes — so
-that one chip's share of a deployment fits one chip.
+architecture family.  ``--full`` selects the published config; ``--layers
+N`` cuts its depth to N layers, ``--experts N`` the routed experts it holds
+(the router still scores all of them) and ``--vocab N`` its vocabulary to
+the first N rows, so that one chip's share of a deployment fits one chip.
+A cut config prints its chip share, and a held-expert model the tokens its
+experts were routed and dropped at each log.
 
 Placement: with one visible device the n nodes are vmapped on it.  With
 several (``--devices`` caps how many are used), the nodes go onto a
@@ -80,6 +83,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="use the published config instead of the smoke one")
     ap.add_argument("--layers", type=int, default=None,
                     help="cut the config's depth to this many layers")
+    ap.add_argument("--experts", type=int, default=None,
+                    help="routed experts this chip holds (ids 0..N-1)")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="vocabulary rows this chip holds (ids 0..N-1)")
     ap.add_argument("--dtype", default=None, choices=["bfloat16", "float32"],
                     help="parameter dtype (default: the config's)")
     ap.add_argument("--steps", type=int,
@@ -116,16 +123,29 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def arch_config(arch: str, full: bool, layers: Optional[int] = None,
-                dtype: Optional[str] = None):
+                dtype: Optional[str] = None, experts: Optional[int] = None,
+                vocab: Optional[int] = None):
     """The model config of ``--arch``/``--full``, its depth cut to
-    ``layers`` and its parameter dtype set to ``dtype`` when given (no
-    other field changes)."""
+    ``layers``, the routed experts it holds to ``experts``, its vocabulary
+    to ``vocab`` rows and its parameter dtype set to ``dtype`` when given
+    (no other field changes)."""
     cfg = get_config(arch) if full else get_smoke_config(arch)
-    if layers is not None:
-        cfg = dataclasses.replace(cfg, num_layers=layers)
-    if dtype is not None:
-        cfg = dataclasses.replace(cfg, dtype=dtype)
-    return cfg
+    cut = {"num_layers": layers, "experts_held": experts,
+           "vocab_size": vocab, "dtype": dtype}
+    cut = {k: v for k, v in cut.items() if v is not None}
+    if experts is not None and not cfg.num_experts:
+        raise SystemExit(f"--experts: {cfg.name} has no routed experts")
+    return dataclasses.replace(cfg, **cut) if cut else cfg
+
+
+def chip_share(cfg, uncut) -> str:
+    """What of the uncut config this one holds: layers, routed experts and
+    vocabulary rows."""
+    parts = [f"layers {cfg.num_layers}/{uncut.num_layers}"]
+    if cfg.num_experts:
+        parts.append(f"experts {cfg.held_experts}/{cfg.num_experts}")
+    parts.append(f"vocab rows {cfg.vocab_size}/{uncut.vocab_size}")
+    return " ".join(parts)
 
 
 def node_mesh(n_nodes: int, n_devices: Optional[int] = None):
@@ -156,7 +176,8 @@ def _state_shardings(cfg, params_s, mesh, dasha, state_s):
 def main(argv=None) -> TrainRun:
     args = parse_args(argv)
     enable_compile_cache()
-    cfg = arch_config(args.arch, args.full, args.layers, args.dtype)
+    cfg = arch_config(args.arch, args.full, args.layers, args.dtype,
+                      args.experts, args.vocab)
     mesh = node_mesh(args.nodes, args.devices)
     key = jax.random.PRNGKey(args.seed)
     k_init, k_state, k_data = jax.random.split(key, 3)
@@ -170,6 +191,9 @@ def main(argv=None) -> TrainRun:
           f"params={n_params/1e6:.2f}M nodes={args.nodes} "
           f"devices={1 if mesh is None else mesh.devices.size} "
           f"tokens/step={args.nodes*args.batch*args.seq}")
+    if (args.layers, args.experts, args.vocab) != (None, None, None):
+        print("[train] chip share: " + chip_share(
+            cfg, arch_config(args.arch, args.full)))
 
     dasha = DashaTrainConfig(
         gamma=args.gamma, compression=args.compression, mode=args.mode,
@@ -239,7 +263,9 @@ def main(argv=None) -> TrainRun:
     eval_batch = jax.tree_util.tree_map(
         lambda x: x.reshape((-1,) + x.shape[2:]),
         make_node_batches(k_eval, tcfg, args.nodes, args.batch, **data_kw))
-    eval_loss = jax.jit(lambda p: lm.loss_fn(cfg, p, eval_batch)[1]["loss"])
+    # the held-out loss, and for a held-expert model the tokens its experts
+    # were routed and dropped on that batch, from one forward pass
+    eval_fn = jax.jit(lambda p: lm.loss_fn(cfg, p, eval_batch)[1])
 
     frac = payload_frac(dasha)
     chunk = args.chunk or args.log_every
@@ -249,14 +275,21 @@ def main(argv=None) -> TrainRun:
     t0 = time.time()
 
     def hook(ms, t, tr):
-        rec = {"step": done + t, "loss": float(eval_loss(ms.x)),
+        ev = jax.device_get(eval_fn(ms.x))
+        rec = {"step": done + t, "loss": float(ev["loss"]),
                "g_norm_sq": float(tr["g_norm_sq"][-1])}
+        experts = ""
+        if "dropped" in ev:
+            rec.update(expert_tokens=ev["expert_tokens"].tolist(),
+                       dropped=int(ev["dropped"]))
+            experts = (f"routed/expert={ev['expert_tokens'].sum(0).tolist()} "
+                       f"dropped={rec['dropped']} ")
         log.append(rec)
         print(f"[train] step {rec['step']:5d} "
               f"loss={rec['loss']:.4f} "
               f"|g|^2={rec['g_norm_sq']:.3e} "
               f"payload={frac:.4f} "
-              f"coords/node={float(ms.bits_sent):.3e} "
+              f"coords/node={float(ms.bits_sent):.3e} {experts}"
               f"({time.time()-t0:.1f}s)")
         if args.ckpt:
             save_method_state(args.ckpt, ms, step=int(ms.t))

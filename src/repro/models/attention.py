@@ -112,29 +112,31 @@ def gqa_prefill(p: Dict, x: jax.Array, positions: jax.Array,
     v = jnp.einsum("bsd,dgk->bsgk", x, p["wv"])
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     q = q.reshape(B, S, G, R, hd)
     sc = scale or hd ** -0.5
     k_pos = positions[0]
-    if S < QBLOCK_THRESHOLD or S % QBLOCK != 0 or S % KBLOCK != 0:
-        out = _sdpa(q, k, v, positions[0], k_pos, window,
-                    cfg.attn_logit_softcap, sc)
-    else:
-        nb = S // QBLOCK
-        q_blocks = jnp.moveaxis(
-            q.reshape(B, nb, QBLOCK, G, R, hd), 1, 0)       # (nb,B,Q,G,R,hd)
-        pos_blocks = k_pos.reshape(nb, QBLOCK)
+    with jax.named_scope("attn.core"):
+        if S < QBLOCK_THRESHOLD or S % QBLOCK != 0 or S % KBLOCK != 0:
+            out = _sdpa(q, k, v, positions[0], k_pos, window,
+                        cfg.attn_logit_softcap, sc)
+        else:
+            nb = S // QBLOCK
+            q_blocks = jnp.moveaxis(
+                q.reshape(B, nb, QBLOCK, G, R, hd), 1, 0)   # (nb,B,Q,G,R,hd)
+            pos_blocks = k_pos.reshape(nb, QBLOCK)
 
-        def body(_, inp):
-            qb, pb = inp
-            ob = _flash_sdpa(qb, k, v, pb, k_pos, window,
-                             cfg.attn_logit_softcap, sc)
-            return None, ob
+            def body(_, inp):
+                qb, pb = inp
+                ob = _flash_sdpa(qb, k, v, pb, k_pos, window,
+                                 cfg.attn_logit_softcap, sc)
+                return None, ob
 
-        _, out_blocks = jax.lax.scan(jax.checkpoint(body), None,
-                                     (q_blocks, pos_blocks))
-        out = jnp.moveaxis(out_blocks, 0, 1).reshape(B, S, G, R, hd)
+            _, out_blocks = jax.lax.scan(jax.checkpoint(body), None,
+                                         (q_blocks, pos_blocks))
+            out = jnp.moveaxis(out_blocks, 0, 1).reshape(B, S, G, R, hd)
     out = out.reshape(B, S, H, hd)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 

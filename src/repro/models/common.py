@@ -20,6 +20,7 @@ def pad_to(x: int, mult: int) -> int:
 class ArchConfig:
     name: str
     arch_type: str               # dense | moe | ssm | hybrid | vlm | audio
+                                 # | pattern
     num_layers: int
     d_model: int
     num_heads: int
@@ -29,7 +30,7 @@ class ArchConfig:
     source: str = ""             # citation bracket from the assignment
     head_dim: Optional[int] = None
     qkv_bias: bool = False
-    mlp_type: str = "swiglu"     # swiglu | gelu | geglu
+    mlp_type: str = "swiglu"     # swiglu | gelu | geglu | relu2
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -42,6 +43,11 @@ class ArchConfig:
     capacity_factor: float = 1.25
     moe_dispatch: str = "gather"   # gather | einsum (see moe.moe_ffn)
     moe_chunk: int = 4096          # tokens per einsum-dispatch group
+    # the held-expert layer of a ``pattern`` stack (moe.moe_held)
+    routed_scale: float = 1.0      # routed weights x this after top-k
+    shared_expert_ff: int = 0      # width of one non-gated shared expert
+    experts_held: int = 0          # experts this chip holds (0 = all)
+    expert_first: int = 0          # global id of the first held expert
 
     # --- MLA (DeepSeek-V2) ---------------------------------------------------
     use_mla: bool = False
@@ -55,6 +61,7 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_headdim: int = 64
     ssm_ngroups: int = 1
+    ssm_heads: int = 0             # explicit head count (0 = d_inner / P)
     conv_width: int = 4
     ssd_chunk: int = 256
     use_ssd_kernel: bool = False   # Pallas ssd_chunk path (TPU deploy)
@@ -64,6 +71,12 @@ class ArchConfig:
     global_every: int = 0          # gemma3: 1 global layer per `global_every`
     hybrid_attn_every: int = 0     # zamba2: shared attn block every k layers
     attn_logit_softcap: float = 0.0
+    use_rope: bool = True
+
+    # --- pattern stack (nemotron-h): one letter per layer ------------------
+    # M = Mamba2, E = held-expert MoE, * = attention; the first num_layers
+    # letters are the layers (a depth cut keeps a prefix)
+    layer_pattern: str = ""
 
     # --- VLM ----------------------------------------------------------------
     cross_attn_every: int = 0      # llama-3.2-vision: cross-attn each k layers
@@ -90,11 +103,22 @@ class ArchConfig:
 
     @property
     def d_inner(self) -> int:          # SSM inner width
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_nheads(self) -> int:
         return self.d_inner // self.ssm_headdim
+
+    @property
+    def pattern(self) -> str:
+        """The letters of the layers this config holds."""
+        return self.layer_pattern[:self.num_layers]
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def uses_attention(self) -> bool:
@@ -153,7 +177,14 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x)^2 in f32, back in x's dtype."""
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(x.dtype)
+
+
 def mlp_apply(p: dict, x: jax.Array, mlp_type: str) -> jax.Array:
+    if mlp_type == "relu2":
+        return relu2(x @ p["w_in"]) @ p["w_out"]
     if mlp_type == "gelu":
         h = jax.nn.gelu(x @ p["w_in"] + p.get("b_in", 0.0))
         return h @ p["w_out"] + p.get("b_out", 0.0)

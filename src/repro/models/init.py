@@ -96,7 +96,7 @@ def _block_params(key, cfg: ArchConfig, dt) -> Dict:
 def _mamba_params(key, cfg: ArchConfig, dt) -> Dict:
     d = cfg.d_model
     H, P, N, W = cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_state, cfg.conv_width
-    cd = H * P + 2 * N
+    cd = H * P + 2 * cfg.ssm_ngroups * N
     ks = jax.random.split(key, 6)
     return {"ln": jnp.zeros((d,), dt),
             "w_z": _dense_init(ks[0], (d, H, P), dt, d),
@@ -109,6 +109,34 @@ def _mamba_params(key, cfg: ArchConfig, dt) -> Dict:
             "D": jnp.ones((H,), jnp.float32),
             "norm": jnp.zeros((H * P,), dt),
             "w_out": _dense_init(ks[4], (H * P, d), dt, H * P)}
+
+
+def _held_moe_params(key, cfg: ArchConfig, dt) -> Dict:
+    """One held-expert MoE layer (moe.moe_held): pre-norm, the router over
+    every expert (f32), the held experts' relu^2 MLPs and the shared one."""
+    d, E, Eh, ff = cfg.d_model, cfg.num_experts, cfg.held_experts, cfg.d_ff
+    sf = cfg.shared_expert_ff
+    ks = jax.random.split(key, 5)
+    return {"ln": jnp.zeros((d,), dt),
+            "router": _dense_init(ks[0], (d, E), jnp.float32, d),
+            "w_in": jax.vmap(lambda k: _dense_init(k, (d, ff), dt))(
+                jax.random.split(ks[1], Eh)),
+            "w_out": jax.vmap(lambda k: _dense_init(k, (ff, d), dt, ff))(
+                jax.random.split(ks[2], Eh)),
+            "shared_w_in": _dense_init(ks[3], (d, sf), dt),
+            "shared_w_out": _dense_init(ks[4], (sf, d), dt, sf)}
+
+
+def _attn_params(key, cfg: ArchConfig, dt) -> Dict:
+    """One pre-norm attention layer of a ``pattern`` stack."""
+    return {"ln": jnp.zeros((cfg.d_model,), dt),
+            **_gqa_params(key, cfg, dt)}
+
+
+#: a ``pattern`` stack's layer kinds: letter -> (params key, init)
+PATTERN_KINDS = {"M": ("mamba", _mamba_params),
+                 "E": ("moe", _held_moe_params),
+                 "*": ("attn", _attn_params)}
 
 
 def _cross_block_params(key, cfg: ArchConfig, dt) -> Dict:
@@ -134,7 +162,14 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict:
                                         (cfg.d_model, cfg.padded_vocab), dt)
 
     at = cfg.arch_type
-    if at == "ssm":
+    if at == "pattern":
+        # one stack per layer kind, in the order the pattern lists them
+        for i, (letter, (name, fn)) in enumerate(PATTERN_KINDS.items()):
+            count = cfg.pattern.count(letter)
+            if count:
+                params[name] = _stack(jax.random.fold_in(keys[2], i), count,
+                                      lambda k, fn=fn: fn(k, cfg, dt))
+    elif at == "ssm":
         params["layers"] = _stack(keys[2], cfg.num_layers,
                                   lambda k: _mamba_params(k, cfg, dt))
     elif at == "hybrid":

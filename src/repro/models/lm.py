@@ -10,6 +10,8 @@ Public API (used by launch/, tests/, examples/):
 Layers are scanned; heterogeneous structure (gemma3 local/global groups,
 zamba2 shared attention, VLM cross blocks) is handled inside the scan body
 with `lax.cond` + dynamic indexing so each family still compiles ONE body.
+The ``pattern`` stack (Nemotron-H: Mamba-2, MoE and attention layers in
+one stack, DESIGN.md §19) unrolls its layers in pattern order instead.
 """
 from __future__ import annotations
 
@@ -23,6 +25,8 @@ from repro.models import attention as attn_lib
 from repro.models.blocks import (block_decode, block_prefill, cross_block,
                                  mamba_block_decode, mamba_block_prefill)
 from repro.models.common import ArchConfig, rms_norm
+from repro.models.init import PATTERN_KINDS
+from repro.models.moe import moe_held
 
 
 # ---------------------------------------------------------------------------
@@ -63,6 +67,56 @@ def _seq_constrain(x: jax.Array, axis: Optional[str]) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# pattern stack (nemotron-h): x + mixer(rmsnorm(x)), the mixer by letter
+# ---------------------------------------------------------------------------
+
+def _pattern_layer(letter: str, cfg: ArchConfig, pos: jax.Array):
+    """One layer of a ``pattern`` stack: (params, x) -> (x, tokens routed
+    to each held expert or None, dropped assignments)."""
+    none = jnp.int32(0)
+    if letter == "M":
+        return lambda lp, x: (mamba_block_prefill(lp, x, cfg), None, none)
+    if letter == "E":
+        def moe(lp, x):
+            y, routed, dropped = moe_held(
+                lp, rms_norm(x, lp["ln"], cfg.norm_eps), cfg)
+            return x + y, routed, dropped
+        return moe
+    if letter == "*":
+        return lambda lp, x: (x + attn_lib.gqa_prefill(
+            lp, rms_norm(x, lp["ln"], cfg.norm_eps), pos, cfg), None, none)
+    raise ValueError(f"layer letter {letter!r} in {cfg.layer_pattern!r}")
+
+
+def pattern_forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
+                    remat: bool = True
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The ``pattern`` stack's hidden states before the final norm:
+    returns (x (B,S,d), tokens routed to each held expert per MoE layer
+    (n_moe, E_h) int32, dropped assignments () int32).  Layers of one kind
+    are stacked on a leading axis (:data:`repro.models.init.PATTERN_KINDS`)
+    and sliced in pattern order; each layer is rematerialised."""
+    B, S = tokens.shape
+    pos = _positions(B, S)
+    x = params["embed"][tokens]
+    seen = {name: 0 for name, _ in PATTERN_KINDS.values()}
+    routed, dropped = [], jnp.int32(0)
+    for letter in cfg.pattern:
+        name = PATTERN_KINDS[letter][0]
+        lp = jax.tree_util.tree_map(lambda a, i=seen[name]: a[i],
+                                    params[name])
+        seen[name] += 1
+        x, r, dr = _maybe_remat(_pattern_layer(letter, cfg, pos), remat)(
+            lp, x)
+        if r is not None:
+            routed.append(r)
+        dropped = dropped + dr
+    routed = jnp.stack(routed) if routed else \
+        jnp.zeros((0, cfg.held_experts), jnp.int32)
+    return x, routed, dropped
+
+
+# ---------------------------------------------------------------------------
 # prefill / train forward
 # ---------------------------------------------------------------------------
 
@@ -77,10 +131,15 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
     the hidden states to the final position BEFORE the vocab projection
     (serving prefill: avoids materialising (B,S,V))."""
     B, S = tokens.shape
+    at = cfg.arch_type
+    if at == "pattern":
+        x, _, _ = pattern_forward(cfg, params, tokens, remat=remat)
+        if last_only:
+            x = x[:, -1:]
+        return _logits(cfg, params, x), jnp.float32(0)
     x = _embed(cfg, params, tokens)
     x = _seq_constrain(x, seq_shard)
     pos = _positions(B, S)
-    at = cfg.arch_type
 
     if at == "ssm":
         def body(carry, lp):
@@ -199,10 +258,17 @@ def _encoder_forward(cfg: ArchConfig, params: Dict, frames: jax.Array,
 def loss_fn(cfg: ArchConfig, params: Dict, batch: Dict, *,
             remat: bool = True,
             seq_shard: Optional[str] = None) -> Tuple[jax.Array, Dict]:
-    logits, aux = forward(cfg, params, batch["tokens"],
-                          image_embeds=batch.get("image_embeds"),
-                          frames=batch.get("frames"), remat=remat,
-                          seq_shard=seq_shard)
+    counters = {}
+    if cfg.arch_type == "pattern":
+        x, routed, dropped = pattern_forward(cfg, params, batch["tokens"],
+                                             remat=remat)
+        logits, aux = _logits(cfg, params, x), jnp.float32(0)
+        counters = {"expert_tokens": routed, "dropped": dropped}
+    else:
+        logits, aux = forward(cfg, params, batch["tokens"],
+                              image_embeds=batch.get("image_embeds"),
+                              frames=batch.get("frames"), remat=remat,
+                              seq_shard=seq_shard)
     labels = batch["labels"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
     nll = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
@@ -210,7 +276,8 @@ def loss_fn(cfg: ArchConfig, params: Dict, batch: Dict, *,
     nll = jnp.where(mask, nll, 0.0)
     loss = jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
     total = loss + 0.01 * aux
-    return total, {"loss": loss, "aux": aux}
+    return total, {"loss": loss, "aux": aux, **counters}
+
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Mixture-of-Experts FFN: top-k router, capacity-based dense dispatch
 (Shazeer-style einsum dispatch — maps onto expert parallelism over the
-"model" mesh axis), optional shared experts (DeepSeek-V2).
+"model" mesh axis), optional shared experts (DeepSeek-V2); and
+:func:`moe_held`, the dropless held-expert layer of the ``pattern`` stack.
 
 Dispatch is the classic dropping formulation: each expert processes at most
 ``capacity = ceil(cf * tokens * k / E)`` tokens; overflow tokens fall through
@@ -14,7 +15,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ArchConfig, mlp_apply
+from repro.kernels.ops import grouped_matmul
+from repro.models.common import ArchConfig, mlp_apply, relu2
 from repro.models.sharding import constrain_expert_major, constrain_token_major
 
 
@@ -169,3 +171,75 @@ def _moe_ffn_einsum(p: Dict, x: jax.Array, cfg: ArchConfig
                                "w_in": p["shared_w_in"],
                                "w_out": p["shared_w_out"]}, xt[:N], "swiglu")
     return out.reshape(B, S, d), jnp.mean(auxs)
+
+
+# ---------------------------------------------------------------------------
+# held experts, dropless (the chip's share of an expert-parallel layer)
+# ---------------------------------------------------------------------------
+
+def dropped_assignments(sizes: jax.Array, rows: jax.Array,
+                        groups: jax.Array) -> jax.Array:
+    """Assignments to a held expert (``groups`` below ``len(sizes) - 1``)
+    whose output row ``rows`` lies outside that expert's group of rows, as
+    consecutive groups of ``sizes`` give them: int32 count."""
+    row_group = jnp.searchsorted(jnp.cumsum(sizes), rows, side="right")
+    held = groups < sizes.shape[0] - 1
+    return jnp.sum((held & (row_group != groups)).astype(jnp.int32))
+
+
+def moe_held(p: Dict, x: jax.Array, cfg: ArchConfig
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The part of a routed-expert layer that this chip's experts give, plus
+    its shared expert.  x: (B,S,d) -> (out (B,S,d), tokens routed to each
+    held expert (E_h,) int32, dropped assignments () int32).
+
+    The router scores all ``cfg.num_experts`` experts by a sigmoid, picks
+    the top ``experts_per_token`` by score (the score-correction bias is
+    held at its initial 0, so it adds nothing), weighs the chosen scores
+    normalised to sum 1 times ``routed_scale``, and the chip computes
+    its experts ``expert_first ..`` + ``experts_held``: relu^2 MLPs over
+    every token routed to them, by grouped products
+    (:func:`repro.kernels.ops.grouped_matmul`) over the (token, choice) rows
+    sorted by expert, so that no token is dropped.  What the absent
+    experts would add is left out.  A held (token, choice) assignment is
+    dropped when the row that its output is read back from was not computed
+    by its own expert's group of the products; the layer has no capacity,
+    so that count is 0.  The routing, sort and combine run under
+    the ``moe.route`` named scope, the grouped products under
+    ``moe.experts``, the shared expert under ``moe.shared``."""
+    B, S, d = x.shape
+    K, Eh, e0 = cfg.experts_per_token, cfg.held_experts, cfg.expert_first
+    N = B * S
+    f32 = jnp.float32
+    xt = x.reshape(N, d)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xt.astype(f32), p["router"].astype(f32),
+                         precision=jax.lax.Precision.HIGHEST)   # (N, E)
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s, K)
+        w = jnp.take_along_axis(s, idx, -1)                     # (N, K)
+        w = w / jnp.sum(w, -1, keepdims=True) * cfg.routed_scale
+        local = idx - e0
+        held = (local >= 0) & (local < Eh)
+        grp = jnp.where(held, local, Eh).reshape(N * K)         # Eh = absent
+        order = jnp.argsort(grp, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(grp, Eh + 1, dtype=jnp.int32), 0)
+        # rows padded to whole 128-row tiles; the pad joins the absent group
+        M = -(-N * K // 128) * 128
+        sizes = sizes.at[Eh].add(M - N * K)
+        rows = jnp.pad(jnp.take(xt, order // K, axis=0),
+                       ((0, M - N * K), (0, 0)))                # (M, d)
+    with jax.named_scope("moe.experts"):
+        h = relu2(grouped_matmul(rows, p["w_in"], sizes))
+        y = grouped_matmul(h, p["w_out"], sizes)                # (M, d)
+    with jax.named_scope("moe.route"):
+        back = jnp.zeros((N * K,), jnp.int32).at[order].set(
+            jnp.arange(N * K, dtype=jnp.int32))
+        y = jnp.take(y, back, axis=0).reshape(N, K, d)
+        out = jnp.einsum("nkd,nk->nd", y, (w * held).astype(y.dtype))
+        routed = sizes[:Eh]
+        dropped = dropped_assignments(sizes, back, grp)
+    with jax.named_scope("moe.shared"):
+        out = out + mlp_apply({"w_in": p["shared_w_in"],
+                               "w_out": p["shared_w_out"]}, xt, "relu2")
+    return out.reshape(B, S, d), routed, dropped

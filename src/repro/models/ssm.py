@@ -6,7 +6,10 @@ decode step — this is what makes the long_500k shape tractable for the
 ssm/hybrid architectures.
 
 Layout: d_inner = H * P (heads x headdim); B/C are per-group (G groups,
-state size N); the scalar-per-head A follows Mamba2.
+state size N; head h reads group h // (H / G)); the scalar-per-head A
+follows Mamba2.  With G > 1 the gated RMSNorm normalises each group's
+d_inner / G channels on their own.  The scan runs under the
+``ssd.scan`` named scope.
 """
 from __future__ import annotations
 
@@ -32,17 +35,56 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, b: jax.Array,
                 c: jax.Array, D: jax.Array, chunk: int,
                 s0: jax.Array | None = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Single-group SSD.
+    """SSD over G B/C groups, head h reading group h // (H / G).
 
     x: (B,S,H,P), dt: (B,S,H) (post-softplus), A: (H,) (negative),
-    b/c: (B,S,N), D: (H,).  Returns (y: (B,S,H,P), final_state: (B,H,N,P)).
+    b/c: (B,S,G,N), or (B,S,N) for one group, D: (H,), s0: (B,H,N,P).
+    Returns (y: (B,S,H,P), final_state: (B,H,N,P)).  With G > 1 each
+    group's H / G heads run as one more sequence of the batch, reading the
+    group's B and C and their own A and D (:func:`_ssd_one_group`).
+    """
+    if b.ndim == 3 or b.shape[2] == 1:
+        N = b.shape[-1]
+        return _ssd_one_group(x, dt, A, b.reshape(b.shape[:2] + (N,)),
+                              c.reshape(c.shape[:2] + (N,)), D, chunk, s0)
+    Bb, S, H, P = x.shape
+    G, N = b.shape[2:]
+    R = H // G
+
+    def fold(t):                # (B,S,G*R,...) -> (B*G,S,R,...)
+        t = t.reshape((Bb, S, G, R) + t.shape[3:])
+        return jnp.moveaxis(t, 2, 1).reshape((Bb * G, S, R) + t.shape[4:])
+
+    def per_group(v):           # (H,) -> (B*G, 1, R): one row per sequence
+        return jnp.broadcast_to(v.reshape(1, G, 1, R),
+                                (Bb, G, 1, R)).reshape(Bb * G, 1, R)
+
+    bc = [jnp.moveaxis(t, 2, 1).reshape(Bb * G, S, N) for t in (b, c)]
+    y, final = _ssd_one_group(
+        fold(x), fold(dt), per_group(A), *bc, per_group(D)[..., None],
+        chunk, None if s0 is None else s0.reshape(Bb * G, R, N, P))
+    y = jnp.moveaxis(y.reshape(Bb, G, S, R, P), 1, 2).reshape(Bb, S, H, P)
+    return y, final.reshape(Bb, H, N, P)
+
+
+def _ssd_one_group(x: jax.Array, dt: jax.Array, A: jax.Array, b: jax.Array,
+                   c: jax.Array, D: jax.Array, chunk: int,
+                   s0: jax.Array | None = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Single-group chunked SSD.
+
+    x: (B,S,H,P), dt: (B,S,H) (post-softplus), A: (H,) or per sequence
+    (B,1,H) (negative), b/c: (B,S,N), D: (H,) or (B,1,H,1).  Returns
+    (y: (B,S,H,P), final_state: (B,H,N,P)).
     """
     Bb, S, H, P = x.shape
     N = b.shape[-1]
     nc = S // chunk
     f32 = jnp.float32
+    if A.ndim == 1:
+        A, D = A[None, None, :], D[None, None, :, None]
     xv = (x * dt[..., None]).astype(f32)                    # dt-weighted input
-    a = (dt * A[None, None, :]).astype(f32)                 # (B,S,H) log decay
+    a = (dt * A).astype(f32)                                # (B,S,H) log decay
 
     xc = xv.reshape(Bb, nc, chunk, H, P)
     ac = a.reshape(Bb, nc, chunk, H)
@@ -79,7 +121,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, b: jax.Array,
     y_off = jnp.einsum("bnqs,bnqh,bnhsp->bnqhp",
                        cc, state_decay, prev_states)
     y = (y_diag + y_off).reshape(Bb, S, H, P)
-    y = y + x.astype(f32) * D[None, None, :, None]
+    y = y + x.astype(f32) * D
     return y.astype(x.dtype), final
 
 
@@ -116,28 +158,42 @@ def mamba_mixer_prefill(p: Dict, x: jax.Array, cfg: ArchConfig,
     """x: (B,S,d) -> (B,S,d)."""
     B, S, _ = x.shape
     H, P, N = cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_state
+    G = cfg.ssm_ngroups
     z = jnp.einsum("bsd,dhp->bshp", x, p["w_z"])
-    xbc = jnp.einsum("bsd,dc->bsc", x, p["w_xbc"])   # (B,S,HP+2N)
+    xbc = jnp.einsum("bsd,dc->bsc", x, p["w_xbc"])   # (B,S,HP+2GN)
     dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", x, p["w_dt"]) + p["dt_bias"])
     xbc = _conv1d_prefill(xbc, p["conv_w"], p["conv_b"])
     xs = xbc[..., :H * P].reshape(B, S, H, P)
-    bmat = xbc[..., H * P:H * P + N]
-    cmat = xbc[..., H * P + N:]
+    bmat = xbc[..., H * P:H * P + G * N]
+    cmat = xbc[..., H * P + G * N:]
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     chunk = min(cfg.ssd_chunk, S)
-    if cfg.use_ssd_kernel and s0 is None and S % chunk == 0:
-        from repro.kernels.ops import ssd_chunk_scan
-        y, _ = ssd_chunk_scan(xs, dt, A, bmat, cmat, p["D"], chunk)
-    else:
-        y, _ = ssd_chunked(xs, dt, A, bmat, cmat, p["D"], chunk, s0)
+    with jax.named_scope("ssd.scan"):
+        if cfg.use_ssd_kernel and G == 1 and s0 is None \
+                and S % chunk == 0:
+            from repro.kernels.ops import ssd_chunk_scan
+            y, _ = ssd_chunk_scan(xs, dt, A, bmat, cmat, p["D"], chunk)
+        else:
+            y, _ = ssd_chunked(xs, dt, A, bmat.reshape(B, S, G, N),
+                               cmat.reshape(B, S, G, N), p["D"], chunk, s0)
     y = y * jax.nn.silu(z)
-    y = rms_norm(y.reshape(B, S, H * P), p["norm"], cfg.norm_eps)
+    if G == 1:
+        # the norm over all of d_inner; a one-group reshape around it would
+        # compile to a different program (more bytes) for the same result
+        y = rms_norm(y.reshape(B, S, H * P), p["norm"], cfg.norm_eps)
+    else:                       # each group's d_inner / G channels alone
+        y = rms_norm(y.reshape(B, S, G, H * P // G),
+                     p["norm"].reshape(G, H * P // G),
+                     cfg.norm_eps).reshape(B, S, H * P)
     return jnp.einsum("bsc,cd->bsd", y, p["w_out"])
 
 
 def mamba_mixer_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ArchConfig
                        ) -> Tuple[jax.Array, Dict]:
-    """x: (B,1,d); cache: {"conv": (B,W-1,Cd), "ssm": (B,H,N,P)}."""
+    """x: (B,1,d); cache: {"conv": (B,W-1,Cd), "ssm": (B,H,N,P)}; one B/C
+    group."""
+    if cfg.ssm_ngroups != 1:
+        raise NotImplementedError("grouped SSD decode")
     B = x.shape[0]
     H, P, N = cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_state
     xt = x[:, 0]

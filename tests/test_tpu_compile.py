@@ -171,3 +171,43 @@ def test_kernel_head_matches_the_recorded_v5e_trace(one_chip, monkeypatch):
     kernel = {scopes.head(n) for n, _, _ in tr.ops[0]
               if trace.short_name(n) == "dasha_update"}
     assert len(kernel) == 1 and kernel <= set(names)
+
+
+def test_nemotron_chunk_compiles_for_v5e_and_fits(one_chip, monkeypatch):
+    """The `train.nemotron3.dasha.s8k` cell's compiled 5-step chunk (one
+    node, 8192 tokens a step, the keyed fused update) at the chip share's
+    real sizes: it compiles for v5e, and the compiler's peak for it (state,
+    gradients and activations) stays under 15 GiB of the chip's 16."""
+    from repro.kernels import ops
+    from repro.launch.train import arch_config
+    from repro.methods.driver import Driver, _metric_zeros
+    from repro.models import init_params, lm
+    from repro.optim.distributed import DashaTrainConfig, make_method
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = arch_config("nemotron-3-nano-30b-a3b", True, 7, None, 8, 16384)
+    method = make_method(
+        DashaTrainConfig(gamma=0.003, compression=1 / 32, n_nodes=1,
+                         use_kernel=True),
+        lambda p, b: lm.loss_fn(cfg, p, b)[0])
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    state = jax.eval_shape(
+        lambda k: method.init(init_params(cfg, k), k, init_mode="zeros"),
+        key)
+
+    def data_fn(k, t):
+        toks = jax.random.randint(k, (1, 1, 8193), 1, cfg.vocab_size)
+        return {"tokens": toks[..., :-1], "labels": toks[..., 1:]}
+
+    metrics = {"g_sq": lambda s, b: sum(
+        jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(s.g))}
+    drv = Driver(method, data_fn=data_fn, metrics=metrics, chunk=5,
+                 donate=True)
+    carry = (state, jax.ShapeDtypeStruct((), jnp.int32), jax.eval_shape(
+        lambda: _metric_zeros(metrics, state,
+                              jax.eval_shape(data_fn, key, 0))))
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (carry, key))
+    compiled = drv._chunk_fn(5).lower(*placed).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15 * 2 ** 30
