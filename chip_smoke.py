@@ -207,14 +207,16 @@ def phase_kernel_vs_jnp(compiles: list) -> None:
 
 def _keyed_vs_mask(cfg, nodes: int, p: float) -> str:
     """The keyed kernels (mask drawn inside) against the explicit-mask
-    kernels fed ``draw_mask``'s mask under the same key, at the lane-layout
-    size of every leaf of the nodes' state: m, h_i and g_i must be
-    bit-equal, for DASHA and MVR."""
+    kernels fed ``draw_mask``'s mask under the same key, in the (rows,
+    cols) view the fused update streams each leaf of the nodes' state in
+    (its own layout, else lane rows): m, h_i and g_i must be bit-equal,
+    for DASHA and MVR."""
     import jax
     import jax.numpy as jnp
 
     from repro.compress.plan import draw_mask, u8_threshold
     from repro.kernels import dasha_update as kern
+    from repro.kernels.ops import node_update_view
     from repro.models import init_params
 
     thresh = u8_threshold(p)
@@ -222,16 +224,17 @@ def _keyed_vs_mask(cfg, nodes: int, p: float) -> str:
         raise AssertionError(f"compression {p} is no multiple of 1/256")
     shapes = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
-    sizes = sorted({-(-nodes * x.size // kern.LANE)
+    views = sorted({node_update_view((nodes,) + x.shape)
+                    or (-(-nodes * x.size // kern.LANE), kern.LANE)
                     for x in jax.tree_util.tree_leaves(shapes)})
 
     def bits(x):
         return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
     @functools.partial(jax.jit, static_argnums=1)
-    def inputs(key, rows):
-        mask = draw_mask(key, (rows, kern.LANE), p).astype(jnp.float32)
-        return tuple(jax.random.normal(k, (rows, kern.LANE)) for k in
+    def inputs(key, view):
+        mask = draw_mask(key, view, p).astype(jnp.float32)
+        return tuple(jax.random.normal(k, view) for k in
                      jax.random.split(jax.random.fold_in(key, 1), 3)) + (mask,)
 
     # the mask comes in as an argument, as in the step: drawn in the same
@@ -259,18 +262,18 @@ def _keyed_vs_mask(cfg, nodes: int, p: float) -> str:
     key = jax.random.PRNGKey(7)
     worst = 0.0
     for variant in ("dasha", "mvr"):
-        for i, rows in enumerate(sizes):
+        for i, view in enumerate(views):
             k = jax.random.fold_in(key, i)
             diff, unequal = jax.device_get(
-                check(*inputs(k, rows), k, variant))
+                check(*inputs(k, view), k, variant))
             worst = max(worst, float(diff))
             if int(unequal):
                 raise AssertionError(
-                    f"keyed {variant} kernel at {rows} rows: {int(unequal)} "
+                    f"keyed {variant} kernel at {view}: {int(unequal)} "
                     f"elements differ from the explicit mask's, max|diff| "
                     f"{float(diff):.3e}")
-    return (f"keyed vs mask kernels (dasha, mvr) at {len(sizes)} leaf sizes "
-            f"up to {sizes[-1]} rows: max|diff|={worst:.3e}, bit-equal")
+    return (f"keyed vs mask kernels (dasha, mvr) in {len(views)} leaf "
+            f"views: max|diff|={worst:.3e}, bit-equal")
 
 
 def phase_fed(compiles: list) -> None:

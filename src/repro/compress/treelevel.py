@@ -15,10 +15,11 @@ axis and GSPMD shardings.  This module is the ONE place that bridges them:
 All masks come from :mod:`repro.compress.plan`, so the dense and fused paths
 are parity-testable under the same key.  The fused path draws a leaf's
 ``independent`` u8-threshold mask inside the kernel from the leaf key
-(:func:`kernel_draw_threshold`, counted by :func:`kernel_draw_count`);
-every other mask draw runs under the ``dasha.compress`` named scope (the
-mean over nodes under ``dasha.aggregate``), which names its ops in a
-device trace.
+(:func:`kernel_draw_threshold`, counted by :func:`kernel_draw_count`)
+and streams each leaf in its own layout where the kernel's blocks tile it
+(:func:`kernel_layout_count`); every other mask draw runs under the
+``dasha.compress`` named scope (the mean over nodes under
+``dasha.aggregate``), which names its ops in a device trace.
 """
 from __future__ import annotations
 
@@ -170,8 +171,8 @@ def tree_masks(key: jax.Array, tree: PyTree, *, mode: str, p: float, n: int,
     return masks, _scale(mode, p, n)
 
 
-#: the largest lane-layout size (R x 128) whose flat indices the keyed
-#: kernels count exactly in u32 arithmetic
+#: the largest leaf whose flat indices the keyed kernels count exactly in
+#: u32 arithmetic
 KERNEL_DRAW_MAX_ELEMENTS = 2 ** 32
 
 
@@ -194,7 +195,7 @@ def kernel_draw_threshold(x, spec, *, mode: str, p: float, mesh=None,
     The kernel replays ``draw_mask``'s u8 path over the whole
     ``(n, *shape)`` leaf, so it takes a leaf only when that is the draw:
     ``independent`` masks, ``256 p`` an integer in (0, 256), a threefry
-    ``key`` (raw where ``None``), the padded flat size within
+    ``key`` (raw where ``None``), the size within
     :data:`KERNEL_DRAW_MAX_ELEMENTS`, and the leaf not split over ``mesh``
     (a shard's elements are not a contiguous run of the leaf's flat
     indices)."""
@@ -202,7 +203,7 @@ def kernel_draw_threshold(x, spec, *, mode: str, p: float, mesh=None,
         return None
     if spec is not None and mesh is not None and not mesh.empty:
         return None
-    if -(-int(x.size) // 128) * 128 > KERNEL_DRAW_MAX_ELEMENTS:
+    if int(x.size) > KERNEL_DRAW_MAX_ELEMENTS:
         return None
     return u8_threshold(p)
 
@@ -215,10 +216,20 @@ class KernelDraws(NamedTuple):
     elements: int
     of_elements: int
 
+    #: what the counted leaves have, in the printed line
+    label = "in kernel"
+
     def __str__(self) -> str:
-        return (f"in kernel {self.leaves}/{self.of_leaves} leaves, "
+        return (f"{self.label} {self.leaves}/{self.of_leaves} leaves, "
                 f"{self.elements / 1e6:.1f}M/{self.of_elements / 1e6:.1f}M"
                 " elements")
+
+
+class KernelLayouts(KernelDraws):
+    """How much of a tree the fused kernel updates in its own layout."""
+
+    __slots__ = ()
+    label = "own layout"
 
 
 def kernel_draw_count(tree: PyTree, *, mode: str, p: float,
@@ -229,14 +240,43 @@ def kernel_draw_count(tree: PyTree, *, mode: str, p: float,
     under ``specs`` on ``mesh`` (default: the current abstract mesh)."""
     if mesh is None:
         mesh = jax.sharding.get_abstract_mesh()
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    spec_leaves = [None] * len(leaves) if specs is None \
-        else treedef.flatten_up_to(specs)
+    leaves, spec_leaves = _spec_leaves(tree, specs)
     drawn = [int(x.size) for x, spec in zip(leaves, spec_leaves)
              if kernel_draw_threshold(x, spec, mode=mode, p=p,
                                       mesh=mesh) is not None]
     return KernelDraws(len(drawn), len(leaves), sum(drawn),
                        sum(int(x.size) for x in leaves))
+
+
+def _spec_leaves(tree: PyTree, specs: Optional[PyTree]):
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    return leaves, ([None] * len(leaves) if specs is None
+                    else treedef.flatten_up_to(specs))
+
+
+def kernel_layout_count(tree: PyTree, *, specs: Optional[PyTree] = None,
+                        mesh=None) -> KernelLayouts:
+    """Leaves and elements of ``tree`` (leaves ``(n, *shape)``, arrays or
+    shapes) that :func:`fused_tree_update` streams in their own layout
+    (:func:`repro.kernels.ops.node_update_view`) rather than in lane rows:
+    each device's shard where ``specs`` split a leaf over ``mesh``
+    (default: the current abstract mesh)."""
+    from jax.sharding import NamedSharding
+
+    from repro.kernels.ops import node_update_view
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    leaves, spec_leaves = _spec_leaves(tree, specs)
+
+    def shape(x, spec):
+        if spec is None or mesh.empty:
+            return x.shape
+        return NamedSharding(mesh, spec).shard_shape(x.shape)
+
+    own = [int(x.size) for x, spec in zip(leaves, spec_leaves)
+           if node_update_view(shape(x, spec)) is not None]
+    return KernelLayouts(len(own), len(leaves), sum(own),
+                         sum(int(x.size) for x in leaves))
 
 
 def fused_tree_update(key: jax.Array, grads_new: PyTree, h: PyTree,

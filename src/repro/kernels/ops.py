@@ -1,15 +1,24 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles the (d,) <-> (R, 128) padding/reshape plumbing so callers pass flat
-vectors (or any shape); kernels see lane-aligned 2-D blocks.  Where a call
-runs is decided when it is traced, from the default backend: on a TPU every
-kernel compiles to Mosaic (and a kernel the chip refuses raises); on any
-other backend the kernel body runs in the Pallas interpreter.
+The fused node update streams each tensor in its own row-major layout: the
+ops layer views a tensor of shape ``(..., cols)`` as ``(rows, cols)``, rows
+the product of all dims but the last (:func:`node_update_view`).  That
+merges leading dims only, so where the second-to-last dim is a multiple of
+8 it is a bitcast on the TPU, and the outputs come back by the same
+reshape.  A tensor whose view no kernel block tiles (a 1-D vector; a row
+too wide for one block whose width is no multiple of 128; see
+:func:`repro.kernels.dasha_update.node_update_block`) takes the lane path
+instead: flattened, padded to (R, 128) lane rows and cut back after the
+kernel, which costs a relayout each way.  Where a call runs is decided
+when it is traced, from the default backend: on a TPU every kernel
+compiles to Mosaic (and a kernel the chip refuses raises); on any other
+backend the kernel body runs in the Pallas interpreter.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +27,8 @@ from jax.custom_batching import sequential_vmap
 from repro.kernels.dasha_update import (LANE, dasha_mvr_update_keyed_pallas,
                                         dasha_mvr_update_pallas,
                                         dasha_update_keyed_pallas,
-                                        dasha_update_pallas, quantize_pallas)
+                                        dasha_update_pallas,
+                                        node_update_block, quantize_pallas)
 
 
 def _interpret() -> bool:
@@ -63,23 +73,23 @@ def _grouped_bwd(res, g):
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def _to_lanes(x: jax.Array) -> Tuple[jax.Array, int]:
-    flat = x.reshape(-1).astype(jnp.float32)
-    d = flat.shape[0]
-    pad = (-d) % LANE
-    flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(-1, LANE), d
+def node_update_view(shape) -> Optional[Tuple[int, int]]:
+    """The (rows, cols) view in which the fused node update streams a
+    tensor of ``shape`` in its own layout, or ``None`` where it takes the
+    lane path."""
+    if len(shape) < 2:
+        return None
+    view = (math.prod(shape[:-1]), shape[-1])
+    return view if node_update_block(*view) else None
 
 
-def _mask_to_lanes(mask: jax.Array) -> jax.Array:
-    """The mask cast into the kernel's layout belongs to the mask draw: it
-    runs under the compression plan's named scope."""
-    with jax.named_scope("dasha.compress"):
-        return _to_lanes(mask)[0]
+def _to_lanes(x: jax.Array) -> jax.Array:
+    flat = x.reshape(-1)
+    return jnp.pad(flat, (0, (-flat.shape[0]) % LANE)).reshape(-1, LANE)
 
 
-def _from_lanes(x2: jax.Array, d: int, shape, dtype) -> jax.Array:
-    return x2.reshape(-1)[:d].reshape(shape).astype(dtype)
+def _from_lanes(x2: jax.Array, shape) -> jax.Array:
+    return x2.reshape(-1)[:math.prod(shape)].reshape(shape)
 
 
 def _key_words(key: jax.Array) -> jax.Array:
@@ -90,16 +100,28 @@ def _key_words(key: jax.Array) -> jax.Array:
 
 
 def _fused(kernel, tensors, *scalars, mask=None, key=None):
-    """Run a node-update kernel on same-shape ``tensors`` in the lane
-    layout, then the ``mask`` in that layout or the ``key``'s words, then
-    the static ``scalars``.  Returns (m, h_new, g_local_new) with the first
-    tensor's shape and dtype."""
+    """Run a node-update kernel on same-shape ``tensors`` as f32 in their
+    own layout's view (else in lane rows), then the ``mask`` in that view
+    or the ``key``'s words, then the static ``scalars``.  Returns (m,
+    h_new, g_local_new) with the first tensor's shape and dtype."""
     shape, dtype = tensors[0].shape, tensors[0].dtype
-    lanes = [_to_lanes(t)[0] for t in tensors]
-    extra = _key_words(key) if mask is None else _mask_to_lanes(mask)
-    outs = kernel(*lanes, extra, *scalars, interpret=_interpret())
-    return tuple(_from_lanes(t, tensors[0].size, shape, dtype)
-                 for t in outs)
+    view = node_update_view(shape)
+
+    def to_view(x):
+        x = x.astype(jnp.float32)
+        return _to_lanes(x) if view is None else x.reshape(view)
+
+    if mask is None:
+        extra = _key_words(key)
+    else:
+        # the mask cast into the kernel's view belongs to the mask draw:
+        # it runs under the compression plan's named scope
+        with jax.named_scope("dasha.compress"):
+            extra = to_view(mask)
+    outs = kernel(*map(to_view, tensors), extra, *scalars,
+                  interpret=_interpret())
+    return tuple((_from_lanes(t, shape) if view is None else t.reshape(shape)
+                  ).astype(dtype) for t in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("a", "scale"))
@@ -120,7 +142,7 @@ def dasha_update_keyed(grad: jax.Array, h: jax.Array, g_local: jax.Array,
                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`dasha_update` with the mask ``jax.random.bits(key,
     grad.shape, uint8) < thresh`` drawn inside the kernel (threefry keys,
-    typed or raw; ``grad.size`` padded to 128 at most 2**32)."""
+    typed or raw; ``grad.size`` at most 2**32)."""
     return _fused(dasha_update_keyed_pallas, (grad, h, g_local), a, scale,
                   thresh, key=key)
 

@@ -43,7 +43,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.io import (checkpoint_step, load_method_state,
                                  save_method_state)
-from repro.compress.treelevel import kernel_draw_count
+from repro.compress.treelevel import kernel_draw_count, kernel_layout_count
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTextConfig, make_node_batches
 from repro.methods import MethodState
@@ -211,6 +211,8 @@ def main(argv=None) -> TrainRun:
         print("[train] mask draw: " + str(kernel_draw_count(
             per_node, mode=args.mode, p=args.compression, specs=specs,
             mesh=mesh)))
+        print("[train] node update: " + str(kernel_layout_count(
+            per_node, specs=specs, mesh=mesh)))
 
     def node_loss(p, b):
         return lm.loss_fn(cfg, p, b)[0]

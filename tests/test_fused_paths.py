@@ -289,3 +289,38 @@ def test_cell_draws_every_mask_in_the_kernel():
     assert count.leaves == count.of_leaves == 13
     assert count.elements == count.of_elements
     assert str(count) == "in kernel 13/13 leaves, 544.2M/544.2M elements"
+
+
+def test_kernel_layout_count():
+    """Own layout where a kernel block tiles the leaf's (rows, cols) view;
+    the lane path for a 1-D leaf and for rows too wide for a block whose
+    width is no multiple of 128; a shard's view under a mesh; and in the
+    mamba2 cell every leaf in its own layout."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.compress.treelevel import kernel_layout_count
+    from repro.launch.train import arch_config
+    from repro.models import init_params
+
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    tree = {"embed": leaf(4, 64, 256), "w_z": leaf(4, 16, 48, 64),
+            "row": leaf(4, 16500), "scale": leaf(4,),
+            "wide": leaf(4, 3, 16500)}
+    own = 4 * 64 * 256 + 4 * 16 * 48 * 64 + 4 * 16500
+    total = own + 4 + 4 * 3 * 16500
+    count = kernel_layout_count(tree)
+    assert tuple(count) == (3, 5, own, total)
+    assert str(count) == (f"own layout 3/5 leaves, {own / 1e6:.1f}M/"
+                          f"{total / 1e6:.1f}M elements")
+    mesh = jax.make_mesh((1,), ("data",))
+    specs = jax.tree_util.tree_map(lambda _: P("data"), tree)
+    assert kernel_layout_count(tree, specs=specs, mesh=mesh) == count
+
+    cfg = arch_config("mamba2-780m", True, 4)
+    shapes = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    per_node = jax.tree_util.tree_map(lambda s: leaf(4, *s.shape), shapes)
+    assert str(kernel_layout_count(per_node)) \
+        == "own layout 13/13 leaves, 544.2M/544.2M elements"

@@ -333,8 +333,10 @@ def test_attention_layers_take_no_rotary_embedding():
 
 def test_dasha_chunk_through_make_method_and_driver(capsys):
     """One node, the smoke config, through the trainer: every leaf's mask
-    drawn inside the keyed fused kernel, the chip share and no dropped
-    token printed at each log."""
+    drawn inside the keyed fused kernel, the leaves updated in their own
+    layout as :func:`kernel_layout_count` counts them, the chip share and
+    no dropped token printed at each log."""
+    from repro.compress.treelevel import kernel_layout_count
     from repro.launch import train
     run = train.main(["--arch", ARCH, "--steps", "2", "--log-every", "2",
                       "--seq", "32", "--batch", "1", "--nodes", "1",
@@ -343,6 +345,10 @@ def test_dasha_chunk_through_make_method_and_driver(capsys):
     out = capsys.readouterr().out
     leaves = len(jax.tree_util.tree_leaves(run.state.x))
     assert f"mask draw: in kernel {leaves}/{leaves} leaves" in out
+    per_node = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((1,) + x.shape, jnp.float32),
+        run.state.x)
+    assert f"node update: {kernel_layout_count(per_node)}\n" in out
     assert "chip share: layers 5/5 experts 4/16 vocab rows 256/512" in out
     assert "dropped=0" in out
     rec = run.log[-1]

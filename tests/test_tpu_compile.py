@@ -211,3 +211,75 @@ def test_nemotron_chunk_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     compiled = drv._chunk_fn(5).lower(*placed).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().peak_memory_in_bytes < 15 * 2 ** 30
+
+
+#: instructions that move no data: the kernels' operands and results may
+#: pass through these alone
+_FREE_OPS = {"parameter", "bitcast", "custom-call", "tuple",
+             "get-tuple-element", "constant"}
+
+
+@pytest.mark.parametrize("variant,mode", [("dasha", "independent"),
+                                          ("mvr", "permk")])
+def test_fused_tree_update_streams_mamba2_leaves_in_own_layout(
+        one_chip, monkeypatch, variant, mode):
+    """mamba2-780m's leaves at published widths (4 layers, 4 nodes) through
+    ``fused_tree_update`` for a v5e, keyed DASHA (6 streams) and
+    explicit-mask MVR (8): every leaf's blocks fit the kernel's VMEM, and
+    each leaf whose width is a multiple of 128 and whose second-to-last
+    dim is a multiple of 8 (embed, w_xbc, w_out: 99.9% of the elements
+    that are 128 wide) reaches its kernel and comes back by bitcasts
+    alone — no copy, reshape, transpose or fusion in its shape, its
+    (rows, cols) view or (R, 128) lane rows, outside the mask draw.  The
+    small leaves with 4 rows a node keep (4, 128) tiles in HBM, which
+    the kernel's (8, 128) blocks relayout."""
+    import math
+    import re
+
+    from repro.compress.treelevel import fused_tree_update
+    from repro.kernels import ops
+    from repro.launch.train import arch_config
+    from repro.models import init_params
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = arch_config("mamba2-780m", True, 4)
+    shapes = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tree = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct((4,) + s.shape, jnp.float32,
+                                       sharding=one_chip), shapes)
+    n_in = 4 if variant == "mvr" else 3
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def update(key, *trees):
+        kw = dict(grads_old=trees[1]) if variant == "mvr" else {}
+        m, h_new, g_new = fused_tree_update(
+            key, trees[0], *trees[-2:], mode=mode, a=0.2, p=1 / 32, n=4,
+            variant=variant, b=0.1, **kw)
+        return h_new, g_new, m
+
+    # h and g die with the update, as a step's state does: the kernels
+    # write h_new and g_new over them
+    hlo = jax.jit(update, donate_argnums=(n_in - 1, n_in)).lower(
+        key, *[tree] * n_in).compile().as_text()
+    wide = [x.shape for x in jax.tree_util.tree_leaves(tree)
+            if x.shape[-1] % 128 == 0 and x.shape[-2] % 8 == 0]
+    assert len(wide) == 3
+    ours = set()
+    for shape in wide:
+        size = math.prod(shape)
+        ours |= {shape, ops.node_update_view(shape), (-(-size // 128), 128),
+                 (size,)}
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    moved = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m is None or m.group(2) in _FREE_OPS \
+                or "dasha.compress" in line:
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(",") if d)
+        if dims in ours:
+            moved.append(line.strip()[:120])
+    assert not moved, moved
+    assert hlo.count("tpu_custom_call") >= 13
