@@ -5,7 +5,7 @@ Cases (all at CPU-container scale; emitted to ``BENCH_driver.json``):
 
 * ``smoke_lm_tune`` — the HEADLINE case: the paper's powers-of-two
   stepsize tune (8 gammas) at the smoke LM config.  Old harness: one
-  Python loop per gamma — a fresh ``jax.jit(make_train_step(...))`` per
+  Python loop per gamma — a fresh ``jax.jit(make_method(...).step)`` per
   stepsize (each gamma recompiles), eager per-step batch generation, a
   host ``float()`` read per run.  New: ONE vmapped chunked sweep
   (``driver.sweep``) — compiles once, draws data in-jit, runs all lanes
@@ -40,8 +40,7 @@ from repro.data.pipeline import SyntheticTextConfig, make_node_batches
 from repro.methods import FlatSubstrate, Hyper, Method
 from repro.methods.driver import Driver, Sweeper
 from repro.models import init_params, lm
-from repro.optim.distributed import (DashaTrainConfig, dasha_train_init,
-                                     make_method, make_train_step)
+from repro.optim.distributed import DashaTrainConfig, make_method
 
 QUICK = bool(os.environ.get("REPRO_BENCH_QUICK"))
 REPS = 1 if QUICK else 3     # best-of-N timing (the container is noisy)
@@ -94,16 +93,15 @@ def _bench_smoke_lm_tune() -> Dict:
     def old_tune():
         best = None
         for g in gammas:
-            dcfg = _dcfg(g)
-            st = dasha_train_init(params, dcfg, jax.random.PRNGKey(1))
-            step = jax.jit(make_train_step(dcfg, node_loss))
+            method = make_method(_dcfg(g), node_loss)
+            st = method.init(params, jax.random.PRNGKey(1), init_mode="zeros")
+            step = jax.jit(method.step)
             k = jax.random.PRNGKey(2)
             for _ in range(STEPS_TUNE):
                 k, kb = jax.random.split(k)
-                st, m = step(st, make_node_batches(kb, tcfg, N_NODES,
-                                                   BATCH))
+                st = step(st, make_node_batches(kb, tcfg, N_NODES, BATCH))
             fl = float(eval_loss(
-                st.params, make_node_batches(k, tcfg, N_NODES, BATCH)))
+                st.x, make_node_batches(k, tcfg, N_NODES, BATCH)))
             if best is None or fl < best:
                 best = fl
         return best
@@ -150,29 +148,31 @@ def _bench_smoke_lm_single() -> Dict:
     cfg, params, tcfg, node_loss, eval_loss = _lm_setup(SEQ_1)
     dcfg = _dcfg(0.003)
 
+    method = make_method(dcfg, node_loss)
+    ms0 = method.init(params, jax.random.PRNGKey(1), init_mode="zeros")
+
     # OLD: the pre-driver launch/train.py Python loop
-    state = dasha_train_init(params, dcfg, jax.random.PRNGKey(1))
-    step = jax.jit(make_train_step(dcfg, node_loss))
+    step = jax.jit(method.step)
+    g_norm_sq = jax.jit(lambda g: sum(jnp.sum(jnp.square(x))
+                                      for x in jax.tree_util.tree_leaves(g)))
 
     def py_loop(state, k_data, steps):
         for t in range(steps):
             k_data, k_b = jax.random.split(k_data)
             batch = make_node_batches(k_b, tcfg, N_NODES, BATCH_1)
-            state, metrics = step(state, batch)
+            state = step(state, batch)
             if t % LOG_EVERY == 0 or t == steps - 1:
-                float(eval_loss(state.params, batch))
-                float(metrics["g_norm_sq"])
+                float(eval_loss(state.x, batch))
+                float(g_norm_sq(state.g))
         return state
 
-    py_loop(state, jax.random.PRNGKey(9), 2)           # warm up jits
+    py_loop(ms0, jax.random.PRNGKey(9), 2)             # warm up jits
     py_sps = _best_sps(
         lambda: jax.block_until_ready(
-            py_loop(state, jax.random.PRNGKey(2), STEPS_LM).params),
+            py_loop(ms0, jax.random.PRNGKey(2), STEPS_LM).x),
         STEPS_LM)
 
     # NEW: the chunked compiled driver, data drawn in-jit
-    method = make_method(dcfg, node_loss)
-    ms0 = method.init(params, jax.random.PRNGKey(1), init_mode="zeros")
 
     def data_fn(k, t):
         return make_node_batches(k, tcfg, N_NODES, BATCH_1)

@@ -6,9 +6,7 @@ Each module exposes ``run() -> list[dict]``; rows are printed as CSV with a
 leading `bench` column.  Besides the CSV, a machine-readable
 ``BENCH_summary.json`` records which benches ran, whether they passed,
 their wall seconds, and a headline row each — ``scripts/bench_report.py``
-folds it into the trajectory report.  The roofline report reads the
-dry-run JSON (run ``repro.launch.dryrun`` separately — it needs 512
-placeholder devices).
+folds it into the trajectory report.
 """
 from __future__ import annotations
 
@@ -23,7 +21,7 @@ BENCHES = ["fig1_gradient", "fig2_finite_sum", "fig3_stochastic",
            "fig4_dnn", "fig5_quadratic_pl", "table1_complexity",
            "kernel_bench", "compress_bench", "driver_bench",
            "fed_bench", "fed_scale_bench", "fed_async_bench",
-           "fed_faults_bench", "roofline_report"]
+           "fed_faults_bench"]
 
 
 def _headline(rows) -> dict:

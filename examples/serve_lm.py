@@ -1,5 +1,4 @@
-"""Serving example: batched prefill + KV-cache decode through the public API
-(the serve_step the decode_32k / long_500k dry-run shapes lower).
+"""Serving example: batched prefill + KV-cache decode through the public API.
 
     PYTHONPATH=src python examples/serve_lm.py [--arch gemma3-12b]
 

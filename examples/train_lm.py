@@ -6,9 +6,8 @@ steps (the deliverable-(b) scenario; scaled to this CPU container).
 This wraps the production launcher (repro.launch.train), which runs
 entirely through the compiled run driver (DESIGN.md §10): batches are
 drawn inside the jitted scan, metrics stream as named traces, and the
-checkpoint hook fires between chunks.  On a TPU cluster the same entry
-point takes --full to select the assigned full-size config under the
-16x16 / 2x16x16 meshes validated by the dry-run.
+checkpoint hook fires between chunks.  On a TPU the same entry point
+takes --full to select the published config (--layers N cuts its depth).
 
 ``REPRO_EXAMPLE_ROUNDS`` overrides the step count (the CI smoke path).
 """

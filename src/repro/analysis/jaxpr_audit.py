@@ -17,7 +17,7 @@ hand-rolled one-off walks (DESIGN.md §15):
   report rather than an OOM surprise.
 
 Plus :func:`hlo_collective_report`, which feeds the compiled module text
-through :mod:`repro.launch.hlo_parse` for loop-aware collective bytes.
+through :mod:`repro.analysis.hlo_parse` for loop-aware collective bytes.
 
 All entry points accept the *uncompiled* callable plus example
 arguments; they trace via ``jax.make_jaxpr`` / ``jax.jit(...).lower``
@@ -258,7 +258,7 @@ def scan_carry_report(fn: Callable, *args: Any,
 def hlo_collective_report(fn: Callable, *args: Any,
                           **jit_kwargs: Any) -> Dict[str, float]:
     """Loop-aware collective byte totals for the compiled module, via
-    :mod:`repro.launch.hlo_parse` (trip-count multipliers included)."""
-    from repro.launch import hlo_parse
+    :mod:`repro.analysis.hlo_parse` (trip-count multipliers included)."""
+    from repro.analysis import hlo_parse
     txt = _compile(fn, *args, **jit_kwargs).as_text() or ""
     return hlo_parse.collective_bytes_loop_aware(txt)

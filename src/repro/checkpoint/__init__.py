@@ -1,5 +1,4 @@
 from repro.checkpoint.io import (FORMAT_VERSION,  # noqa: F401
                                  checkpoint_meta, checkpoint_step,
-                                 load_checkpoint, load_method_state,
-                                 load_state, save_checkpoint,
-                                 save_method_state, save_state)
+                                 load_checkpoint, load_state,
+                                 save_checkpoint, save_state)

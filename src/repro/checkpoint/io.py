@@ -5,12 +5,12 @@ Two layers:
 * the generic pytree save/load of the seed (``save_checkpoint`` /
   ``load_checkpoint``) — kept for params-only snapshots;
 * the VERSIONED full-state format (``save_state`` / ``load_state``, v2):
-  when the saved tree is a NamedTuple (``MethodState``,
-  ``DashaTrainState``, optimizer states nest freely inside), the meta
-  records per-field leaf spans so restore is matched BY FIELD NAME — a
-  checkpoint written with extra retired fields (the seed-era
-  ``prev_params``) restores into today's state by dropping them, and
-  missing-field mismatches fail loudly instead of loading garbage.
+  when the saved tree is a NamedTuple (``MethodState``, optimizer states
+  nest freely inside), the meta records per-field leaf spans so restore
+  is matched BY FIELD NAME — a checkpoint written with extra retired
+  fields (the seed-era ``prev_params``) restores into today's state by
+  dropping them, and missing-field mismatches fail loudly instead of
+  loading garbage.
 
 Restore is bit-identical for every dtype npz can hold natively; bfloat16
 is stored as float32 (a lossless widening) and cast back on load.  This
@@ -19,9 +19,8 @@ lifts the seed's "checkpointing is params-only" restriction: the driver
 (x / g / g_local / h_local / opt_state / key / t / bits_sent), and a
 restored run continues bit-identically (tested in tests/test_driver.py).
 
-v1 checkpoints (no ``version`` in meta) load positionally; a v1
-``DashaTrainState`` whose retired ``prev_params`` slot held a full
-params-shaped copy is detected by leaf count and its leaves are skipped.
+v1 checkpoints (no ``version`` in meta) and non-NamedTuple trees load
+positionally.
 
 Multi-host sharded checkpointing (array-serialization per shard) remains
 out of scope for the CPU container.
@@ -37,9 +36,6 @@ import numpy as np
 
 #: current on-disk format version (meta.json "version")
 FORMAT_VERSION = 2
-
-#: state fields that existed in older formats and are dropped on restore
-RETIRED_FIELDS = ("prev_params",)
 
 
 def _write(path: str, leaves, meta: dict) -> None:
@@ -122,13 +118,16 @@ def _field_spans(tree) -> Optional[list]:
             for f in tree._fields]
 
 
-def save_state(path: str, state: Any, *, step: int = 0,
+def save_state(path: str, state: Any, *, step: Optional[int] = None,
                extra: Optional[dict] = None) -> None:
     """Save a full state pytree in the versioned (v2) format.
 
     When ``state`` is a NamedTuple the meta records per-field leaf spans,
     enabling field-name-matched restore across state-layout revisions.
+    ``step`` defaults to the state's round counter ``t`` (0 without one).
     """
+    if step is None:
+        step = int(np.asarray(getattr(state, "t", 0)))
     leaves, treedef = jax.tree_util.tree_flatten(state)
     _write(path, leaves, {"version": FORMAT_VERSION,
                           "treedef": str(treedef), "step": step,
@@ -137,13 +136,14 @@ def save_state(path: str, state: Any, *, step: int = 0,
 
 
 def load_state(path: str, like: Any) -> Any:
-    """Restore a v2 (or v1) state checkpoint into the structure of ``like``.
+    """Restore a v2 (or v1) state checkpoint into the structure of ``like``:
+    a restored ``MethodState`` continues bit-identically under the driver
+    (same data keys via ``fold_in(data_key, t)``, same method RNG via the
+    restored ``key``).
 
     v2 + NamedTuple: fields are matched by NAME — saved fields absent from
     ``like`` (retired fields such as ``prev_params``) are dropped; fields
-    of ``like`` absent from the save raise.  Otherwise: positional, with
-    the v1 ``prev_params`` leaf-count heuristic (a seed-era
-    ``DashaTrainState`` whose second slot duplicated ``params``).
+    of ``like`` absent from the save raise.  Otherwise: positional.
     """
     saved, meta = _read(path)
     like_leaves, treedef = jax.tree_util.tree_flatten(like)
@@ -153,7 +153,6 @@ def load_state(path: str, like: Any) -> Any:
         for f in fields:
             spans[f["name"]] = saved[off:off + f["leaves"]]
             off += f["leaves"]
-        dropped = [n for n in spans if n not in like._fields]
         missing = [n for n in like._fields if n not in spans]
         if missing:
             raise ValueError(f"checkpoint at {path!r} lacks state fields "
@@ -166,35 +165,8 @@ def load_state(path: str, like: Any) -> Any:
                 raise ValueError(f"field {name!r}: saved {len(got)} leaves"
                                  f" vs expected {want}")
             picked.extend(got)
-        del dropped  # retired fields silently skipped (documented shim)
         return jax.tree_util.tree_unflatten(treedef,
                                             _cast_into(picked, like_leaves))
     # v1 / non-NamedTuple: positional restore
-    if (len(saved) != len(like_leaves) and hasattr(like, "_fields")
-            and like._fields and like._fields[0] == "params"):
-        # seed-era DashaTrainState: prev_params (slot 2) was a full
-        # params-shaped copy — exactly one extra params-sized leaf span
-        p = len(jax.tree_util.tree_leaves(like.params))
-        if len(saved) == len(like_leaves) + p:
-            saved = saved[:p] + saved[2 * p:]
     return jax.tree_util.tree_unflatten(treedef,
                                         _cast_into(saved, like_leaves))
-
-
-# ---------------------------------------------------------------------------
-# MethodState convenience (the driver's checkpoint cadence)
-# ---------------------------------------------------------------------------
-
-def save_method_state(path: str, state: Any, *, step: Optional[int] = None,
-                      extra: Optional[dict] = None) -> None:
-    """Full-``MethodState`` checkpoint; ``step`` defaults to ``state.t``."""
-    if step is None:
-        step = int(np.asarray(getattr(state, "t", 0)))
-    save_state(path, state, step=step, extra=extra)
-
-
-def load_method_state(path: str, like: Any) -> Any:
-    """Restore a ``MethodState`` → bit-identical continuation under the
-    driver (same data keys via ``fold_in(data_key, t)``, same method RNG
-    via the restored ``key``)."""
-    return load_state(path, like)

@@ -560,7 +560,7 @@ class FedSim:
         at ``clock0``; traces cover the resumed segment only.
         ``checkpoint(state, next_round, wall_clock)`` fires after every
         chunk — save the MethodState there
-        (:func:`repro.checkpoint.io.save_method_state`) and a later run
+        (:func:`repro.checkpoint.io.save_state`) and a later run
         can restore bit-identically."""
         metric_fn = self._metric_fn(metric_fn)
         if not (0 <= int(start_round) <= rounds):

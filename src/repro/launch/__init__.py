@@ -1,1 +1,1 @@
-"""Launcher: mesh, dry-run, train/serve drivers."""
+"""The training launcher: node mesh, its state shardings, checkpoint cadence."""

@@ -41,14 +41,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.checkpoint.io import (checkpoint_step, load_method_state,
-                                 save_method_state)
+from repro.checkpoint.io import checkpoint_step, load_state, save_state
 from repro.compress.treelevel import kernel_draw_count, kernel_layout_count
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTextConfig, make_node_batches
 from repro.methods import MethodState
 from repro.methods.driver import Driver
 from repro.models import init_params, lm
+from repro.models.sharding import dp_axes, param_specs, to_shardings
+from repro.optim.base import AdamState
 from repro.optim.distributed import (DashaTrainConfig, make_method,
                                      payload_frac)
 
@@ -162,15 +163,28 @@ def node_mesh(n_nodes: int, n_devices: Optional[int] = None):
                          devices=devices)
 
 
-def _state_shardings(cfg, params_s, mesh, dasha, state_s):
-    from repro.launch.specs import node_state_specs
-    from repro.models.sharding import to_shardings
-    p_specs, p_specs_f, per_node, opt_specs = node_state_specs(
-        cfg, params_s, mesh, dasha, state_s.opt_state)
-    specs = MethodState(x=p_specs_f, g=p_specs_f, g_local=per_node,
-                        h_local=per_node, opt_state=opt_specs, key=P(),
-                        t=P(), bits_sent=P())
-    return p_specs, to_shardings(specs, mesh)
+def node_state_specs(cfg, mesh, dasha: DashaTrainConfig,
+                     state_s: MethodState) -> MethodState:
+    """PartitionSpecs of the trainer's ``MethodState`` on ``mesh``, for
+    the state ``state_s`` (shapes only).
+
+    Params, g and the server optimizer's moments follow the param rule
+    (:func:`repro.models.sharding.param_specs`); each node's h_i / g_i
+    puts its node axis on the data axes, the param rule after it; key,
+    round counter and payload count are replicated.  The ``x`` specs are
+    also the ones pinned onto each node's gradient."""
+    dp = dp_axes(mesh)
+    p_specs = param_specs(cfg, state_s.x, mesh)
+    per_node = jax.tree_util.tree_map(
+        lambda s: P(dp, *tuple(s)), p_specs,
+        is_leaf=lambda x: isinstance(x, P))
+    if dasha.server_opt == "adam":
+        opt_specs: Any = AdamState(mu=p_specs, nu=p_specs, count=P())
+    else:
+        opt_specs = jax.tree_util.tree_map(lambda x: P(), state_s.opt_state)
+    return MethodState(x=p_specs, g=p_specs, g_local=per_node,
+                       h_local=per_node, opt_state=opt_specs, key=P(),
+                       t=P(), bits_sent=P())
 
 
 def main(argv=None) -> TrainRun:
@@ -227,15 +241,15 @@ def main(argv=None) -> TrainRun:
         state = jax.jit(init_state)(k_init, k_state)
     else:
         state_s = jax.eval_shape(init_state, k_init, k_state)
-        grad_specs, shardings = _state_shardings(cfg, params_s, mesh, dasha,
-                                                 state_s)
-        state = jax.jit(init_state, out_shardings=shardings)(k_init, k_state)
-        method = make_method(dasha, node_loss, grad_specs=grad_specs)
+        specs = node_state_specs(cfg, mesh, dasha, state_s)
+        state = jax.jit(init_state, out_shardings=to_shardings(specs, mesh))(
+            k_init, k_state)
+        method = make_method(dasha, node_loss, grad_specs=specs.x)
     done = 0
     if args.resume:
         if not args.ckpt:
             raise SystemExit("--resume requires --ckpt")
-        state = load_method_state(args.ckpt, state)
+        state = load_state(args.ckpt, state)
         done = checkpoint_step(args.ckpt)
         print(f"[train] resumed from {args.ckpt} at step {done}")
 
@@ -294,7 +308,7 @@ def main(argv=None) -> TrainRun:
               f"coords/node={float(ms.bits_sent):.3e} {experts}"
               f"({time.time()-t0:.1f}s)")
         if args.ckpt:
-            save_method_state(args.ckpt, ms, step=int(ms.t))
+            save_state(args.ckpt, ms)
 
     remaining = args.steps - done
     if remaining <= 0:

@@ -124,12 +124,6 @@ class ArchConfig:
     def uses_attention(self) -> bool:
         return self.arch_type != "ssm"
 
-    @property
-    def is_subquadratic(self) -> bool:
-        """Eligible for the long_500k shape (DESIGN.md §4)."""
-        return (self.arch_type in ("ssm", "hybrid")
-                or self.sliding_window > 0)
-
     def param_count(self) -> int:
         """Analytic parameter count (used for MODEL_FLOPS = 6 N D)."""
         from repro.models.init import init_params  # noqa: cyclic-light

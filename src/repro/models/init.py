@@ -1,8 +1,7 @@
 """Parameter initialisation for every architecture family.
 
 Layers are STACKED along a leading axis (scanned at apply time) so a model
-compiles one layer body regardless of depth — essential to keep 512-device
-dry-run compile times sane.
+compiles one layer body regardless of depth.
 """
 from __future__ import annotations
 

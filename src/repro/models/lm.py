@@ -19,7 +19,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as attn_lib
 from repro.models.blocks import (block_decode, block_prefill, cross_block,
@@ -53,17 +52,6 @@ def _positions(B: int, S: int) -> jax.Array:
 
 def _maybe_remat(fn, use_remat: bool):
     return jax.checkpoint(fn) if use_remat else fn
-
-
-def _seq_constrain(x: jax.Array, axis: Optional[str]) -> jax.Array:
-    """Megatron-SP style residual-stream sharding: between blocks the
-    (B, S, d) carry lives sharded over ``axis`` on the SEQUENCE dim, so the
-    per-layer saved remat residual is S/tp long; GSPMD all-gathers around
-    the attention mixer and reduce-scatters back.  Only used on the training
-    path (under vmap with spmd_axis_name, which supplies the batch axes)."""
-    if axis is None:
-        return x
-    return jax.lax.with_sharding_constraint(x, P(None, axis, None))
 
 
 # ---------------------------------------------------------------------------
@@ -124,8 +112,7 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
             image_embeds: Optional[jax.Array] = None,
             frames: Optional[jax.Array] = None,
             remat: bool = True,
-            last_only: bool = False,
-            seq_shard: Optional[str] = None
+            last_only: bool = False
             ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits (B,S,V_padded), aux_loss scalar).  ``last_only`` slices
     the hidden states to the final position BEFORE the vocab projection
@@ -138,12 +125,10 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
             x = x[:, -1:]
         return _logits(cfg, params, x), jnp.float32(0)
     x = _embed(cfg, params, tokens)
-    x = _seq_constrain(x, seq_shard)
     pos = _positions(B, S)
 
     if at == "ssm":
         def body(carry, lp):
-            carry = _seq_constrain(carry, seq_shard)
             return mamba_block_prefill(lp, carry, cfg), None
         x, _ = jax.lax.scan(_maybe_remat(body, remat), x, params["layers"])
         aux = jnp.float32(0)
@@ -153,7 +138,6 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
 
         def body(carry, inp):
             lp, idx = inp
-            carry = _seq_constrain(carry, seq_shard)
             def with_attn(h):
                 out, _ = block_prefill(params["shared_attn"], h, pos, cfg)
                 return out
@@ -169,7 +153,6 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
 
         def body(carry, inp):
             lp, idx = inp
-            carry = _seq_constrain(carry, seq_shard)
             h, aux = block_prefill(lp, carry, pos, cfg)
             def with_cross(hh):
                 cp = jax.tree_util.tree_map(
@@ -188,7 +171,6 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
 
         def body(carry, inp):
             lp, cp = inp
-            carry = _seq_constrain(carry, seq_shard)
             h, aux = block_prefill(lp, carry, pos, cfg)
             h = cross_block(cp, h, enc, cfg)
             return h, aux
@@ -202,10 +184,8 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
 
         def group(carry, inp):
             locals_p, global_p = inp
-            carry = _seq_constrain(carry, seq_shard)
 
             def local_body(h, lp):
-                h = _seq_constrain(h, seq_shard)
                 out, a = block_prefill(lp, h, pos, cfg, window=W)
                 return out, a
             h, a1 = jax.lax.scan(local_body, carry, locals_p)
@@ -221,7 +201,6 @@ def forward(cfg: ArchConfig, params: Dict, tokens: jax.Array, *,
         W = cfg.sliding_window
 
         def body(carry, lp):
-            carry = _seq_constrain(carry, seq_shard)
             h, a = block_prefill(lp, carry, pos, cfg, window=W)
             return h, a
 
@@ -256,8 +235,7 @@ def _encoder_forward(cfg: ArchConfig, params: Dict, frames: jax.Array,
 # ---------------------------------------------------------------------------
 
 def loss_fn(cfg: ArchConfig, params: Dict, batch: Dict, *,
-            remat: bool = True,
-            seq_shard: Optional[str] = None) -> Tuple[jax.Array, Dict]:
+            remat: bool = True) -> Tuple[jax.Array, Dict]:
     counters = {}
     if cfg.arch_type == "pattern":
         x, routed, dropped = pattern_forward(cfg, params, batch["tokens"],
@@ -267,8 +245,7 @@ def loss_fn(cfg: ArchConfig, params: Dict, batch: Dict, *,
     else:
         logits, aux = forward(cfg, params, batch["tokens"],
                               image_embeds=batch.get("image_embeds"),
-                              frames=batch.get("frames"), remat=remat,
-                              seq_shard=seq_shard)
+                              frames=batch.get("frames"), remat=remat)
     labels = batch["labels"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
     nll = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.kernels.ops import grouped_matmul
 from repro.models.common import ArchConfig, mlp_apply, relu2
-from repro.models.sharding import constrain_expert_major, constrain_token_major
 
 
 def _capacity(cfg: ArchConfig, num_tokens: int) -> int:
@@ -39,8 +38,7 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ArchConfig,
       contains scatters which GSPMD shards poorly on big meshes).
     * ``einsum``  — Switch-Transformer one-hot matmul dispatch over token
       chunks (MXU-friendly, no scatters anywhere in fwd/bwd; costs extra
-      dispatch FLOPs ~ 2*E*C/ (3*K*ff) of the expert GEMMs).  This is the
-      mode the production dry-run uses for training.
+      dispatch FLOPs ~ 2*E*C/ (3*K*ff) of the expert GEMMs).
     """
     if cfg.moe_dispatch == "einsum" and not dropless:
         return _moe_ffn_einsum(p, x, cfg)
@@ -71,19 +69,12 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ArchConfig,
     slot_tok = jnp.full((E, C + 1), N, jnp.int32)
     slot_tok = slot_tok.at[e_flat, c_flat].set(t_flat, mode="drop")
     xt_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], 0)
-    buffers = constrain_expert_major(xt_pad[slot_tok[:, :C]])  # (E, C, d)
+    buffers = xt_pad[slot_tok[:, :C]]                          # (E, C, d)
 
-    # expert computation: (E, C, d) x (E, d, ff) — expert dim shards on
-    # "model".  Weights are constrained AT USE so their cotangents (the
-    # scan-backward grad accumulators) compile expert-sharded too.
-    wg = constrain_expert_major(p["w_gate"])
-    wi = constrain_expert_major(p["w_in"])
-    wo = constrain_expert_major(p["w_out"])
-    h = jnp.einsum("ecd,edf->ecf", buffers, wg)
-    h = constrain_expert_major(
-        jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buffers, wi))
-    y = constrain_expert_major(
-        jnp.einsum("ecf,efd->ecd", h, wo))                     # (E, C, d)
+    # expert computation: (E, C, d) x (E, d, ff)
+    h = jnp.einsum("ecd,edf->ecf", buffers, p["w_gate"])
+    h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buffers, p["w_in"])
+    y = jnp.einsum("ecf,efd->ecd", h, p["w_out"])              # (E, C, d)
 
     # combine back: one (N, d) gather per k (never materialise (N*K, d))
     y_pad = jnp.concatenate([y, jnp.zeros((E, 1, d), y.dtype)], 1)
@@ -93,7 +84,6 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ArchConfig,
     for k in range(K):
         w_k = (gate_vals[:, k] * keep[:, k]).astype(xt.dtype)  # (N,)
         out = out + y_pad[e_nk[:, k], c_nk[:, k]] * w_k[:, None]
-    out = constrain_token_major(out)
 
     if cfg.num_shared_experts:
         out = out + mlp_apply({"w_gate": p["shared_w_gate"],
@@ -143,18 +133,12 @@ def _moe_ffn_einsum(p: Dict, x: jax.Array, cfg: ArchConfig
         keep = pos < C
         oh_c = jax.nn.one_hot(jnp.where(keep, pos, C), C + 1,
                               dtype=xg.dtype)[..., :C]         # (G, K, C)
-        disp = jnp.einsum("gke,gkc->gec", oh_e.astype(xg.dtype), oh_c)
-        disp = constrain_token_major(disp)                     # (G, E, C)
-        buf = constrain_expert_major(
-            jnp.einsum("gec,gd->ecd", disp, xg))               # (E, C, d)
-        wg = constrain_expert_major(p["w_gate"])
-        wi = constrain_expert_major(p["w_in"])
-        wo = constrain_expert_major(p["w_out"])
-        h = jnp.einsum("ecd,edf->ecf", buf, wg)
-        h = constrain_expert_major(
-            jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buf, wi))
-        y = constrain_expert_major(
-            jnp.einsum("ecf,efd->ecd", h, wo))                 # (E, C, d)
+        disp = jnp.einsum("gke,gkc->gec", oh_e.astype(xg.dtype),
+                          oh_c)                                # (G, E, C)
+        buf = jnp.einsum("gec,gd->ecd", disp, xg)              # (E, C, d)
+        h = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
+        h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buf, p["w_in"])
+        y = jnp.einsum("ecf,efd->ecd", h, p["w_out"])          # (E, C, d)
         comb = jnp.einsum("gke,gkc,gk->gec", oh_e.astype(xg.dtype), oh_c,
                           (gate_vals * keep).astype(xg.dtype))
         out = jnp.einsum("gec,ecd->gd", comb, y)

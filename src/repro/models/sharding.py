@@ -1,9 +1,9 @@
-"""Sharding policy: PartitionSpecs for params, caches and batches.
+"""Sharding policy: PartitionSpecs for params and decode caches.
 
 Megatron-style 2D: batch over ("pod","data"), tensor dims over "model" —
 but only when the dimension is divisible by the model-axis size; otherwise the
-tensor is replicated (recorded by ``sharding_report``).  Stacked-layer leading
-axes are always unsharded (they are scanned).
+tensor is replicated.  Stacked-layer leading axes are always unsharded (they
+are scanned).
 """
 from __future__ import annotations
 
@@ -16,46 +16,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.common import ArchConfig
 
 DP_AXES = ("pod", "data")   # logical batch axes (pod may be absent)
-
-# ---------------------------------------------------------------------------
-# trace-time expert-sharding context: moe_ffn pins its big (E, C, ...)
-# intermediates to the "model" axis so GSPMD keeps BOTH the (vmapped) node
-# axis and the expert axis sharded instead of replicating one of them.
-# ---------------------------------------------------------------------------
-import contextvars
-from contextlib import contextmanager
-
-_EXPERT_AXIS: "contextvars.ContextVar" = contextvars.ContextVar(
-    "expert_shard_axis", default=None)
-
-
-@contextmanager
-def expert_sharding(axis):
-    """Set the mesh axis that expert-major MoE intermediates shard over
-    (None = no constraints; the CPU/eager default)."""
-    tok = _EXPERT_AXIS.set(axis)
-    try:
-        yield
-    finally:
-        _EXPERT_AXIS.reset(tok)
-
-
-def constrain_expert_major(x):
-    """Pin an (E, ...) tensor's leading dim to the active expert axis."""
-    axis = _EXPERT_AXIS.get()
-    if axis is None:
-        return x
-    spec = P(axis, *([None] * (x.ndim - 1)))
-    return jax.lax.with_sharding_constraint(x, spec)
-
-
-def constrain_token_major(x):
-    """Pin an (N_tokens, ...) tensor to be expert-axis-replicated (its node
-    axis sharding comes from the vmap spmd_axis_name lifting)."""
-    axis = _EXPERT_AXIS.get()
-    if axis is None:
-        return x
-    return jax.lax.with_sharding_constraint(x, P(*([None] * x.ndim)))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -74,24 +34,9 @@ def _div(n: int, tp: int) -> bool:
     return tp > 1 and n % tp == 0
 
 
-def param_specs(cfg: ArchConfig, params: Any, mesh: Mesh,
-                fsdp: bool = False, hd_fallback: bool = True) -> Any:
-    """Mirror the params pytree with PartitionSpecs (path-name rules).
-
-    ``fsdp=True`` additionally shards, for every matrix leaf, the first
-    trailing dim not already taken by "model" over the data axes (ZeRO-3:
-    params/g gathered on use).  Never applied to per-node DASHA state whose
-    leading node axis already occupies the data axes.
-
-    ``hd_fallback=False`` disables the head_dim-sharding fallback for
-    non-divisible head counts: attention weights replicate instead.  Right
-    for SERVE paths of long-context archs — the per-layer all-reduce of
-    hd-partial logits at 32k context costs far more ICI than the few-GB of
-    replicated attention weights (see EXPERIMENTS.md §Perf-4).
-    """
+def param_specs(cfg: ArchConfig, params: Any, mesh: Mesh) -> Any:
+    """Mirror the params pytree with PartitionSpecs (path-name rules)."""
     tp = tp_size(mesh)
-    dp = dp_axes(mesh)
-    dpn = dp_size(mesh)
     H, G = cfg.num_heads, cfg.num_kv_heads
     Hs = cfg.ssm_nheads if cfg.ssm_state else 0
     E = cfg.num_experts
@@ -99,7 +44,7 @@ def param_specs(cfg: ArchConfig, params: Any, mesh: Mesh,
     def model_if(ok: bool):
         return "model" if ok else None
 
-    hd_ok = _div(cfg.head_dim or 0, tp) and hd_fallback
+    hd_ok = _div(cfg.head_dim or 0, tp)
 
     def qkv_spec(n_heads: int) -> Tuple:
         """(d, heads, hd) weight: shard heads when divisible, else fall back
@@ -200,23 +145,9 @@ def param_specs(cfg: ArchConfig, params: Any, mesh: Mesh,
         if (name in ("w_gate", "w_in") and not is_expert):
             spec = (None, model_if(_div(cfg.d_ff, tp)))
         lead = leaf.ndim - len(spec)
-        spec = list(((None,) * lead) + tuple(spec))
-        if fsdp and leaf.ndim >= 2 and dp:
-            for i in range(lead, leaf.ndim):
-                if spec[i] is None and leaf.shape[i] % dpn == 0 \
-                        and leaf.shape[i] >= dpn:
-                    spec[i] = dp if len(dp) > 1 else dp[0]
-                    break
-        return P(*spec)
+        return P(*((None,) * lead), *spec)
 
     return jax.tree_util.tree_map_with_path(rule, params)
-
-
-def batch_specs(cfg: ArchConfig, mesh: Mesh, batch_size: int) -> Dict:
-    dp = dp_axes(mesh)
-    b = dp if batch_size % dp_size(mesh) == 0 else None
-    return {"tokens": P(b, None), "labels": P(b, None),
-            "image_embeds": P(b, None, None), "frames": P(b, None, None)}
 
 
 def cache_specs(cfg: ArchConfig, cache: Any, mesh: Mesh,
@@ -279,21 +210,6 @@ def cache_specs(cfg: ArchConfig, cache: Any, mesh: Mesh,
         return P(*spec)
 
     return jax.tree_util.tree_map_with_path(rule, cache)
-
-
-def sharding_report(cfg: ArchConfig, params: Any, mesh: Mesh) -> str:
-    """Human-readable summary of which tensors replicate (for DESIGN.md)."""
-    specs = param_specs(cfg, params, mesh)
-    lines = []
-    flat = jax.tree_util.tree_leaves_with_path(specs)
-    shapes = jax.tree_util.tree_leaves_with_path(params)
-    n_rep = 0
-    for (p, s), (_, leaf) in zip(flat, shapes):
-        if all(a is None for a in s) and leaf.ndim >= 2:
-            n_rep += 1
-    lines.append(f"{cfg.name}: {n_rep}/{len(flat)} matrix params replicated "
-                 f"on model axis (size {tp_size(mesh)})")
-    return "\n".join(lines)
 
 
 def to_shardings(tree_specs: Any, mesh: Mesh) -> Any:

@@ -1,3 +1,2 @@
 from repro.optim.base import SGD, Adam, apply_updates  # noqa: F401
-from repro.optim.distributed import (DashaTrainConfig, DashaTrainState,  # noqa: F401
-                                     dasha_train_init, make_train_step)
+from repro.optim.distributed import DashaTrainConfig  # noqa: F401

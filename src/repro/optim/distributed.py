@@ -1,54 +1,38 @@
-"""DASHA as a first-class distributed training feature — thin shim.
+"""The trainer's configuration and its Method.
 
-This is the paper's algorithm family integrated with model training on a
-TPU mesh: the "nodes" are the data-parallel groups (axis n =
-("pod","data")); every method quantity (h_i, g_i, messages) is a PYTREE
-shaped like the params with a leading node axis, so each leaf keeps its
-tensor-parallel ("model") sharding.
-
-The algorithm itself now comes from the methods layer (DESIGN.md §7):
-:meth:`repro.methods.Method.build` over a
-:class:`repro.methods.TreeSubstrate` whose oracle derives per-node
-gradients from the loss, with compression through
-:class:`repro.methods.TreeCompression` (the
-:mod:`repro.compress.treelevel` modes — independent | shared_coords |
-permk — including the fused Pallas path).  Because the h-updates are
-registry rules, the trainer supports EVERY variant — ``dasha``, ``mvr``,
-and (new) ``page`` and ``sync_mvr``, the latter with the probability-p
-uncompressed megabatch sync round and honest per-round payload accounting
-(``payload_coords`` metric; the static ``payload_frac`` expectation folds
-the dense sync rounds in via
-:func:`repro.methods.accounting.expected_payload_frac`).
+:class:`DashaTrainConfig` holds what a training run sets of the paper's
+algorithm family: the server stepsize, the compressor (independent |
+shared_coords | permk Bernoulli-RandP, the fraction it sends), the
+variant (``dasha`` | ``mvr`` | ``page`` | ``sync_mvr``), the node count,
+the server optimizer, the fused Pallas path and the dtype of each node's
+h_i / g_i.  :func:`make_method` turns it and a per-node loss into the
+engine's :class:`repro.methods.Method` (DESIGN.md §7): the variant's rule
+over a :class:`repro.methods.TreeSubstrate`, whose oracle derives each
+node's gradient from the loss, with compression through
+:class:`repro.methods.TreeCompression`.  Every quantity of the method
+(h_i, g_i, messages) is a pytree shaped like the params with a leading
+node axis.  ``method.init`` then ``methods.driver.Driver`` run it.
 
 ``use_kernel=True`` routes every mode x variant through the fused Pallas
 path; the MVR/SARAH h-update is recomputed inside the kernel pass
-(:class:`repro.methods.rules.MvrFusion`), preserving the seed's one-HBM-
-pass property.
+(:class:`repro.methods.rules.MvrFusion`), so each step reads and writes
+the node state once.  :func:`payload_frac` is the static expected
+fraction of coordinates sent, SYNC-MVR's dense sync rounds included.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-# canonical compression primitives (single definitions live in repro.compress;
-# re-exported here for back-compat with seed-era imports — the trainer's own
-# compression calls now live in repro.methods.substrates.TreeCompression)
-from repro.compress import draw_mask  # noqa: F401
-from repro.compress import (bernoulli_compress,  # noqa: F401
-                            fused_tree_update, leaf_keys, omega_bernoulli,
-                            omega_permk, permk_compress)
-from repro.methods import (BatchLossOracle, Hyper, Method, MethodState,
-                           TreeCompression, TreeSubstrate,
-                           expected_payload_frac, get_rule)
-from repro.optim.base import SGD, Adam, apply_updates  # noqa: F401
+from repro.compress import omega_bernoulli, omega_permk
+from repro.methods import (BatchLossOracle, Hyper, Method, TreeCompression,
+                           TreeSubstrate, expected_payload_frac, get_rule)
+from repro.optim.base import SGD, Adam
 
 PyTree = Any
-
-#: seed-era alias; prefer repro.compress.leaf_keys
-_leaf_keys = leaf_keys
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,8 +48,6 @@ class DashaTrainConfig:
     use_kernel: bool = False         # fused Pallas path (all modes/variants)
     # --- memory / sharding knobs (beyond-paper TPU adaptation) ------------
     state_dtype: str = "float32"     # h_i/g_i storage: float32 | bfloat16
-    seq_shard: bool = False          # Megatron-SP residual-stream sharding
-    fsdp: bool = False               # ZeRO-3 params/g over the data axis
     spmd_axes: Optional[Tuple[str, ...]] = None  # vmap spmd_axis_name
 
     @property
@@ -90,52 +72,10 @@ class DashaTrainConfig:
                      b=self.b, p=self.p)
 
 
-class DashaTrainState(NamedTuple):
-    """Trainer-facing state; ``prev_params`` (dead since the methods-layer
-    refactor — both gradient points of an MVR round are evaluated inside
-    the same step) is RETIRED from the structure.  v1 checkpoints that
-    still carry it restore through the versioned format's field-name shim
-    (:func:`repro.checkpoint.io.load_state`)."""
-
-    params: PyTree        # replicated over nodes, sharded over "model"
-    g: PyTree             # server estimator (like params, fp32)
-    h_local: PyTree       # per-node h_i: leading node axis
-    g_local: PyTree       # per-node g_i
-    opt_state: Any
-    key: jax.Array
-    step: jax.Array
-
-
-# ---------------------------------------------------------------------------
-# init / step
-# ---------------------------------------------------------------------------
-
 def _server_opt(cfg: DashaTrainConfig):
     if cfg.server_opt == "adam":
         return Adam(lr=cfg.gamma)
     return SGD(lr=cfg.gamma)
-
-
-def dasha_train_init(params: PyTree, cfg: DashaTrainConfig,
-                     key: jax.Array, grads0: Optional[PyTree] = None
-                     ) -> DashaTrainState:
-    """``grads0``: optional (n, *shape) initial per-node gradients (paper
-    initialisation h_i^0 = g_i^0 = grad f_i(x^0)); zeros otherwise."""
-    n = cfg.n_nodes
-    sdt = cfg.jax_state_dtype
-    f32 = lambda t: jax.tree_util.tree_map(lambda x: x.astype(sdt), t)
-    if grads0 is None:
-        per_node = jax.tree_util.tree_map(
-            lambda p: jnp.zeros((n,) + p.shape, sdt), params)
-    else:
-        per_node = f32(grads0)
-    g = jax.tree_util.tree_map(
-        lambda h: jnp.mean(h.astype(jnp.float32), 0), per_node)
-    opt = _server_opt(cfg)
-    return DashaTrainState(params=params, g=g,
-                           h_local=per_node, g_local=per_node,
-                           opt_state=opt.init(params), key=key,
-                           step=jnp.zeros((), jnp.int32))
 
 
 def make_method(cfg: DashaTrainConfig,
@@ -181,46 +121,3 @@ def payload_frac(cfg: DashaTrainConfig) -> float:
                            n=cfg.n_nodes)
     return expected_payload_frac(get_rule(cfg.variant), cfg.hyper,
                                  comp.static_frac)
-
-
-def method_state(state: DashaTrainState,
-                 bits_sent: Optional[jax.Array] = None) -> MethodState:
-    """View a trainer state as the engine's MethodState."""
-    if bits_sent is None:
-        bits_sent = jnp.zeros((), jnp.float32)
-    return MethodState(x=state.params, g=state.g, g_local=state.g_local,
-                       h_local=state.h_local, opt_state=state.opt_state,
-                       key=state.key, t=state.step, bits_sent=bits_sent)
-
-
-def train_state(ms: MethodState) -> DashaTrainState:
-    """Project a MethodState back onto the trainer state (drops the
-    cumulative ``bits_sent`` — the trainer traces it as a metric)."""
-    return DashaTrainState(params=ms.x, g=ms.g, h_local=ms.h_local,
-                           g_local=ms.g_local, opt_state=ms.opt_state,
-                           key=ms.key, step=ms.t)
-
-
-def make_train_step(cfg: DashaTrainConfig,
-                    loss_fn: Callable[[PyTree, Any], jax.Array],
-                    grad_specs: Optional[PyTree] = None
-                    ) -> Callable[[DashaTrainState, Any],
-                                  Tuple[DashaTrainState, dict]]:
-    """Build the jit-able train step for ANY registry variant (thin wrapper
-    over :func:`make_method`; see it for the argument contracts)."""
-    method = make_method(cfg, loss_fn, grad_specs)
-    frac = payload_frac(cfg)
-
-    def step(state: DashaTrainState, batch) -> Tuple[DashaTrainState, dict]:
-        # NOTE: jnp.sum(x*x), NOT jnp.vdot — vdot ravels each leaf, which
-        # forces GSPMD to all-gather the full (sharded) estimator (20 GB/dev
-        # for a 16B model) just to compute a scalar metric.
-        gn = sum(jnp.sum(jnp.square(x))
-                 for x in jax.tree_util.tree_leaves(state.g))
-        ms = method.step(method_state(state), batch)
-        metrics = {"g_norm_sq": gn,
-                   "payload_frac": jnp.float32(frac),
-                   "payload_coords": ms.bits_sent}
-        return train_state(ms), metrics
-
-    return step

@@ -1,7 +1,7 @@
 import os
 
-# Smoke tests and benches must see ONE device (the dry-run sets its own 512
-# placeholder devices in its own process — never here).
+# Smoke tests and benches must see ONE device (a test that needs several
+# sets them in its own subprocess).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax

@@ -12,24 +12,21 @@ Contract families:
 * in-jit data: data_fn(fold_in(data_key, t), t) inside the scan matches a
   hand-rolled python loop drawing the same batches;
 * checkpoint format: versioned save/load roundtrips every MethodState
-  field bit-exactly, and v1/v2 checkpoints carrying the retired
-  prev_params field restore into today's DashaTrainState.
+  field bit-exactly, and a v2 checkpoint carrying the retired
+  prev_params field restores into today's MethodState.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.checkpoint.io import (load_method_state, load_state,
-                                 save_checkpoint, save_method_state,
-                                 save_state)
+from repro.checkpoint.io import load_state, save_state
 from repro.compress import make_round_compressor
 from repro.core.oracles import FiniteSumProblem, StochasticProblem
 from repro.data.pipeline import synthetic_classification
-from repro.methods import FlatSubstrate, Hyper, Method
+from repro.methods import FlatSubstrate, Hyper, Method, MethodState
 from repro.methods import driver as drv
-from repro.optim.distributed import (DashaTrainConfig, DashaTrainState,
-                                     dasha_train_init, make_method)
+from repro.optim.distributed import DashaTrainConfig, make_method
 
 N_NODES, M, D, K = 4, 16, 24, 6
 
@@ -158,8 +155,8 @@ def test_checkpoint_resume_is_bit_identical(build, tmp_path):
 
     # N rounds -> checkpoint -> restore -> N rounds
     half, tr_a = drv.run(m, st0, n, chunk=3, metrics=mets, metric_every=4)
-    save_method_state(path, half)
-    restored = load_method_state(path, jax.tree_util.tree_map(
+    save_state(path, half)
+    restored = load_state(path, jax.tree_util.tree_map(
         jnp.zeros_like, half))
     _assert_states_equal(restored, half)
     resumed, tr_b = drv.run(m, restored, n, chunk=3, metrics=mets,
@@ -306,8 +303,8 @@ def test_data_fn_resume_regenerates_same_stream(tmp_path):
     half, _ = drv.run(method, st0, 3, data_fn=data_fn, data_key=dk,
                       chunk=2)
     path = str(tmp_path / "ck")
-    save_method_state(path, half)
-    restored = load_method_state(
+    save_state(path, half)
+    restored = load_state(
         path, jax.tree_util.tree_map(jnp.zeros_like, half))
     resumed, _ = drv.run(method, restored, 3, data_fn=data_fn, data_key=dk,
                          chunk=2)
@@ -324,8 +321,8 @@ def test_method_state_roundtrip_preserves_dtypes(tmp_path):
     m, st0 = _sync_mvr()
     st, _ = drv.run(m, st0, 3)
     path = str(tmp_path / "ck")
-    save_method_state(path, st)
-    out = load_method_state(path, jax.tree_util.tree_map(jnp.zeros_like,
+    save_state(path, st)
+    out = load_state(path, jax.tree_util.tree_map(jnp.zeros_like,
                                                          st))
     _assert_states_equal(out, st)
     assert out.key.dtype == st.key.dtype
@@ -334,56 +331,23 @@ def test_method_state_roundtrip_preserves_dtypes(tmp_path):
 
 
 def test_v2_checkpoint_drops_retired_prev_params_field(tmp_path):
-    """A checkpoint written with the old state layout (prev_params holding
-    a full params copy) restores into today's DashaTrainState through the
-    field-name shim."""
+    """A checkpoint written with a retired field beside today's fields
+    (the seed-era prev_params, a full params copy) restores into
+    MethodState through the field-name shim."""
     import collections
-    params, loss, cfg = (_mlp_method()[1], None,
-                         DashaTrainConfig(gamma=0.05, n_nodes=2))
-    new = dasha_train_init(params, cfg, jax.random.PRNGKey(5))
+    method, params, data_fn, _ = _mlp_method()
+    st0 = method.init(params, jax.random.PRNGKey(5), init_mode="zeros")
+    new, _ = drv.run(method, st0, 2, data_fn=data_fn,
+                     data_key=jax.random.PRNGKey(6))
     OldState = collections.namedtuple(
-        "DashaTrainState", ["params", "prev_params", "g", "h_local",
-                            "g_local", "opt_state", "key", "step"])
-    old = OldState(params=new.params, prev_params=new.params, g=new.g,
-                   h_local=new.h_local, g_local=new.g_local,
-                   opt_state=new.opt_state, key=new.key, step=new.step)
+        "MethodState", ("x", "prev_params") + MethodState._fields[1:])
+    old = OldState(prev_params=params, **new._asdict())
     path = str(tmp_path / "ck")
     save_state(path, old, step=7)
     out = load_state(path, jax.tree_util.tree_map(jnp.zeros_like, new))
-    assert "prev_params" not in out._fields
+    assert isinstance(out, MethodState)
     for a, b in zip(jax.tree_util.tree_leaves(out),
-                    jax.tree_util.tree_leaves(new)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_v1_positional_checkpoint_prev_params_heuristic(tmp_path):
-    """A SEED-era (v1, no field spans) checkpoint whose prev_params slot
-    duplicated params: the positional loader detects the extra leaf span
-    and skips it."""
-    import json
-    import os
-    params = {"w": jnp.arange(6.0).reshape(2, 3), "b": jnp.ones((3,))}
-    cfg = DashaTrainConfig(gamma=0.05, n_nodes=2)
-    new = dasha_train_init(params, cfg, jax.random.PRNGKey(6))
-    import collections
-    OldState = collections.namedtuple(
-        "DashaTrainState", ["params", "prev_params", "g", "h_local",
-                            "g_local", "opt_state", "key", "step"])
-    old = OldState(params=new.params, prev_params=new.params, g=new.g,
-                   h_local=new.h_local, g_local=new.g_local,
-                   opt_state=new.opt_state, key=new.key, step=new.step)
-    path = str(tmp_path / "ck")
-    save_checkpoint(path, old, step=3)      # generic (no field spans)
-    # strip v2 markers to simulate a seed-era meta
-    mp = os.path.join(path, "meta.json")
-    with open(mp) as f:
-        meta = json.load(f)
-    meta.pop("version", None)
-    with open(mp, "w") as f:
-        json.dump(meta, f)
-    out = load_state(path, jax.tree_util.tree_map(jnp.zeros_like, new))
-    for a, b in zip(jax.tree_util.tree_leaves(out),
-                    jax.tree_util.tree_leaves(new)):
+                    jax.tree_util.tree_leaves(new), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

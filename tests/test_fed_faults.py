@@ -385,8 +385,8 @@ def _drill(cls, variant, fm, kill_chunk, tmp_path, rounds=40, chunk=8):
     calls = {"n": 0}
 
     def cp(state, next_round, now):
-        ckpt_io.save_method_state(path, state, step=next_round,
-                                  extra={"wall_clock": now})
+        ckpt_io.save_state(path, state, step=next_round,
+                           extra={"wall_clock": now})
         calls["n"] += 1
         if calls["n"] == kill_chunk + 1:
             raise _Killed
@@ -398,7 +398,7 @@ def _drill(cls, variant, fm, kill_chunk, tmp_path, rounds=40, chunk=8):
     # "new process": fresh sim, state restored from disk only
     sim2, like = build()
     meta = ckpt_io.checkpoint_meta(path)
-    st2 = ckpt_io.load_method_state(path, like)
+    st2 = ckpt_io.load_state(path, like)
     res = sim2.run(st2, rounds, start_round=int(meta["step"]),
                    clock0=float(meta["extra"]["wall_clock"]))
     cut = int(meta["step"])

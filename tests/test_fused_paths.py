@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 from repro.compress import fused_tree_update, permk_compress
-from repro.optim.distributed import (DashaTrainConfig, dasha_train_init,
-                                     make_train_step)
+from repro.optim.distributed import DashaTrainConfig, make_method
 
 KEY = jax.random.PRNGKey(0)
 
@@ -56,12 +55,13 @@ def test_kernel_path_matches_reference_path(mode, variant):
         cfg = DashaTrainConfig(gamma=0.05, compression=0.5, n_nodes=2,
                                mode=mode, variant=variant, b=0.3,
                                use_kernel=uk)
-        state = dasha_train_init(params, cfg, jax.random.PRNGKey(5))
-        step = jax.jit(make_train_step(cfg, loss))
+        method = make_method(cfg, loss)
+        state = method.init(params, jax.random.PRNGKey(5), init_mode="zeros")
+        step = jax.jit(method.step)
         for b in batches:
-            state, _ = step(state, b)
+            state = step(state, b)
         outs.append(state)
-    for name, tree_a, tree_b in (("params", outs[0].params, outs[1].params),
+    for name, tree_a, tree_b in (("params", outs[0].x, outs[1].x),
                                  ("g", outs[0].g, outs[1].g),
                                  ("h", outs[0].h_local, outs[1].h_local),
                                  ("g_local", outs[0].g_local,
@@ -119,8 +119,9 @@ def test_fused_training_reduces_loss(mode, variant):
     cfg = DashaTrainConfig(gamma=0.01, compression=0.25, mode=mode,
                            variant=variant, b=0.2, n_nodes=4,
                            server_opt="adam", use_kernel=True)
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(3))
-    step = jax.jit(make_train_step(cfg, loss))
+    method = make_method(cfg, loss)
+    state = method.init(params, jax.random.PRNGKey(3), init_mode="zeros")
+    step = jax.jit(method.step)
     key = jax.random.PRNGKey(4)
     b0 = make_batch(key, 4)
     flat = jax.tree_util.tree_map(
@@ -128,8 +129,8 @@ def test_fused_training_reduces_loss(mode, variant):
     l0 = float(loss(params, flat))
     for _ in range(200):
         key, kb = jax.random.split(key)
-        state, _ = step(state, make_batch(kb, 4))
-    assert float(loss(state.params, flat)) < 0.6 * l0
+        state = step(state, make_batch(kb, 4))
+    assert float(loss(state.x, flat)) < 0.6 * l0
 
 
 # ---------------------------------------------------------------------------

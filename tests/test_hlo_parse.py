@@ -1,10 +1,10 @@
-"""Edge cases for the loop-aware HLO collective parser (launch/hlo_parse):
+"""Edge cases for the loop-aware HLO collective parser (analysis/hlo_parse):
 nested while loops multiply trip counts, ``.clone``-suffixed computation
 names resolve, and async ``-start``/``-done`` collective pairs are counted
 exactly once (the ``-done`` half is a wait, not a second transfer)."""
-from repro.launch.hlo_parse import (collective_bytes_loop_aware,
-                                    computation_multipliers,
-                                    split_computations, trip_count)
+from repro.analysis.hlo_parse import (collective_bytes_loop_aware,
+                                      computation_multipliers,
+                                      split_computations, trip_count)
 
 
 def _hlo(*comps):

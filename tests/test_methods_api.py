@@ -9,9 +9,10 @@ Contract families:
 * all five variants (dasha | page | mvr | sync_mvr | marina) run through
   Method.build on both substrates and keep the estimator invariant
   g == mean_i g_i;
-* the trainer (make_train_step) now reaches page and sync_mvr, trains, and
-  keeps the invariant; sync_mvr's prob-p dense rounds show up in the
-  unified payload accounting (payload_frac / payload_coords metrics);
+* the trainer (make_method over a TreeSubstrate) reaches page and
+  sync_mvr, trains, and keeps the invariant; sync_mvr's prob-p dense
+  rounds show up in the unified payload accounting (the static
+  payload_frac and the per-round growth of bits_sent);
 * Hyper.from_theory assembles the Section-6 constants per variant.
 """
 import jax
@@ -26,8 +27,8 @@ from repro.methods import (VARIANTS, FlatSubstrate, Hyper, LeafProblemOracle,
                            Method, TreeSubstrate, expected_payload_frac,
                            get_rule, round_payload)
 from repro.optim.base import SGD
-from repro.optim.distributed import (DashaTrainConfig, dasha_train_init,
-                                     make_train_step)
+from repro.optim.distributed import (DashaTrainConfig, make_method,
+                                     payload_frac)
 
 N_NODES, M, D, K = 4, 16, 24, 6
 ALL_VARIANTS = ("dasha", "page", "mvr", "sync_mvr", "marina")
@@ -156,8 +157,14 @@ def test_unknown_variant_raises():
 
 
 # ---------------------------------------------------------------------------
-# the trainer reaches page / sync_mvr (make_train_step-equivalent training)
+# the trainer reaches page / sync_mvr
 # ---------------------------------------------------------------------------
+
+def _trainer(cfg, loss, params, key):
+    """The trainer's zero-initialised state and its jitted step."""
+    method = make_method(cfg, loss)
+    return method.init(params, key, init_mode="zeros"), jax.jit(method.step)
+
 
 def _mlp_problem():
     key = jax.random.PRNGKey(0)
@@ -186,8 +193,7 @@ def test_trainer_new_variants_learn_and_keep_invariant(variant, use_kernel):
     cfg = DashaTrainConfig(gamma=0.01, compression=0.25, variant=variant,
                            p=0.2, b=0.2, n_nodes=4, server_opt="adam",
                            use_kernel=use_kernel)
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(3))
-    step = jax.jit(make_train_step(cfg, loss))
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(3))
     key = jax.random.PRNGKey(4)
     b0 = make_batch(key, 4)
     flat = jax.tree_util.tree_map(
@@ -195,8 +201,8 @@ def test_trainer_new_variants_learn_and_keep_invariant(variant, use_kernel):
     l0 = float(loss(params, flat))
     for _ in range(200):
         key, kb = jax.random.split(key)
-        state, metrics = step(state, make_batch(kb, 4))
-    assert float(loss(state.params, flat)) < 0.6 * l0
+        state = step(state, make_batch(kb, 4))
+    assert float(loss(state.x, flat)) < 0.6 * l0
     for g, gl in zip(jax.tree_util.tree_leaves(state.g),
                      jax.tree_util.tree_leaves(state.g_local)):
         np.testing.assert_allclose(np.asarray(g),
@@ -210,33 +216,33 @@ def test_trainer_new_variants_learn_and_keep_invariant(variant, use_kernel):
 
 def test_trainer_payload_metrics_bill_sync_rounds():
     """sync_mvr's prob-p dense rounds inflate payload_frac beyond the
-    compressed fraction, and per-round payload_coords is either the
+    compressed fraction, and each round adds to bits_sent either the
     compressed or the dense coordinate count."""
     params, loss, make_batch = _mlp_problem()
     d_total = sum(x.size for x in jax.tree_util.tree_leaves(params))
     comp_frac, p_sync = 0.25, 0.3
     cfg = DashaTrainConfig(gamma=0.01, compression=comp_frac,
                            variant="sync_mvr", p=p_sync, n_nodes=4)
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(5))
-    step = jax.jit(make_train_step(cfg, loss))
-    expected = comp_frac + p_sync * (1 - comp_frac)
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(5))
+    assert payload_frac(cfg) == pytest.approx(
+        comp_frac + p_sync * (1 - comp_frac))
     seen = set()
     key = jax.random.PRNGKey(6)
     for _ in range(30):
         key, kb = jax.random.split(key)
-        state, metrics = step(state, make_batch(kb, 4))
-        assert float(metrics["payload_frac"]) == pytest.approx(expected)
-        seen.add(round(float(metrics["payload_coords"]), 3))
+        sent = float(state.bits_sent)
+        state = step(state, make_batch(kb, 4))
+        seen.add(round(float(state.bits_sent) - sent, 3))
     assert seen <= {round(comp_frac * d_total, 3), float(d_total)}
     assert len(seen) == 2        # both branches taken in 30 rounds (p=0.3)
 
     # plain dasha: no sync rounds, frac is the compressed fraction
     cfg0 = DashaTrainConfig(gamma=0.01, compression=comp_frac, n_nodes=4)
-    _, m0 = jax.jit(make_train_step(cfg0, loss))(
-        dasha_train_init(params, cfg0, jax.random.PRNGKey(7)),
-        make_batch(key, 4))
-    assert float(m0["payload_frac"]) == pytest.approx(comp_frac)
-    assert float(m0["payload_coords"]) == pytest.approx(comp_frac * d_total)
+    state0, step0 = _trainer(cfg0, loss, params, jax.random.PRNGKey(7))
+    assert float(state0.bits_sent) == 0.0
+    state0 = step0(state0, make_batch(key, 4))
+    assert payload_frac(cfg0) == pytest.approx(comp_frac)
+    assert float(state0.bits_sent) == pytest.approx(comp_frac * d_total)
 
 
 def test_flat_and_trainer_accounting_agree():
@@ -347,19 +353,3 @@ def test_metric_every_subsamples_and_matches_dense_trace():
     assert t4.shape == t1.shape
     for i in range(12):
         np.testing.assert_allclose(t4[i], t1[4 * (i // 4)], rtol=1e-6)
-
-
-def test_trainer_state_has_no_dead_prev_params_field():
-    """The dead seed-era prev_params slot is RETIRED from the state
-    structure itself (v1 checkpoints restore through the versioned
-    field-name shim, tested in test_driver.py)."""
-    from repro.optim.distributed import DashaTrainState
-    assert "prev_params" not in DashaTrainState._fields
-    params, loss, make_batch = _mlp_problem()
-    cfg = DashaTrainConfig(gamma=0.05, compression=0.5, variant="mvr",
-                           b=0.3, n_nodes=2)
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(14))
-    state, _ = jax.jit(make_train_step(cfg, loss))(
-        state, make_batch(jax.random.PRNGKey(15), 2))
-    assert set(state._fields) == {"params", "g", "h_local", "g_local",
-                                  "opt_state", "key", "step"}

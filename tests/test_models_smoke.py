@@ -83,7 +83,7 @@ def test_smoke_decode_step(arch):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_full_config_matches_assignment(arch):
     """The FULL configs carry the exact assigned numbers (never allocated
-    here — only shape arithmetic via eval_shape in the dry-run)."""
+    here)."""
     cfg = get_config(arch)
     expected = {
         "mamba2-780m": (48, 1536, 50280),

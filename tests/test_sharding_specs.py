@@ -1,5 +1,5 @@
 """Sharding policy: every PartitionSpec divides its dim, for every arch on
-both production meshes (validated with AbstractMesh — no devices needed)."""
+a 16x16 and a 2x16x16 mesh (validated with AbstractMesh — no devices needed)."""
 import jax
 import numpy as np
 import pytest
@@ -41,9 +41,8 @@ def test_param_specs_divisible(arch, mesh_name):
     cfg = get_config(arch)
     mesh = MESHES[mesh_name]
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    for fsdp in (False, True):
-        specs = param_specs(cfg, params, mesh, fsdp=fsdp)
-        _check_divisible(params, specs, mesh, f"{arch}/{mesh_name}/f{fsdp}")
+    specs = param_specs(cfg, params, mesh)
+    _check_divisible(params, specs, mesh, f"{arch}/{mesh_name}")
 
 
 @pytest.mark.parametrize("arch", all_arch_ids())

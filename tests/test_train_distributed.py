@@ -6,11 +6,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.optim.distributed import (DashaTrainConfig, bernoulli_compress,
-                                     dasha_train_init, make_train_step,
-                                     permk_compress)
+from repro.compress import bernoulli_compress, permk_compress
+from repro.optim.distributed import DashaTrainConfig, make_method
 
 KEY = jax.random.PRNGKey(0)
+
+
+def _trainer(cfg, loss, params, key):
+    """The trainer's zero-initialised state and its jitted step."""
+    method = make_method(cfg, loss)
+    return method.init(params, key, init_mode="zeros"), jax.jit(method.step)
 
 
 def _mlp_problem():
@@ -41,8 +46,7 @@ def test_training_reduces_loss(mode, variant):
     cfg = DashaTrainConfig(gamma=0.01, compression=0.25, mode=mode,
                            variant=variant, b=0.2, n_nodes=4,
                            server_opt="adam")
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(3))
-    step = jax.jit(make_train_step(cfg, loss))
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(3))
     key = jax.random.PRNGKey(4)
     batch0 = make_batch(key, 4)
     flat = jax.tree_util.tree_map(
@@ -50,8 +54,8 @@ def test_training_reduces_loss(mode, variant):
     l0 = float(loss(params, flat))
     for t in range(300):
         key, kb = jax.random.split(key)
-        state, _ = step(state, make_batch(kb, 4))
-    l1 = float(loss(state.params, flat))
+        state = step(state, make_batch(kb, 4))
+    l1 = float(loss(state.x, flat))
     assert l1 < 0.5 * l0, (l0, l1)
 
 
@@ -63,13 +67,12 @@ def test_kernel_path_matches_reference_path():
     for uk in (False, True):
         cfg = DashaTrainConfig(gamma=0.05, compression=0.5, n_nodes=2,
                                use_kernel=uk)
-        state = dasha_train_init(params, cfg, jax.random.PRNGKey(5))
-        step = jax.jit(make_train_step(cfg, loss))
+        state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(5))
         for b in batches:
-            state, m = step(state, b)
+            state = step(state, b)
         outs.append(state)
-    for a, b in zip(jax.tree_util.tree_leaves(outs[0].params),
-                    jax.tree_util.tree_leaves(outs[1].params)):
+    for a, b in zip(jax.tree_util.tree_leaves(outs[0].x),
+                    jax.tree_util.tree_leaves(outs[1].x)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
 
@@ -116,12 +119,11 @@ def test_bernoulli_compress_unbiased():
 def test_invariant_g_mean_g_local_training():
     params, loss, make_batch = _mlp_problem()
     cfg = DashaTrainConfig(gamma=0.05, compression=0.5, n_nodes=4)
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(6))
-    step = jax.jit(make_train_step(cfg, loss))
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(6))
     key = jax.random.PRNGKey(7)
     for _ in range(5):
         key, kb = jax.random.split(key)
-        state, _ = step(state, make_batch(kb, 4))
+        state = step(state, make_batch(kb, 4))
     for g, gl in zip(jax.tree_util.tree_leaves(state.g),
                      jax.tree_util.tree_leaves(state.g_local)):
         np.testing.assert_allclose(np.asarray(g),
@@ -133,9 +135,8 @@ def test_bf16_state_still_learns():
     params, loss, make_batch = _mlp_problem()
     cfg = DashaTrainConfig(gamma=0.01, compression=0.25, n_nodes=4,
                            server_opt="adam", state_dtype="bfloat16")
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(8))
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(8))
     assert state.h_local["w1"].dtype == jnp.bfloat16
-    step = jax.jit(make_train_step(cfg, loss))
     key = jax.random.PRNGKey(9)
     b0 = make_batch(key, 4)
     flat = jax.tree_util.tree_map(
@@ -143,8 +144,8 @@ def test_bf16_state_still_learns():
     l0 = float(loss(params, flat))
     for _ in range(300):
         key, kb = jax.random.split(key)
-        state, _ = step(state, make_batch(kb, 4))
-    l1 = float(loss(state.params, flat))
+        state = step(state, make_batch(kb, 4))
+    l1 = float(loss(state.x, flat))
     assert l1 < 0.6 * l0, (l0, l1)
 
 
@@ -162,8 +163,7 @@ def test_shared_coords_training():
     params, loss, make_batch = _mlp_problem()
     cfg = DashaTrainConfig(gamma=0.01, compression=0.25, n_nodes=4,
                            mode="shared_coords", server_opt="adam")
-    state = dasha_train_init(params, cfg, jax.random.PRNGKey(3))
-    step = jax.jit(make_train_step(cfg, loss))
+    state, step = _trainer(cfg, loss, params, jax.random.PRNGKey(3))
     key = jax.random.PRNGKey(4)
     b0 = make_batch(key, 4)
     flat = jax.tree_util.tree_map(
@@ -171,5 +171,5 @@ def test_shared_coords_training():
     l0 = float(loss(params, flat))
     for _ in range(300):
         key, kb = jax.random.split(key)
-        state, _ = step(state, make_batch(kb, 4))
-    assert float(loss(state.params, flat)) < 0.5 * l0
+        state = step(state, make_batch(kb, 4))
+    assert float(loss(state.x, flat)) < 0.5 * l0

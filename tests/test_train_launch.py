@@ -1,5 +1,6 @@
-"""The training launcher (`repro.launch.train`): the depth cut, and the node
-axis spread over several devices against the same nodes vmapped on one.
+"""The training launcher (`repro.launch.train`): the depth cut, the node
+axis spread over several devices against the same nodes vmapped on one,
+and the sharding rule of the trainer state on that mesh.
 
 The multi-device run needs a process whose CPU backend has four devices
 (``--xla_force_host_platform_device_count``), set before JAX starts, so it
@@ -14,7 +15,7 @@ import sys
 import numpy as np
 import pytest
 
-from repro.configs import get_config, get_smoke_config
+from repro.configs import all_arch_ids, get_config, get_smoke_config
 from repro.launch import train
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -90,3 +91,42 @@ def test_node_mesh_matches_one_device_vmap(extra):
     for a, b in zip(mesh["x"], one["x"], strict=True):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("server_opt", ["sgd", "adam"])
+@pytest.mark.parametrize("arch", all_arch_ids() + ["nemotron-3-nano-30b-a3b"])
+def test_node_state_specs_cover_the_state(arch, server_opt):
+    """The launcher's sharding rule on a (4, 1) ("data", "model") mesh
+    gives every leaf of the published config's trainer state a spec: the
+    spec tree is the state's tree, each node's h_i / g_i puts its node
+    axis on the data axis, and no spec names more axes than its leaf has."""
+    import jax
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    from repro.models import init_params
+    from repro.optim.distributed import DashaTrainConfig, make_method
+
+    cfg = get_config(arch)
+    mesh = AbstractMesh((4, 1), ("data", "model"))
+    dasha = DashaTrainConfig(gamma=0.01, n_nodes=4, server_opt=server_opt,
+                             spmd_axes=("data",))
+    method = make_method(dasha, lambda p, b: 0.0)
+    params_s = jax.eval_shape(lambda k: init_params(cfg, k),
+                              jax.random.PRNGKey(0))
+    state_s = jax.eval_shape(
+        lambda p, k: method.init(p, k, init_mode="zeros"), params_s,
+        jax.random.PRNGKey(1))
+    specs = train.node_state_specs(cfg, mesh, dasha, state_s)
+
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    assert jax.tree_util.tree_structure(specs, is_leaf=is_spec) \
+        == jax.tree_util.tree_structure(state_s)
+    for field in ("h_local", "g_local"):
+        node_specs = jax.tree_util.tree_leaves(getattr(specs, field),
+                                               is_leaf=is_spec)
+        assert node_specs and all(s[0] in ("data", ("data",))
+                                  for s in node_specs)
+    for spec, leaf in zip(
+            jax.tree_util.tree_leaves(specs, is_leaf=is_spec),
+            jax.tree_util.tree_leaves(state_s), strict=True):
+        assert len(spec) <= leaf.ndim, (spec, leaf.shape)
