@@ -17,7 +17,8 @@ are parity-testable under the same key.  The fused path draws a leaf's
 ``independent`` u8-threshold mask inside the kernel from the leaf key
 (:func:`kernel_draw_threshold`, counted by :func:`kernel_draw_count`)
 and streams each leaf in its own layout where the kernel's blocks tile it
-(:func:`kernel_layout_count`); every other mask draw runs under the
+(:func:`kernel_layout_count`), its gradient in the oracle's dtype
+(:func:`kernel_grad_dtype_count`); every other mask draw runs under the
 ``dasha.compress`` named scope (the mean over nodes under
 ``dasha.aggregate``), which names its ops in a device trace.
 """
@@ -277,6 +278,28 @@ def kernel_layout_count(tree: PyTree, *, specs: Optional[PyTree] = None,
            if node_update_view(shape(x, spec)) is not None]
     return KernelLayouts(len(own), len(leaves), sum(own),
                          sum(int(x.size) for x in leaves))
+
+
+class KernelGradDtypes(KernelDraws):
+    """How much of a tree's gradient the fused kernel reads in the dtype the
+    oracle made it in, with no cast pass before the kernel."""
+
+    __slots__ = ()
+    label = "own dtype"
+
+
+def kernel_grad_dtype_count(grads: PyTree) -> KernelGradDtypes:
+    """Leaves and elements of the gradient tree ``grads`` (leaves ``(n,
+    *shape)``, arrays or shapes, in the oracle's dtypes) that
+    :func:`fused_tree_update` hands to its kernels as they are
+    (:func:`repro.kernels.ops.kernel_grad_dtype`), rather than as an f32
+    copy."""
+    from repro.kernels.ops import kernel_grad_dtype
+    leaves = jax.tree_util.tree_leaves(grads)
+    own = [int(x.size) for x in leaves
+           if kernel_grad_dtype(x.dtype) == x.dtype]
+    return KernelGradDtypes(len(own), len(leaves), sum(own),
+                            sum(int(x.size) for x in leaves))
 
 
 def fused_tree_update(key: jax.Array, grads_new: PyTree, h: PyTree,

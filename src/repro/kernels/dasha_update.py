@@ -35,13 +35,22 @@ Two forms of each kernel share one update arithmetic:
 Both multiply the same f32 {0, 1} mask into the same expression, so they
 give bit-equal outputs for the same mask.
 
-Tiling: every operand is one 2-D (rows, cols) f32 view, and the grid walks
+Dtypes: each tensor operand may be f32 or bf16 on its own, and the kernel
+casts every block to f32 as it reads it from VMEM, so the arithmetic is
+f32 whatever the operands: the training step hands its bf16 gradients in
+as the backward pass made them, and no f32 copy of a gradient is written
+to HBM for the kernel to read back.  The outputs take h's dtype.
+
+Tiling: every operand is one 2-D (rows, cols) view, and the grid walks
 it in (block_rows, block_cols) blocks, partial at the edges (their
 out-of-range writes are dropped).  :func:`node_update_block` sizes the
-block from the view's shape alone, to ``BLOCK_ELEMENTS`` (1024 x 128) f32
-elements of each operand, lanes padded to 128.  The pipeline
-double-buffers every operand, so a program holds at most
-``2 x tensors x 512 KiB`` of VMEM: keyed DASHA streams 6 tensors (3 in,
+block from the view's shape alone, to ``BLOCK_ELEMENTS`` (1024 x 128)
+elements of each operand, lanes padded to 128, whatever the operands'
+dtypes: the v5e compiler takes a bf16 operand in the same blocks as an
+f32 one, row blocks that are no multiple of 16 (8, 24, 40) included.
+The pipeline double-buffers every operand, so a program holds at most
+``2 x tensors x 512 KiB`` of VMEM (less for a bf16 operand): keyed DASHA
+streams 6 tensors (3 in,
 3 out), keyed MVR 7, the explicit forms one more, 6 to 8 MiB plus one
 strip's hash, inside the 16 MiB of scoped VMEM that Mosaic grants a kernel
 on TPU v5e by default.  The v5e compiler refuses twice that (2048 x 128
@@ -114,11 +123,14 @@ def _dasha_mvr_math(a, b, scale, gn, go, h, gl, mask):
 
 
 def _explicit_kernel(math: Callable, n_scalars: int, *refs):
-    """Inputs: scalars, tensors, then the f32 mask; three outputs."""
+    """Inputs: scalars, tensors, then the f32 mask; three outputs.  Each
+    block is cast to f32 as it is read (an identity for an f32 operand,
+    exact for a bf16 one), each output to its own dtype as it is written."""
     scalars = [r[0] for r in refs[:n_scalars]]
     ins, outs = refs[n_scalars:-3], refs[-3:]
-    for o, v in zip(outs, math(*scalars, *(r[...] for r in ins))):
-        o[...] = v
+    blocks = (r[...].astype(jnp.float32) for r in ins)
+    for o, v in zip(outs, math(*scalars, *blocks)):
+        o[...] = v.astype(o.dtype)
 
 
 def strip_mask(k1, k2, row, col, shape: Tuple[int, int], cols: int,
@@ -158,7 +170,8 @@ def _walk(extent: int, step: int, body: Callable) -> None:
 def _keyed_kernel(math: Callable, n_scalars: int, thresh: int, cols: int,
                   strip_rows: int, key_ref, *refs):
     """Inputs: the key's two u32 words (SMEM), scalars, tensors; three
-    outputs.  Draws each strip's mask and applies ``math`` to the strip;
+    outputs.  Draws each strip's mask and applies ``math`` to the strip,
+    cast to f32 as in :func:`_explicit_kernel`;
     strips of ``strip_rows`` x 128 tile the block, the last row and column
     strips as wide as what is left."""
     k1, k2 = key_ref[0], key_ref[1]
@@ -173,8 +186,9 @@ def _keyed_kernel(math: Callable, n_scalars: int, thresh: int, cols: int,
         mask = strip_mask(k1, k2, row0 + jnp.asarray(r0).astype(jnp.uint32),
                           col0 + jnp.asarray(c0).astype(jnp.uint32),
                           (nr, nc), cols, thresh)
-        for o, v in zip(outs, math(*scalars, *(r[at] for r in ins), mask)):
-            o[at] = v
+        blocks = (r[at].astype(jnp.float32) for r in ins)
+        for o, v in zip(outs, math(*scalars, *blocks, mask)):
+            o[at] = v.astype(o.dtype)
 
     _walk(block_rows, strip_rows, lambda r0, nr: _walk(
         block_cols, LANE, lambda c0, nc: strip(r0, nr, c0, nc)))
@@ -185,15 +199,17 @@ def _node_update_call(kernel: Callable, name: str, scalars: Sequence[float],
                       block: Optional[Tuple[int, int]] = None,
                       key: jax.Array | None = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One pass over same-shape (rows, cols) f32 ``tensors`` in ``block``s
+    """One pass over same-shape (rows, cols) ``tensors`` in ``block``s
     (default :func:`node_update_block`), with f32 ``scalars`` and, for a
     keyed kernel, the (2,) u32 ``key`` in SMEM first.  ``tensors`` end
     with (h, g_local) (then the mask, for an explicit kernel), which
     h_new and g_local_new overwrite in place: where the caller's h and
     g_local die with the update, as a step's state does, XLA then needs
-    no buffer, and no copy into the step's state, for the outputs."""
+    no buffer, and no copy into the step's state, for the outputs.  Each
+    tensor may have its own float dtype (a bf16 gradient beside the f32
+    state): the kernel casts every block to f32 in VMEM, and the outputs
+    take h's dtype."""
     x = tensors[0]
-    dt = x.dtype
     rows, cols = x.shape
     block = block or node_update_block(rows, cols)
     if block is None:
@@ -202,12 +218,13 @@ def _node_update_call(kernel: Callable, name: str, scalars: Sequence[float],
     tens = pl.BlockSpec(block, lambda i, j: (i, j))
     scal = pl.BlockSpec((1,), lambda i, j: (0,))
     in_specs = [scal] * len(scalars) + [tens] * len(tensors)
-    operands = [jnp.full((1,), s, dt) for s in scalars] + list(tensors)
+    operands = [jnp.full((1,), s, jnp.float32) for s in scalars] \
+        + list(tensors)
     if key is not None:
         in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
         operands = [key] + operands
     h_at = len(operands) - (3 if key is None else 2)
-    shape = jax.ShapeDtypeStruct(x.shape, dt)
+    shape = jax.ShapeDtypeStruct(x.shape, operands[h_at].dtype)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -225,8 +242,8 @@ def dasha_update_pallas(grad: jax.Array, h: jax.Array, g_local: jax.Array,
                         block: Optional[Tuple[int, int]] = None,
                         interpret: bool = True
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """All array args: (rows, cols) float32.  Returns (m, h_new,
-    g_local_new)."""
+    """All array args: (rows, cols), f32 or bf16 (see the module
+    docstring).  Returns (m, h_new, g_local_new) in h's dtype."""
     kernel = functools.partial(_explicit_kernel, _dasha_math, 2)
     return _node_update_call(kernel, "dasha_update", (a, scale),
                              (grad, h, g_local, mask), interpret, block)
@@ -254,7 +271,7 @@ def dasha_mvr_update_pallas(grad_new: jax.Array, grad_old: jax.Array,
                             block: Optional[Tuple[int, int]] = None,
                             interpret: bool = True
                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """MVR variant; all array args (rows, cols) float32."""
+    """MVR variant; array args as :func:`dasha_update_pallas`'s."""
     kernel = functools.partial(_explicit_kernel, _dasha_mvr_math, 3)
     return _node_update_call(kernel, "dasha_mvr_update", (a, b, scale),
                              (grad_new, grad_old, h, g_local, mask),

@@ -9,7 +9,10 @@ reshape.  A tensor whose view no kernel block tiles (a 1-D vector; a row
 too wide for one block whose width is no multiple of 128; see
 :func:`repro.kernels.dasha_update.node_update_block`) takes the lane path
 instead: flattened, padded to (R, 128) lane rows and cut back after the
-kernel, which costs a relayout each way.  Where a call runs is decided
+kernel, which costs a relayout each way.  A gradient enters either path
+in the dtype the backward pass made it in (bf16 or f32,
+:func:`kernel_grad_dtype`), and the kernel casts it to f32 on the VMEM
+block: no f32 copy of it is written to HBM.  Where a call runs is decided
 when it is traced, from the default backend: on a TPU every kernel
 compiles to Mosaic (and a kernel the chip refuses raises); on any other
 backend the kernel body runs in the Pallas interpreter.
@@ -99,17 +102,37 @@ def _key_words(key: jax.Array) -> jax.Array:
     return key.astype(jnp.uint32)
 
 
+#: the gradient dtypes that the node-update kernels read as they are,
+#: casting each block to f32 in VMEM; a gradient of any other dtype is
+#: cast to f32 before the kernel, by a pass of its own
+KERNEL_GRAD_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
+
+
+def kernel_grad_dtype(dtype) -> jnp.dtype:
+    """The dtype in which the node-update kernels read a gradient of
+    ``dtype``."""
+    dtype = jnp.dtype(dtype)
+    return dtype if dtype in KERNEL_GRAD_DTYPES else jnp.dtype(jnp.float32)
+
+
 def _fused(kernel, tensors, *scalars, mask=None, key=None):
-    """Run a node-update kernel on same-shape ``tensors`` as f32 in their
-    own layout's view (else in lane rows), then the ``mask`` in that view
-    or the ``key``'s words, then the static ``scalars``.  Returns (m,
-    h_new, g_local_new) with the first tensor's shape and dtype."""
-    shape, dtype = tensors[0].shape, tensors[0].dtype
+    """Run a node-update kernel on same-shape ``tensors`` (the gradients,
+    then h and g_local) in their own layout's view (else in lane rows),
+    then the ``mask`` in that view or the ``key``'s words, then the static
+    ``scalars``.  A gradient enters in its own dtype where the kernel reads
+    it (:func:`kernel_grad_dtype`), so no f32 copy of it reaches HBM; the
+    state and the mask enter as f32.  Returns (m, h_new, g_local_new) with
+    the tensors' shape and h's dtype."""
+    shape, dtype = tensors[0].shape, tensors[-2].dtype
     view = node_update_view(shape)
 
-    def to_view(x):
-        x = x.astype(jnp.float32)
+    def to_view(x, dt=jnp.float32):
+        x = x.astype(dt)
         return _to_lanes(x) if view is None else x.reshape(view)
+
+    grads, state = tensors[:-2], tensors[-2:]
+    tensors = [to_view(g, kernel_grad_dtype(g.dtype)) for g in grads] \
+        + [to_view(x) for x in state]
 
     if mask is None:
         extra = _key_words(key)
@@ -118,8 +141,7 @@ def _fused(kernel, tensors, *scalars, mask=None, key=None):
         # it runs under the compression plan's named scope
         with jax.named_scope("dasha.compress"):
             extra = to_view(mask)
-    outs = kernel(*map(to_view, tensors), extra, *scalars,
-                  interpret=_interpret())
+    outs = kernel(*tensors, extra, *scalars, interpret=_interpret())
     return tuple((_from_lanes(t, shape) if view is None else t.reshape(shape)
                   ).astype(dtype) for t in outs)
 

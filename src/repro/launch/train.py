@@ -42,7 +42,9 @@ import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.io import checkpoint_step, load_state, save_state
-from repro.compress.treelevel import kernel_draw_count, kernel_layout_count
+from repro.compress.treelevel import (kernel_draw_count,
+                                      kernel_grad_dtype_count,
+                                      kernel_layout_count)
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import SyntheticTextConfig, make_node_batches
 from repro.methods import MethodState
@@ -217,9 +219,10 @@ def main(argv=None) -> TrainRun:
         spmd_axes=None if mesh is None else ("data",))
 
     if args.use_kernel:
+        # the nodes' gradients, in the dtypes the oracle makes them in
         per_node = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct((args.nodes,) + x.shape,
-                                           jnp.float32), params_s)
+                                           x.dtype), params_s)
         specs = None if mesh is None else jax.tree_util.tree_map(
             lambda _: P("data"), per_node)
         print("[train] mask draw: " + str(kernel_draw_count(
@@ -227,6 +230,8 @@ def main(argv=None) -> TrainRun:
             mesh=mesh)))
         print("[train] node update: " + str(kernel_layout_count(
             per_node, specs=specs, mesh=mesh)))
+        print("[train] gradient: " + str(kernel_grad_dtype_count(
+            per_node)))
 
     def node_loss(p, b):
         return lm.loss_fn(cfg, p, b)[0]

@@ -195,6 +195,9 @@ class Method(NamedTuple):
                 bits0 = sub.dense_coords(h0)
             else:
                 raise ValueError(init_mode)
+            # the oracle's dtype into the state's (an identity on the flat
+            # substrates and where the two agree)
+            h0 = sub.lin(lambda h_: h_, h0)
             return MethodState(x=x0, g=sub.mean_nodes(h0), g_local=h0,
                                h_local=h0, opt_state=sub.init_opt(x0),
                                key=key, t=jnp.zeros((), jnp.int32),

@@ -637,7 +637,13 @@ class BatchLossOracle:
     accumulators sharded, and ``grad_specs`` pins per-param shardings.
     The same data batch evaluates both points of a pair — the "same
     samples" requirement of MVR/PAGE — and the megabatch sync round reuses
-    the round's batch (B' = B at this layer).
+    the round's batch (B' = B at this layer).  Each gradient comes back in
+    the dtype the backward pass made it in (the parameters'): the fused
+    node update reads it so and casts it in VMEM, and every other path
+    casts it where it becomes state (``TreeSubstrate.lin`` / ``where``,
+    the compressors' non-kernel branches, the engine's init).
+    ``state_dtype`` is what :meth:`grad_diff` returns, a difference taken
+    in f32.
     """
 
     loss_fn: Callable[[PyTree, Any], jax.Array]
@@ -655,9 +661,7 @@ class BatchLossOracle:
         vkw = {}
         if self.spmd_axes:
             vkw["spmd_axis_name"] = self.spmd_axes
-        grads = jax.vmap(gfun, in_axes=(None, 0), **vkw)(params, data)
-        return jax.tree_util.tree_map(
-            lambda g_: g_.astype(self.state_dtype), grads)
+        return jax.vmap(gfun, in_axes=(None, 0), **vkw)(params, data)
 
     def grad(self, key, x, data, size: int = 1):
         return self.per_node_grads(x, data)
@@ -787,6 +791,9 @@ class TreeCompression:
                     n=self.n, variant="dasha", specs=self.specs)
             return _mean_nodes(m), h_out, gl, self.payload_per_node(h_new)
 
+        # the oracle's gradient becomes state here, in h's dtype
+        h_new = jax.tree_util.tree_map(lambda hn, hh: hn.astype(hh.dtype),
+                                       h_new, h)
         delta = jax.tree_util.tree_map(
             lambda hn, hh, gl_: hn - hh - a * (gl_ - hh),
             h_new, h, g_local)
@@ -829,6 +836,7 @@ class LeafSpecCompressor:
         keys = _leaf_fanout(key, hn_leaves)
         aggs, h_outs, gls, payload = [], [], [], 0.0
         for k, hn, hh, gl in zip(keys, hn_leaves, h_leaves, gl_leaves):
+            hn = hn.astype(hh.dtype)
             n = hn.shape[0]
             shape = hn.shape[1:]
             d_leaf = int(_leaf_size(hn))
@@ -895,8 +903,10 @@ class TreeSubstrate:
             *trees)
 
     def where(self, coin, a, b):
+        """Leaf-wise ``a`` where ``coin`` else ``b``, in ``b``'s dtype (the
+        state's, where ``a`` is an oracle gradient)."""
         return jax.tree_util.tree_map(
-            lambda a_, b_: jnp.where(coin, a_, b_), a, b)
+            lambda a_, b_: jnp.where(coin, a_.astype(b_.dtype), b_), a, b)
 
     def mean_nodes(self, per_node):
         return _mean_nodes(per_node)

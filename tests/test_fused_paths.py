@@ -325,3 +325,114 @@ def test_kernel_layout_count():
     per_node = jax.tree_util.tree_map(lambda s: leaf(4, *s.shape), shapes)
     assert str(kernel_layout_count(per_node)) \
         == "own layout 13/13 leaves, 544.2M/544.2M elements"
+
+
+# ---------------------------------------------------------------------------
+# the gradient handed to the node update in the oracle's own dtype
+# ---------------------------------------------------------------------------
+
+def _smoke_run(variant, use_kernel, state_dtype="float32",
+               init_mode="zeros"):
+    """Two steps of the trainer's method on the mamba2 smoke config (bf16
+    and f32 parameters, 2 nodes): the state after init (``"exact"``: from
+    the oracle's gradient on the first batch) and each step.  The
+    step is compiled without excess precision, so that a bf16 value is
+    rounded to bf16 wherever the program makes one: otherwise the compiler
+    may keep a bf16 gradient that the next op casts back to f32 at f32, in
+    one program and not in another."""
+    from repro.launch.train import arch_config
+    from repro.models import init_params, lm
+    cfg = arch_config("mamba2-780m", False)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    method = make_method(
+        DashaTrainConfig(gamma=0.01, compression=0.25, n_nodes=2,
+                         variant=variant, use_kernel=use_kernel,
+                         state_dtype=state_dtype),
+        lambda p, b: lm.loss_fn(cfg, p, b)[0])
+    batches = []
+    for i in range(2):
+        toks = jax.random.randint(jax.random.PRNGKey(10 + i), (2, 1, 33), 1,
+                                  cfg.vocab_size)
+        batches.append({"tokens": toks[..., :-1], "labels": toks[..., 1:]})
+    states = [method.init(params, jax.random.PRNGKey(1), init_mode=init_mode,
+                          data=batches[0])]
+    step = jax.jit(method.step,
+                   compiler_options={"xla_allow_excess_precision": False})
+    for b in batches:
+        states.append(step(states[-1], b))
+    return states
+
+
+def _dtypes(state):
+    return jax.tree_util.tree_map(lambda x: x.dtype, state)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("variant", ["dasha", "mvr", "page", "sync_mvr"])
+def test_bf16_gradient_keeps_the_state_and_its_bits(monkeypatch, variant,
+                                                    use_kernel):
+    """The oracle hands the node update its gradients as the backward pass
+    made them (bf16 for the bf16 parameters): every state leaf keeps its
+    dtype across two steps, and the state is bit-equal to the same steps
+    with every gradient cast to f32 before the update."""
+    from repro.methods.substrates import BatchLossOracle
+    got = _smoke_run(variant, use_kernel)
+    for s in got[1:]:
+        assert _dtypes(s) == _dtypes(got[0])
+    assert {x.dtype for x in jax.tree_util.tree_leaves(got[0].h_local)} \
+        == {jnp.dtype(jnp.float32)}
+
+    own = BatchLossOracle.per_node_grads
+    monkeypatch.setattr(
+        BatchLossOracle, "per_node_grads",
+        lambda self, params, data: jax.tree_util.tree_map(
+            lambda g: g.astype(jnp.float32), own(self, params, data)))
+    want = _smoke_run(variant, use_kernel)
+    for a, b in zip(got, want):
+        _assert_same_bits(a, b)
+
+
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_state_keeps_its_dtype_from_an_exact_init(state_dtype):
+    """h_i and g_i start from the oracle's gradient (bf16 and f32 leaves)
+    in the state's dtype, and keep it through the kernel and the dense
+    path and SYNC-MVR's dense sync branch, as the parameters keep
+    theirs."""
+    for use_kernel in (False, True):
+        states = _smoke_run("sync_mvr", use_kernel, state_dtype=state_dtype,
+                            init_mode="exact")
+        for s in states:
+            assert _dtypes(s) == _dtypes(states[0])
+        assert {x.dtype for x in jax.tree_util.tree_leaves(
+            (states[0].h_local, states[0].g_local))} \
+            == {jnp.dtype(state_dtype)}
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "nemotron-3-nano-30b-a3b"])
+def test_kernel_grad_dtype_count(arch):
+    """The gradients the oracle makes for both configurations' smoke
+    trees reach the kernel in their own dtype, all of them; an f16 leaf
+    would take a cast pass to f32 first."""
+    from repro.compress.treelevel import kernel_grad_dtype_count
+    from repro.launch.train import arch_config
+    from repro.methods.substrates import BatchLossOracle
+    from repro.models import init_params, lm
+    cfg = arch_config(arch, False)
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 1, 32), jnp.int32)
+    oracle = BatchLossOracle(lambda p, b: lm.loss_fn(cfg, p, b)[0])
+    grads = jax.eval_shape(oracle.per_node_grads, params,
+                           {"tokens": toks, "labels": toks})
+    dtypes = {x.dtype for x in jax.tree_util.tree_leaves(grads)}
+    assert jnp.dtype(jnp.bfloat16) in dtypes
+    count = kernel_grad_dtype_count(grads)
+    assert count.leaves == count.of_leaves
+    assert count.elements == count.of_elements
+    n = count.of_leaves
+    total = count.of_elements
+    assert str(count) == (f"own dtype {n}/{n} leaves, {total / 1e6:.1f}M/"
+                          f"{total / 1e6:.1f}M elements")
+    half = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float16), grads)
+    assert tuple(kernel_grad_dtype_count(half)) == (0, n, 0, total)

@@ -111,31 +111,55 @@ def _keyed_inputs(shape, n=4):
     return [jax.random.normal(k, shape) for k in ks]
 
 
+#: the gradient dtypes the kernels read as they are: the f32 of the flat
+#: problems and the bf16 that the trainer's backward pass makes
+GRAD_DTYPES = ["float32", "bfloat16"]
+
+
+def _same_as_f32_grads(update, grads, state):
+    """``update(*grads, *state)`` keeps the state's dtype and is bit-equal
+    to the update given the gradients cast to f32."""
+    got = update(*grads, *state)
+    assert all(x.dtype == state[0].dtype for x in got)
+    _same_bits(got, update(*(g.astype(jnp.float32) for g in grads), *state))
+    return got
+
+
+@pytest.mark.parametrize("grad_dtype", GRAD_DTYPES)
 @pytest.mark.parametrize("thresh", [1, 8, 255])
 @pytest.mark.parametrize("shape", KEYED_SHAPES)
-def test_keyed_dasha_update_equals_explicit_mask(shape, thresh):
+def test_keyed_dasha_update_equals_explicit_mask(shape, thresh, grad_dtype):
     """The keyed kernel's m, h_new and g_i are bit-equal to the
-    explicit-mask kernel's fed ``draw_mask(k, shape, thresh / 256)``."""
+    explicit-mask kernel's fed ``draw_mask(k, shape, thresh / 256)``, and
+    a bf16 gradient gives what its f32 cast gives."""
     from repro.compress.plan import draw_mask
     grad, h, gl = _keyed_inputs(shape, 3)
+    grad = grad.astype(grad_dtype)
     key = jax.random.PRNGKey(11)
     mask = draw_mask(key, shape, thresh / 256).astype(jnp.float32)
     scale = 256 / thresh
-    _same_bits(ops.dasha_update_keyed(grad, h, gl, key, 0.2, scale, thresh),
-               ops.dasha_update(grad, h, gl, mask, 0.2, scale))
+    got = _same_as_f32_grads(lambda g, h_, gl_: ops.dasha_update_keyed(
+        g, h_, gl_, key, 0.2, scale, thresh), (grad,), (h, gl))
+    _same_bits(got, ops.dasha_update(grad, h, gl, mask, 0.2, scale))
 
 
+@pytest.mark.parametrize("grad_dtype", GRAD_DTYPES)
 @pytest.mark.parametrize("thresh", [1, 8, 255])
 @pytest.mark.parametrize("shape", KEYED_SHAPES)
-def test_keyed_dasha_mvr_update_equals_explicit_mask(shape, thresh):
+def test_keyed_dasha_mvr_update_equals_explicit_mask(shape, thresh,
+                                                     grad_dtype):
     from repro.compress.plan import draw_mask
     gn, go, h, gl = _keyed_inputs(shape)
+    gn, go = gn.astype(grad_dtype), go.astype(grad_dtype)
     key = jax.random.PRNGKey(12)
     mask = draw_mask(key, shape, thresh / 256).astype(jnp.float32)
     scale = 256 / thresh
-    _same_bits(ops.dasha_mvr_update_keyed(gn, go, h, gl, key, 0.2, 0.3,
-                                          scale, thresh),
-               ops.dasha_mvr_update(gn, go, h, gl, mask, 0.2, 0.3, scale))
+    got = _same_as_f32_grads(
+        lambda gn_, go_, h_, gl_: ops.dasha_mvr_update_keyed(
+            gn_, go_, h_, gl_, key, 0.2, 0.3, scale, thresh),
+        (gn, go), (h, gl))
+    _same_bits(got, ops.dasha_mvr_update(gn, go, h, gl, mask, 0.2, 0.3,
+                                         scale))
 
 
 @pytest.mark.parametrize("variant", ["dasha", "mvr"])
@@ -265,34 +289,43 @@ def _lane_path(kernel, tensors, extra, *scalars):
     return tuple(ops._from_lanes(t, shape) for t in outs)
 
 
+@pytest.mark.parametrize("grad_dtype", GRAD_DTYPES)
 @pytest.mark.parametrize("form", ["keyed", "explicit"])
 @pytest.mark.parametrize("variant", ["dasha", "mvr"])
 @pytest.mark.parametrize("shape", LAYOUT_SHAPES, ids=str)
-def test_own_layout_is_bit_equal_to_the_lane_path(shape, variant, form):
+def test_own_layout_is_bit_equal_to_the_lane_path(shape, variant, form,
+                                                  grad_dtype):
     """m, h_new and g_local_new in each leaf's own layout are bit-equal to
-    the (R, 128) lane path's, keyed and explicit, DASHA and MVR."""
+    the (R, 128) lane path's, keyed and explicit, DASHA and MVR; a bf16
+    gradient (packed into bf16 lane rows on the lane path) gives what its
+    f32 cast gives."""
     from repro.compress.plan import draw_mask
     from repro.kernels import dasha_update as kern
     view = ops.node_update_view(shape)
     assert (view is None) == (shape in [(1000,), (9, 16500)])
     gn, go, h, gl = _keyed_inputs(shape)
+    gn, go = gn.astype(grad_dtype), go.astype(grad_dtype)
     key = jax.random.PRNGKey(16)
     mvr = variant == "mvr"
-    tensors = (gn, go, h, gl) if mvr else (gn, h, gl)
+    grads = (gn, go) if mvr else (gn,)
     scalars = (0.2, 0.3, 32.0) if mvr else (0.2, 32.0)
     if form == "keyed":
-        got = (ops.dasha_mvr_update_keyed if mvr else ops.dasha_update_keyed)(
-            *tensors, key, *scalars, 8)
+        op = ops.dasha_mvr_update_keyed if mvr else ops.dasha_update_keyed
+        got = _same_as_f32_grads(lambda *ts: op(*ts, key, *scalars, 8),
+                                 grads, (h, gl))
         kernel = (kern.dasha_mvr_update_keyed_pallas if mvr
                   else kern.dasha_update_keyed_pallas)
-        want = _lane_path(kernel, tensors, ops._key_words(key), *scalars, 8)
+        want = _lane_path(kernel, (*grads, h, gl), ops._key_words(key),
+                          *scalars, 8)
     else:
         mask = draw_mask(key, shape, 8 / 256).astype(jnp.float32)
-        got = (ops.dasha_mvr_update if mvr else ops.dasha_update)(
-            *tensors, mask, *scalars)
+        op = ops.dasha_mvr_update if mvr else ops.dasha_update
+        got = _same_as_f32_grads(lambda *ts: op(*ts, mask, *scalars),
+                                 grads, (h, gl))
         kernel = (kern.dasha_mvr_update_pallas if mvr
                   else kern.dasha_update_pallas)
-        want = _lane_path(kernel, tensors, ops._to_lanes(mask), *scalars)
+        want = _lane_path(kernel, (*grads, h, gl), ops._to_lanes(mask),
+                          *scalars)
     _same_bits(got, want)
 
 

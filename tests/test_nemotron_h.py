@@ -334,7 +334,8 @@ def test_attention_layers_take_no_rotary_embedding():
 def test_dasha_chunk_through_make_method_and_driver(capsys):
     """One node, the smoke config, through the trainer: every leaf's mask
     drawn inside the keyed fused kernel, the leaves updated in their own
-    layout as :func:`kernel_layout_count` counts them, the chip share and
+    layout as :func:`kernel_layout_count` counts them, every gradient
+    handed to the kernel in its own dtype, the chip share and
     no dropped token printed at each log."""
     from repro.compress.treelevel import kernel_layout_count
     from repro.launch import train
@@ -349,6 +350,7 @@ def test_dasha_chunk_through_make_method_and_driver(capsys):
         lambda x: jax.ShapeDtypeStruct((1,) + x.shape, jnp.float32),
         run.state.x)
     assert f"node update: {kernel_layout_count(per_node)}\n" in out
+    assert f"gradient: own dtype {leaves}/{leaves} leaves" in out
     assert "chip share: layers 5/5 experts 4/16 vocab rows 256/512" in out
     assert "dropped=0" in out
     rec = run.log[-1]

@@ -85,6 +85,40 @@ def test_dasha_mvr_update_keyed_compiles_for_v5e(one_chip):
         [((ROWS, LANE), f32)] * 4 + [((2,), jnp.uint32)], one_chip)
 
 
+@pytest.mark.parametrize("shape", [(4, 50432, 1536), (4, 4, 1536, 48, 64),
+                                   (9, 16500)], ids=["embed", "w_z", "lanes"])
+@pytest.mark.parametrize("variant", ["dasha", "mvr"])
+def test_keyed_update_reads_bf16_gradients_with_no_convert(
+        one_chip, monkeypatch, variant, shape):
+    """Keyed DASHA and MVR through the ops layer, for a v5e, with bf16
+    gradients beside the f32 state: mamba2's embed (a (201728, 1536)
+    view), ``w_z`` (a 64-wide view) and a leaf on the lane path.  Each
+    gradient reaches the kernel as a bf16 operand, and no convert runs
+    outside it: no f32 copy of a gradient is written to HBM."""
+    import re
+
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    if variant == "mvr":
+        def update(gn, go, h, gl, k):
+            return ops.dasha_mvr_update_keyed(gn, go, h, gl, k, 0.2, 0.1,
+                                              32.0, 8)
+        n_grads = 2
+    else:
+        def update(g, h, gl, k):
+            return ops.dasha_update_keyed(g, h, gl, k, 0.2, 32.0, 8)
+        n_grads = 1
+    hlo = _compile(update, [(shape, bf16)] * n_grads + [(shape, f32)] * 2
+                   + [((2,), jnp.uint32)], one_chip).as_text()
+    assert not re.search(r"\bconvert\(", hlo)
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    operands = calls[0].split("operand_layout_constraints={")[1]
+    operands = operands[:operands.index("}}") + 1]
+    assert operands.count("bf16[") == n_grads, operands
+
+
 def test_quantize_compiles_for_v5e(one_chip):
     f32 = jnp.float32
     _compile(lambda x, u: quantize_pallas(x, u, 15, interpret=False),
